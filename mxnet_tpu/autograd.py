@@ -25,6 +25,7 @@ distinct value node and cannot corrupt earlier gradients.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import weakref
 from typing import Optional
@@ -182,7 +183,8 @@ def mark_variables(variables, gradients, grad_reqs="write"):
 
 
 def _ones_like(a):
-    return jnp.ones(a.shape, a.dtype)
+    with _on_device_of([a]):
+        return jnp.ones(a.shape, a.dtype)
 
 
 def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
@@ -250,10 +252,12 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
                     cots.append(outs_cot[i])
                 else:
                     cots.append(None)
-            cots = _fill_zeros(node, cots)
-            in_cots = node.vjp_fn(tuple(cots))
+            with _on_device_of(outs_cot):
+                cots = _fill_zeros(node, cots)
+                in_cots = node.vjp_fn(tuple(cots))
         else:
-            in_cots = node.vjp_fn(outs_cot[0])
+            with _on_device_of(outs_cot):
+                in_cots = node.vjp_fn(outs_cot[0])
         for slot, g, x in zip(node.in_keys, in_cots, node.in_arrays):
             if slot is None or g is None:
                 continue
@@ -315,6 +319,20 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
                     if o is not None:
                         o._released = True
         _STATE.tape = [n for n in _STATE.tape if id(n) not in used_nodes]
+
+
+def _on_device_of(cots):
+    """Default-device scope for the reverse sweep, as invoke() sets for
+    the forward: where the (first) given array lives. What the sweep
+    creates rather than computes — the head's ones, the zero gradient
+    of an input the loss never used, a hidden output's zero cotangent —
+    is uncommitted and would otherwise land on the PROCESS default
+    device: replica i's gradient on device 0, which made the kvstore
+    decline its fused all-reduce for the whole step."""
+    devs = next(c for c in cots if c is not None).devices()
+    if len(devs) != 1:
+        return contextlib.nullcontext()
+    return jax.default_device(next(iter(devs)))
 
 
 def _fill_zeros(node, cots):

@@ -41,7 +41,8 @@ and a restarting engine replays it with ``warmup(manifest=...)`` — a
 rolling restart serves its first real request from a warm cache.
 
 Env knobs (see ``envvars.py``): ``MXNET_TPU_COMPILE_CACHE`` (gate),
-``MXNET_TPU_COMPILE_CACHE_DIR``, ``MXNET_TPU_COMPILE_CACHE_MIN_S``,
+``MXNET_TPU_COMPILE_CACHE_DIR`` (yields to jax's own
+``JAX_COMPILATION_CACHE_DIR``), ``MXNET_TPU_COMPILE_CACHE_MIN_S``,
 ``MXNET_TPU_WARMUP_MANIFEST``.
 """
 from __future__ import annotations
@@ -51,13 +52,24 @@ import os
 import threading
 import time
 
+import jax
+from jax._src import compilation_cache as _jax_cc
+from jax._src import monitoring as _jax_monitoring
+
 from . import envvars
 
 __all__ = ["configure", "ensure", "enabled", "state", "events_snapshot",
            "classify", "manifest_path", "new_manifest", "manifest_shapes",
            "merge_manifests", "save_manifest", "load_manifest"]
 
-_DEFAULT_DIR = os.path.join("~", ".cache", "mxnet_tpu", "compile_cache")
+# The directory is part of the cache key's locality: a cache that moves
+# never hits. It is therefore placed from OUTSIDE the program or not at
+# all — JAX_COMPILATION_CACHE_DIR (jax's own knob, left untouched), then
+# MXNET_TPU_COMPILE_CACHE_DIR, then this one fixed path in the checkout
+# (gitignored). Never $HOME, a temp name, a pid or a time.
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 _lock = threading.Lock()
 _state = {"configured": False, "dir": None, "min_s": None}
@@ -96,75 +108,59 @@ def _install_listener():
     with _lock:
         # check-and-set under the lock: two engines' concurrent first
         # compiles must not register the listener twice (every cache
-        # event would count double). A failed install (private-API
-        # drift) also latches — the cache still works, only the
-        # hit/miss split degrades (classify() then reports "miss").
+        # event would count double)
         if _listener_installed:
             return
         _listener_installed = True
-        try:
-            from jax._src import monitoring as _mon
-            _on_cache_event._counters = _counters()
-            _mon.register_event_listener(_on_cache_event)
-        except Exception:
-            pass
+        _on_cache_event._counters = _counters()
+        _jax_monitoring.register_event_listener(_on_cache_event)
 
 
-def configure(cache_dir=None, min_compile_secs=None, force=False):
-    """Point JAX's persistent compilation cache at an on-disk
-    directory and install the hit/miss event listener. Idempotent —
-    repeat calls with no arguments are no-ops once configured; pass
-    explicit arguments (or ``force=True``) to re-point it.
+def configure(force=False):
+    """Turn on JAX's persistent compilation cache and install the
+    hit/miss event listener. Idempotent — repeat calls are no-ops once
+    configured; ``force=True`` re-reads the environment.
+
+    Where the cache lives is decided outside the program (see
+    ``_DEFAULT_DIR``): when ``JAX_COMPILATION_CACHE_DIR`` is set jax
+    already points there and this function sets no directory at all.
 
     Returns the effective state dict ``{"configured", "dir",
     "min_s"}`` (``configured=False`` when the
-    ``MXNET_TPU_COMPILE_CACHE`` gate is off or jax is unavailable).
+    ``MXNET_TPU_COMPILE_CACHE`` gate is off).
     """
     if not envvars.get("MXNET_TPU_COMPILE_CACHE"):
         return dict(_state)
     with _lock:
-        already = _state["configured"]
-    if already and not force and cache_dir is None \
-            and min_compile_secs is None:
-        return dict(_state)
-    path = (cache_dir
-            or envvars.get("MXNET_TPU_COMPILE_CACHE_DIR")
-            or os.path.expanduser(_DEFAULT_DIR))
-    path = os.path.abspath(os.path.expanduser(path))
-    min_s = (min_compile_secs if min_compile_secs is not None
-             else envvars.get("MXNET_TPU_COMPILE_CACHE_MIN_S"))
-    try:
-        import jax
-
+        if _state["configured"] and not force:
+            return dict(_state)
+    min_s = float(envvars.get("MXNET_TPU_COMPILE_CACHE_MIN_S"))
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.abspath(os.path.expanduser(
+            envvars.get("MXNET_TPU_COMPILE_CACHE_DIR") or _DEFAULT_DIR))
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_s))
-        # size floor off: whether an entry is worth persisting is the
-        # compile-TIME knob's job (and tests set it to 0 to force
-        # cross-process hits on trivially small computations)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # jax LATCHES "cache unused" on the first compile of the
-        # process (is_cache_used memoizes per task) — any compile
-        # before this point (model init, an eager op) would leave the
-        # cache permanently inert despite the config. Reset so the
-        # next compile re-initializes against the directory above.
-        try:
-            from jax._src import compilation_cache as _jax_cc
-            _jax_cc.reset_cache()
-        except Exception:
-            pass        # private-API drift: fresh processes still work
-    except Exception:
-        return dict(_state)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
+    # size floor off: whether an entry is worth persisting is the
+    # compile-TIME knob's job (and tests set it to 0 to force
+    # cross-process hits on trivially small computations)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax LATCHES "cache unused" on the first compile of the process
+    # (is_cache_used memoizes per task) — any compile before this point
+    # (model init, an eager op) would leave the cache permanently inert
+    # despite the config. Reset so the next compile re-initializes
+    # against the directory above.
+    _jax_cc.reset_cache()
     _install_listener()
     with _lock:
         changed = (_state["dir"] != path or _state["min_s"] != min_s
                    or not _state["configured"])
-        _state.update(configured=True, dir=path, min_s=float(min_s))
+        _state.update(configured=True, dir=path, min_s=min_s)
     if changed:
         from .telemetry import events as _events
         _events.emit("compile_cache_configured", dir=path,
-                     min_compile_secs=float(min_s))
+                     min_compile_secs=min_s)
     return dict(_state)
 
 

@@ -153,9 +153,10 @@ register("MXNET_TPU_COMPILE_CACHE", "bool", True,
          "process then recompiles every shape from scratch",
          scope="compile_cache")
 register("MXNET_TPU_COMPILE_CACHE_DIR", "path", None,
-         "persistent compile-cache directory (default "
-         "``~/.cache/mxnet_tpu/compile_cache``); share it across "
-         "engine processes so restarts reuse each other's executables",
+         "persistent compile-cache directory, used when jax's own "
+         "``JAX_COMPILATION_CACHE_DIR`` is unset (default: the fixed "
+         "``<checkout>/.jax_cache``); share it across engine "
+         "processes so restarts reuse each other's executables",
          scope="compile_cache")
 register("MXNET_TPU_COMPILE_CACHE_MIN_S", "float", 1.0,
          "only compiles slower than this many seconds are persisted "

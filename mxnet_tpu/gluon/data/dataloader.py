@@ -32,6 +32,19 @@ __all__ = ["DataLoader", "DevicePrefetcher", "default_batchify_fn",
            "default_mp_batchify_fn"]
 
 
+def _holds_accelerator():
+    """Has this process already opened a non-CPU JAX backend? A chip
+    belongs to one process: forked after that point, a worker would
+    inherit the parent's device handles and its client threads' locks.
+    So workers fork before the parent first touches the backend, or the
+    loader uses threads."""
+    import jax
+    from jax._src import xla_bridge
+
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu")
+
+
 def default_batchify_fn(data):
     """Stack samples into a batch (reference default_batchify_fn)."""
     if isinstance(data[0], NDArray):
@@ -180,9 +193,9 @@ class DataLoader:
         self._batchify_fn = batchify_fn
 
     def _get_mp_pool(self):
-        """Fork the worker pool ONCE and keep it across epochs
-        (reference keeps workers alive too; forking a parent that holds
-        an accelerator client is expensive — seconds per worker)."""
+        """Fork the worker pool ONCE and keep it across epochs (the
+        reference keeps workers alive too). Only reached while this
+        process holds no accelerator (see ``_holds_accelerator``)."""
         if self._mp_pool is None:
             ctx = _mp.get_context("fork")
             self._mp_pool = ctx.Pool(
@@ -205,6 +218,8 @@ class DataLoader:
                     yield self._batchify_fn([self._dataset[idx] for idx in batch])
             return same_process_iter()
         if not self._thread_pool:
+            if self._fork_safe is None and _holds_accelerator():
+                self._use_thread_workers()
             if self._fork_safe is None:
                 # fork the pool BEFORE probing: the probe may materialize
                 # lazy dataset state (open record files) in the parent,
@@ -236,9 +251,14 @@ class DataLoader:
                 self._fork_safe = not has_nd(self._dataset[0])
             except Exception:
                 self._fork_safe = False
-            if not self._fork_safe and self._batchify_fn is default_mp_batchify_fn:
-                self._batchify_fn = default_batchify_fn
+            if not self._fork_safe:
+                self._use_thread_workers()
         return self._fork_safe
+
+    def _use_thread_workers(self):
+        self._fork_safe = False
+        if self._batchify_fn is default_mp_batchify_fn:
+            self._batchify_fn = default_batchify_fn
 
     def __len__(self):
         return len(self._batch_sampler)

@@ -8,6 +8,7 @@ via ctypes, per the environment constraints).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,10 +18,39 @@ _LOCK = threading.Lock()
 _SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "src", "cc")
 _LIB_PATH = os.path.join(_SRC_DIR, "libmxtpu_io.so")
+_STAMP_PATH = _LIB_PATH + ".srchash"
+# what the library is built from — all tracked by git
+_BUILD_INPUTS = ("Makefile", "recordio.cc", "image_batcher.cc")
 
 
 class NativeIOUnavailable(RuntimeError):
     pass
+
+
+def _source_hash():
+    h = hashlib.sha256()
+    for name in _BUILD_INPUTS:
+        with open(os.path.join(_SRC_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
+def _build(want):
+    """Build into a private name, then rename into place: a concurrent
+    loader (xdist workers, engine processes) never maps a half-written
+    library. The stamp is written last, so a build that died leaves no
+    stamp and is redone."""
+    tmp = f"libmxtpu_io.{os.getpid()}.so.tmp"
+    try:
+        subprocess.run(["make", "-B", "-C", _SRC_DIR, f"LIB={tmp}"],
+                       check=True, capture_output=True)
+    except (subprocess.CalledProcessError, FileNotFoundError) as e:
+        raise NativeIOUnavailable(
+            f"could not build native IO library: {e}") from e
+    os.replace(os.path.join(_SRC_DIR, tmp), _LIB_PATH)
+    with open(_STAMP_PATH + f".{os.getpid()}.tmp", "w") as f:
+        f.write(want)
+    os.replace(f.name, _STAMP_PATH)
 
 
 def _load():
@@ -28,17 +58,18 @@ def _load():
     with _LOCK:
         if _LIB is not None:
             return _LIB
-        srcs = [os.path.join(_SRC_DIR, f)
-                for f in ("recordio.cc", "image_batcher.cc")]
-        if not os.path.exists(_LIB_PATH) or \
-                os.path.getmtime(_LIB_PATH) < max(
-                    os.path.getmtime(s) for s in srcs if os.path.exists(s)):
-            try:
-                subprocess.run(["make", "-C", _SRC_DIR], check=True,
-                               capture_output=True)
-            except (subprocess.CalledProcessError, FileNotFoundError) as e:
-                raise NativeIOUnavailable(
-                    f"could not build native IO library: {e}") from e
+        # The .so is untracked: one found in the tree is trusted only
+        # if its stamp names exactly the tracked sources it would be
+        # built from now (file times say nothing after a copy or a
+        # checkout).
+        want = _source_hash()
+        try:
+            with open(_STAMP_PATH) as f:
+                have = f.read()
+        except FileNotFoundError:
+            have = None
+        if have != want or not os.path.exists(_LIB_PATH):
+            _build(want)
         lib = ctypes.CDLL(_LIB_PATH)
         lib.mxio_reader_open.restype = ctypes.c_void_p
         lib.mxio_reader_open.argtypes = [ctypes.c_char_p]

@@ -18,9 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from ..ndarray.register import register_op
-# newer jax exports the x64 context manager at top level; older jax
-# keeps it in jax.experimental
-from ..ops.pallas._util import _enable_x64 as _enable_x64_ctx
 
 __all__ = []  # everything here is reached through the registry
 
@@ -396,7 +393,7 @@ def _x64_safe(fn):
     @functools.wraps(fn)
     def wrapped(a, *rest, **kw):
         if hasattr(a, "dtype") and a.dtype.itemsize <= 4:
-            with _enable_x64_ctx(False):
+            with jax.enable_x64(False):
                 return fn(a, *rest, **kw)
         return fn(a, *rest, **kw)
 

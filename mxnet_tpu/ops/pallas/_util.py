@@ -7,10 +7,6 @@ import jax
 
 from ... import envvars
 
-try:  # newer jax exports the x64 context manager at top level
-    _enable_x64 = jax.enable_x64
-except AttributeError:  # older jax: experimental namespace
-    from jax.experimental import enable_x64 as _enable_x64
 
 def interpret_mode() -> bool:
     """Run pallas_call in interpreter mode (CPU testing of kernels)."""
@@ -26,40 +22,44 @@ def pallas_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _platforms(data):
+    """The set of platforms ``data`` is placed on: its devices' for a
+    concrete jax.Array, the default device's (object or platform
+    string) — else the default backend's — for a tracer, whose
+    placement is decided by whoever runs the trace."""
+    if isinstance(data, jax.core.Tracer):
+        dev = jax.config.jax_default_device
+        if dev is None:
+            return {jax.default_backend()}
+        return {dev if isinstance(dev, str) else dev.platform}
+    if isinstance(data, jax.Array):
+        return {d.platform for d in data.devices()}
+    raise TypeError(
+        f"cannot tell where a {type(data).__name__} is placed: kernel "
+        "dispatch needs a jax.Array or a tracer")
+
+
 def pallas_ok_for(data) -> bool:
-    """pallas_enabled() AND the value actually lives on (or is being
-    traced for) a TPU device. In a TPU-backend process an op invoked on
-    a cpu(0) context must NOT take the Mosaic path — it would crash at
-    lowering ('Only interpret mode is supported on CPU backend')."""
+    """Kernel or jnp twin for this value? The decision is explicit, never
+    a quiet decline: off (MXNET_TPU_DISABLE_PALLAS) takes the twin;
+    interpret mode (the MXNET_TPU_PALLAS_INTERPRET test switch) takes
+    the kernel anywhere; on a TPU backend a value placed on (or traced
+    for) TPU devices takes the kernel and one placed on CPU devices
+    takes the twin — an op on a cpu(0) context in a TPU process must
+    not reach Mosaic. Any other placement raises."""
     if not pallas_enabled():
         return False
     if interpret_mode():
         return True
-    # jax.Array.devices() -> set[Device] classifies single- and
-    # multi-device arrays uniformly (a CPU-mesh-sharded array in a TPU
-    # process must refuse the Mosaic path). Tracers expose neither
-    # .devices nor .device.
-    devs = None
-    devices_fn = getattr(data, "devices", None)
-    if callable(devices_fn):
-        try:
-            devs = devices_fn()
-        except Exception:
-            devs = None
-    if devs is None:
-        dev = getattr(data, "device", None)
-        if dev is not None and not callable(dev):
-            devs = getattr(dev, "device_set", None)
-            if not devs and hasattr(dev, "platform"):
-                devs = [dev]
-    if devs is None:
-        # trace time: placement is the default device / backend
-        dev = jax.config.jax_default_device
-        if dev is None:
-            return jax.default_backend() == "tpu"
-        devs = [dev]
-    # unknown platforms fail CLOSED — jnp fallback is always correct
-    return {getattr(d, "platform", None) for d in devs} == {"tpu"}
+    platforms = _platforms(data)
+    if platforms == {"tpu"}:
+        return True
+    if platforms == {"cpu"}:
+        return False
+    raise ValueError(
+        f"kernel dispatch cannot classify a value placed on "
+        f"{sorted(platforms)} in a TPU process: expected all-TPU or "
+        "all-CPU devices")
 
 
 def resolve_interpret(interpret):
@@ -82,7 +82,7 @@ def x32(fn):
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        with _enable_x64(False):
+        with jax.enable_x64(False):
             return fn(*args, **kwargs)
 
     return wrapper

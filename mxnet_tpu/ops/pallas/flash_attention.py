@@ -84,6 +84,15 @@ _SEG_SUBLANES = 8
 # each other (or any real id ≥ 0), so they get DISTINCT negatives
 _SEG_PAD_Q = -2
 _SEG_PAD_KV = -3
+# The backward kernels work on f32 (block_q, block_k) tiles (s, p, dp,
+# ds) of 4 MiB each at the default 512x2048 tiling, against Mosaic's
+# default scoped-VMEM limit of 16 MiB. The un-segmented backward
+# compiles under that limit; with the segment mask's repeated id tile
+# the v5e compiler refused the packed S=2048 backward by 76 KiB
+# (16.07 MiB needed). Interpret mode never checks VMEM, so the limit is
+# stated: twice the default, a quarter of a v5e core's 128 MiB.
+_BWD_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=32 * 1024 * 1024)
 
 
 def _dot_precision(dtype):
@@ -584,6 +593,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        name="mxtpu_flash_fwd",
         interpret=interpret,
     )(*operands)
     o = o[:, :sq].reshape(b, h, sq, d)
@@ -707,6 +717,8 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_BWD_COMPILER_PARAMS,
+        name="mxtpu_flash_bwd_fused",
         interpret=interpret,
     )(*operands)
 
@@ -752,6 +764,8 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, d), q_dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_BWD_COMPILER_PARAMS,
+        name="mxtpu_flash_bwd_dq",
         interpret=interpret,
     )(*operands)
 
@@ -790,6 +804,8 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_BWD_COMPILER_PARAMS,
+        name="mxtpu_flash_bwd_dkv",
         interpret=interpret,
     )(*operands)
 
@@ -1027,6 +1043,7 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, kv_lens,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, block_q, d), q.dtype),
+        name="mxtpu_paged_flash_fwd",
         interpret=resolve_interpret(interpret),
     )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
       qf, k_pages, v_pages)

@@ -104,6 +104,7 @@ def _ln_fwd(x2, gamma, beta, eps, interpret):
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
+        name="mxtpu_layer_norm_fwd",
         interpret=interpret,
     )(x2, gamma.reshape(1, d), beta.reshape(1, d))
     return out, mu, rs
@@ -134,6 +135,7 @@ def _ln_bwd(x2, gamma, mu, rs, dy2, interpret):
             jax.ShapeDtypeStruct((1, d), jnp.float32),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
+        name="mxtpu_layer_norm_bwd",
         interpret=interpret,
     )(x2, gamma.reshape(1, d), mu, rs, dy2)
     return dx, dg, db

@@ -159,6 +159,7 @@ def _lstm_fwd(gin, w, h0, c0, interpret):
             pltpu.VMEM((N, H), jnp.float32),
             pltpu.VMEM((N, H), jnp.float32),
         ],
+        name="mxtpu_lstm_fwd",
         interpret=interpret,
     )(gin, w, h0, c0)
     return out, cseq, gates
@@ -209,6 +210,7 @@ def _lstm_bwd(gates, cseq, out, w, h0, c0, dout, dcseq, interpret):
             pltpu.VMEM((N, H), jnp.float32),
             pltpu.VMEM((H, G), jnp.float32),
         ],
+        name="mxtpu_lstm_bwd",
         interpret=interpret,
     )(gates, cseq, cseq, out, dout, dcseq, w, h0, c0)
     return dgin, dh0, dc0, dw

@@ -110,6 +110,7 @@ def _xent_fwd(logits, labels, interpret):
             pltpu.VMEM((bn, 1), jnp.float32),
             pltpu.VMEM((bn, 1), jnp.float32),
         ],
+        name="mxtpu_softmax_xent_fwd",
         interpret=interpret,
     )(logits, lab)
     return loss[:, 0], lse[:, 0]
@@ -140,6 +141,7 @@ def _xent_bwd(logits, labels, lse, g, interpret):
         out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
+        name="mxtpu_softmax_xent_bwd",
         interpret=interpret,
     )(logits, lab, lse2, g2)
     return dx

@@ -50,4 +50,4 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    return lax.axis_size(axis_name) if hasattr(lax, "axis_size") else lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)

@@ -75,11 +75,7 @@ def _build(devices, shapes_dtypes):
     lowered = jax.jit(
         reduce_all, out_shardings=[repl_sh] * len(shapes_dtypes)).lower(avals)
     compiled = lowered.compile()
-    try:
-        hlo = compiled.as_text()
-    except Exception:
-        hlo = ""
-    return compiled, stack_sh, hlo
+    return compiled, stack_sh, compiled.as_text()
 
 
 def reduce_replica_lists(value_lists, devices=None):
@@ -176,11 +172,7 @@ def reduce_compressed_replica_lists(value_lists, residual_lists,
             out_shardings=([repl_sh] * n_keys, [stack_sh] * n_keys),
             donate_argnums=(1,),
         ).lower(avals_g, avals_r).compile()
-        try:
-            hlo = compiled.as_text()
-        except Exception:
-            hlo = ""
-        entry = (compiled, stack_sh, hlo)
+        entry = (compiled, stack_sh, compiled.as_text())
         _CACHE[key] = entry
     compiled, stack_sh, hlo = entry
     _LAST_HLO[0] = hlo

@@ -40,15 +40,6 @@ __all__ = ["ring_attention", "ulysses_attention",
 _NEG_INF = -1e30
 
 
-def _axis_size_static(axis_name):
-    size = lax.axis_size(axis_name) if hasattr(lax, "axis_size") else None
-    if size is None or not isinstance(size, int):
-        raise ValueError(
-            f"static size of mesh axis {axis_name!r} unavailable; pass "
-            "axis_size= explicitly")
-    return size
-
-
 def ring_attention(q, k, v, axis_name, causal=False, sm_scale=None,
                    axis_size=None, remat=True, use_flash=None):
     """Blockwise self-attention over a ring of sequence shards.
@@ -66,7 +57,7 @@ def ring_attention(q, k, v, axis_name, causal=False, sm_scale=None,
         online-softmax fold (which materializes one (B, H, C, C) score
         block per step and remains the CPU/debug fallback).
     """
-    P_ = axis_size if axis_size is not None else _axis_size_static(axis_name)
+    P_ = axis_size if axis_size is not None else lax.axis_size(axis_name)
     b, h, c, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
     idx = lax.axis_index(axis_name)
@@ -196,14 +187,11 @@ def ulysses_attention(q, k, v, axis_name, causal=False, sm_scale=None):
 
 
 def _seq_sharded_wrapper(fn, mesh, axis_name, **kw):
-    from ._compat import shard_map
-
     spec = P(None, None, axis_name, None)
-    wrapped = shard_map(
+    return jax.shard_map(
         functools.partial(fn, axis_name=axis_name, **kw),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)
-    return wrapped
 
 
 def make_ring_attention_fn(mesh, axis_name="sp", causal=False,

@@ -27,7 +27,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["make_sharded_embedding_fn", "shard_embedding_table"]
@@ -44,13 +43,9 @@ def shard_embedding_table(table, mesh, axis_name="ep"):
     return jax.device_put(table, NamedSharding(mesh, P(axis_name, None)))
 
 
-def _local_lookup(table_l, ids_l, axis_name, n=None):
-    """Per-device body: bucketed all_to_all exchange (see module doc).
-    ``n`` is the static axis size — callers pass mesh.shape[axis_name]
-    (lax.axis_size only exists on newer jax, and the size must be a
-    python int for the arange/bucket shapes anyway)."""
-    if n is None:
-        n = lax.axis_size(axis_name)
+def _local_lookup(table_l, ids_l, axis_name):
+    """Per-device body: bucketed all_to_all exchange (see module doc)."""
+    n = lax.axis_size(axis_name)  # static python int inside shard_map
     rows = table_l.shape[0]
     b = ids_l.shape[0]
     c = b  # bucket capacity: worst case all local ids on one shard
@@ -98,11 +93,9 @@ def make_sharded_embedding_fn(mesh, axis_name="ep", batch_axis=None):
     id_spec = (P((batch_axis, axis_name)) if batch_axis
                and batch_axis != axis_name else P(axis_name))
 
-    n = int(mesh.shape[axis_name])
-
     def lookup(table, ids):
-        return shard_map(
-            lambda t, i: _local_lookup(t, i.reshape(-1), axis_name, n),
+        return jax.shard_map(
+            lambda t, i: _local_lookup(t, i.reshape(-1), axis_name),
             mesh=mesh,
             in_specs=(P(axis_name, None), id_spec),
             out_specs=id_spec,

@@ -596,6 +596,13 @@ class DecodeEngine:
                 self._forward_chunk_shape(-shape[0], shape[1])
             else:
                 self._forward_decode_shape(*shape)
+        if self.pool.prefix_enabled:
+            # the copy-on-write page copy is one more compiled program
+            # of the serving loop (first prefix hit on a part-shared
+            # page): scratch onto scratch compiles it and moves nothing
+            with self._forward_lock:
+                self.pool.copy_pages(
+                    [(self.pool.scratch_page, self.pool.scratch_page)])
         return self
 
     def _shape_universe(self):

@@ -26,8 +26,8 @@ engine, bench leg and tests drive:
   interpret, the dense reference off it) and writing its new K/V page
   slot in place.
 
-All are ``jax.jit`` steps with ``donate_argnums=(0,)`` on the cache
-pytree — the decode analog of the encoder path's per-shape CachedOp
+All are ``jax.jit`` steps taking the weights as their first argument
+(never as closed-over constants) with the cache pytree donated — the decode analog of the encoder path's per-shape CachedOp
 executables (one compile per (rows, table-width) bucket, cached by
 jax) — so the page pool updates IN PLACE: steady-state decode performs
 no per-step cache-sized allocation (``MXNET_TPU_DECODE_DONATE=0``
@@ -156,7 +156,13 @@ class PagedCausalLM:
             p[f"l{i}_w2"] = w(4 * U, U)
             p[f"l{i}_b2"] = jnp.zeros((U,), dt)
         self.params = p
-        kw = {"donate_argnums": (0,)} if donate else {}
+        # The weights are an ARGUMENT of every compiled step, never a
+        # closed-over constant: a closure bakes them into each
+        # executable — at 12x768 every (rows, width) bucket then
+        # carried its own 760 MB copy (too big for the persistent
+        # cache, a copy each in HBM, and 40 GiB of host memory gone
+        # compiling a 13-shape warm-up).
+        kw = {"donate_argnums": (1,)} if donate else {}
         self._prefill = jax.jit(self._prefill_impl, **kw)
         self._chunk = jax.jit(self._prefill_chunk_impl, **kw)
         self._decode = jax.jit(self._decode_impl, **kw)
@@ -169,25 +175,23 @@ class PagedCausalLM:
                 "max_len": self.max_len}
 
     # -- shared pieces ------------------------------------------------------
-    def _qkv(self, h, i):
+    def _qkv(self, p, h, i):
         """(..., U) -> three (..., H, D) projections."""
-        p = self.params
         shape = h.shape[:-1] + (self.heads, self.head_dim)
         return ((h @ p[f"l{i}_wq"]).reshape(shape),
                 (h @ p[f"l{i}_wk"]).reshape(shape),
                 (h @ p[f"l{i}_wv"]).reshape(shape))
 
-    def _mlp(self, x, i):
+    def _mlp(self, p, x, i):
         import jax
 
-        p = self.params
         return jax.nn.gelu(
             x @ p[f"l{i}_w1"] + p[f"l{i}_b1"]) @ p[f"l{i}_w2"] \
             + p[f"l{i}_b2"]
 
-    def _ln(self, x, name):
-        return _layer_norm(x, self.params[f"{name}_g"],
-                           self.params[f"{name}_b"])
+    @staticmethod
+    def _ln(p, x, name):
+        return _layer_norm(x, p[f"{name}_g"], p[f"{name}_b"])
 
     def _write(self, caches, i, phys, off, k, v):
         """Scatter per-position K/V into layer ``i``'s page arrays.
@@ -199,7 +203,7 @@ class PagedCausalLM:
         return caches[:2 * i] + (kc, vc) + caches[2 * i + 2:]
 
     # -- prefill ------------------------------------------------------------
-    def _prefill_impl(self, caches, ids, length, phys, off,
+    def _prefill_impl(self, p, caches, ids, length, phys, off,
                       temp, top_k, top_p, seed):
         """One padded prompt row: ids (Lp,) int32, length scalar int32,
         phys/off (Lp,) page coordinates. Returns (first generated
@@ -208,7 +212,6 @@ class PagedCausalLM:
         K/V land in the pages for the decode steps to read back."""
         import jax.numpy as jnp
 
-        p = self.params
         lp = ids.shape[0]
         positions = jnp.minimum(jnp.arange(lp, dtype=jnp.int32),
                                 np.int32(self.max_len - 1))
@@ -218,8 +221,8 @@ class PagedCausalLM:
         causal = col <= row
         scale = np.float32(1.0 / np.sqrt(self.head_dim))
         for i in range(self.layers):
-            h = self._ln(x, f"l{i}_ln1")
-            q, k, v = self._qkv(h, i)          # (Lp, H, D)
+            h = self._ln(p, x, f"l{i}_ln1")
+            q, k, v = self._qkv(p, h, i)          # (Lp, H, D)
             caches = self._write(caches, i, phys, off, k, v)
             s = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32) * scale,
                            k.astype(jnp.float32))
@@ -230,14 +233,14 @@ class PagedCausalLM:
             o = jnp.einsum("hqk,khd->qhd", w_, v.astype(jnp.float32))
             x = x + o.reshape(lp, self.units).astype(x.dtype) \
                 @ p[f"l{i}_wo"]
-            x = x + self._mlp(self._ln(x, f"l{i}_ln2"), i)
+            x = x + self._mlp(p, self._ln(p, x, f"l{i}_ln2"), i)
         h_last = x[length - 1]
-        logits = self._ln(h_last, "lnf") @ p["head"]
+        logits = self._ln(p, h_last, "lnf") @ p["head"]
         tok = _sample_row(logits, temp, top_k, top_p, seed, length - 1)
         return tok, caches
 
     # -- chunked prefill ----------------------------------------------------
-    def _prefill_chunk_impl(self, caches, ids, start, valid, table,
+    def _prefill_chunk_impl(self, p, caches, ids, start, valid, table,
                             temp, top_k, top_p, seed):
         """One prompt SLICE through the paged kernel: ids (C,) int32
         with the ``valid`` real tokens FRONT-aligned (positions
@@ -262,7 +265,6 @@ class PagedCausalLM:
         from ..ops.pallas.flash_attention import (
             paged_attention_reference, paged_flash_attention)
 
-        p = self.params
         c = ids.shape[0]
         width = table.shape[0]
         page_size = caches[0].shape[2]
@@ -277,26 +279,27 @@ class PagedCausalLM:
         phys = jnp.where(live, table[page_idx], scratch)
         off = pos % np.int32(page_size)
         kvl = (start + np.int32(c))[None]           # (1,)
-        attend = (paged_flash_attention if _pallas.pallas_enabled()
+        attend = (paged_flash_attention
+                  if _pallas.pallas_ok_for(caches[0])
                   else paged_attention_reference)
         for i in range(self.layers):
-            h = self._ln(x, f"l{i}_ln1")
-            q, k, v = self._qkv(h, i)               # (C, H, D)
+            h = self._ln(p, x, f"l{i}_ln1")
+            q, k, v = self._qkv(p, h, i)               # (C, H, D)
             caches = self._write(caches, i, phys, off, k, v)
             o = attend(jnp.transpose(q, (1, 0, 2))[None],   # (1,H,C,D)
                        caches[2 * i], caches[2 * i + 1],
                        table[None], kvl)
             o = jnp.transpose(o[0], (1, 0, 2)).reshape(c, self.units)
             x = x + o.astype(x.dtype) @ p[f"l{i}_wo"]
-            x = x + self._mlp(self._ln(x, f"l{i}_ln2"), i)
+            x = x + self._mlp(p, self._ln(p, x, f"l{i}_ln2"), i)
         h_last = x[valid - 1]
-        logits = self._ln(h_last, "lnf") @ p["head"]
+        logits = self._ln(p, h_last, "lnf") @ p["head"]
         tok = _sample_row(logits, temp, top_k, top_p, seed,
                           start + valid - 1)
         return tok, caches
 
     # -- decode -------------------------------------------------------------
-    def _decode_impl(self, caches, ids, positions, tables,
+    def _decode_impl(self, p, caches, ids, positions, tables,
                      temps, top_ks, top_ps, seeds):
         """One continuous-batch iteration: ids/positions (R,) int32,
         tables (R, W) int32 page-table rows. Each row writes its new
@@ -310,7 +313,6 @@ class PagedCausalLM:
         from ..ops.pallas.flash_attention import (
             paged_attention_reference, paged_flash_attention)
 
-        p = self.params
         r = ids.shape[0]
         pos_c = jnp.minimum(positions, np.int32(self.max_len - 1))
         x = p["embed"][ids] + p["pos"][pos_c]       # (R, U)
@@ -320,18 +322,19 @@ class PagedCausalLM:
             axis=1)[:, 0]
         off = positions % np.int32(page_size)
         kvl = positions + np.int32(1)
-        attend = (paged_flash_attention if _pallas.pallas_enabled()
+        attend = (paged_flash_attention
+                  if _pallas.pallas_ok_for(caches[0])
                   else paged_attention_reference)
         for i in range(self.layers):
-            h = self._ln(x, f"l{i}_ln1")
-            q, k, v = self._qkv(h, i)               # (R, H, D)
+            h = self._ln(p, x, f"l{i}_ln1")
+            q, k, v = self._qkv(p, h, i)               # (R, H, D)
             caches = self._write(caches, i, phys, off, k, v)
             o = attend(q[:, :, None, :], caches[2 * i],
                        caches[2 * i + 1], tables, kvl)
             x = x + o[:, :, 0, :].reshape(r, self.units).astype(x.dtype) \
                 @ p[f"l{i}_wo"]
-            x = x + self._mlp(self._ln(x, f"l{i}_ln2"), i)
-        logits = self._ln(x, "lnf") @ p["head"]
+            x = x + self._mlp(p, self._ln(p, x, f"l{i}_ln2"), i)
+        logits = self._ln(p, x, "lnf") @ p["head"]
         import jax
 
         toks = jax.vmap(_sample_row)(logits, temps, top_ks, top_ps,
@@ -343,7 +346,8 @@ class PagedCausalLM:
                 temperature=0.0, top_k=0, top_p=1.0, seed=0):
         import jax.numpy as jnp
 
-        return self._prefill(caches, jnp.asarray(ids, jnp.int32),
+        return self._prefill(self.params, caches,
+                             jnp.asarray(ids, jnp.int32),
                              jnp.asarray(length, jnp.int32),
                              jnp.asarray(phys, jnp.int32),
                              jnp.asarray(off, jnp.int32),
@@ -356,7 +360,8 @@ class PagedCausalLM:
                       temperature=0.0, top_k=0, top_p=1.0, seed=0):
         import jax.numpy as jnp
 
-        return self._chunk(caches, jnp.asarray(ids, jnp.int32),
+        return self._chunk(self.params, caches,
+                           jnp.asarray(ids, jnp.int32),
                            jnp.asarray(start, jnp.int32),
                            jnp.asarray(valid, jnp.int32),
                            jnp.asarray(table, jnp.int32),
@@ -378,7 +383,7 @@ class PagedCausalLM:
                 return jnp.full((r,), fill, dt)
             return jnp.asarray(v, dt)
 
-        return self._decode(caches, ids,
+        return self._decode(self.params, caches, ids,
                             jnp.asarray(positions, jnp.int32),
                             jnp.asarray(tables, jnp.int32),
                             _vec(temperatures, 0.0, jnp.float32),

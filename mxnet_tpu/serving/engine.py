@@ -790,7 +790,7 @@ class ServingEngine:
         thread): submit + block for the result, JSON-serializable
         either way. Returns ``(http_status, body_dict)`` — admission
         errors carry their class name in ``error_type`` so the remote
-        router re-raises the same serving taxonomy. ``engine_ms`` (the
+        router re-raises the same serving error class. ``engine_ms`` (the
         engine-observed submit→result wall) rides back so the router
         can split its dispatch round trip into engine time vs
         transport overhead — the wire-vs-JSON comparison axis."""

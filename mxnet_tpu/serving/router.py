@@ -152,7 +152,7 @@ class RemoteEngineError(ServingError):
 _FAILOVER_ERRORS = (EngineStoppedError, QueueFullError, RemoteEngineError)
 
 # remote /submit error_type -> local exception class (anything unknown
-# lands on ServingError so callers still catch the serving taxonomy)
+# lands on ServingError so callers still catch the serving error family)
 _ERROR_CLASSES = {
     "QueueFullError": QueueFullError,
     "DeadlineExceededError": DeadlineExceededError,

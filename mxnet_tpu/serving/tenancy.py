@@ -143,7 +143,7 @@ class UnknownModelError(LookupError):
     """Submit names a ``model_id`` this engine/registry does not
     host. (A LookupError, not a ServingError subclass, so the model
     axis stays importable below ``queue.py``; the engine re-raises it
-    through the normal shed taxonomy.)"""
+    as a normal shed error.)"""
 
 
 class ModelRegistry:

@@ -376,7 +376,7 @@ class WireListener:
     ``/healthz`` as ``wire_port`` so a fronting router can upgrade its
     dispatch transport without configuration. The submit path is the
     ENGINE's — admission errors ride back as ERROR frames carrying the
-    serving taxonomy's class name, results as RESULT frames with the
+    serving error's class name, results as RESULT frames with the
     raw typed ndarray (no ``tolist()``) plus the request's amortized
     cost bill and the engine-observed wall (``engine_ms``, the router's
     dispatch-overhead baseline).
@@ -624,7 +624,7 @@ class WireListener:
         except Exception as e:
             # admission failure (queue full, too long, stopped,
             # malformed tokens): the class name rides back so the
-            # router re-raises the same serving taxonomy
+            # router re-raises the same serving error class
             writer.send((FRAME_ERROR, corr,
                          {"error_type": type(e).__name__,
                           "error": str(e),

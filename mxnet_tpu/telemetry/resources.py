@@ -96,24 +96,18 @@ def thread_count():
 
 
 def device_memory():
-    """``(bytes_in_use, live_buffer_bytes)`` — PJRT memory stats plus
-    the live-array byte total; each gracefully 0 when unavailable."""
-    in_use = live = 0
-    try:
-        import jax
-    except Exception:
-        return 0, 0
-    try:
-        stats = jax.devices()[0].memory_stats()
-        if stats:
-            in_use = int(stats.get("bytes_in_use", 0))
-    except Exception:
-        in_use = 0
-    try:
-        live = int(sum(a.nbytes for a in jax.live_arrays()))
-    except Exception:
-        live = 0
-    return in_use, live
+    """``(bytes_in_use, live_buffer_bytes)`` of the first local device.
+    An accelerator reports its allocator's own count through PJRT
+    ``memory_stats()`` and the second number is 0. The CPU backend
+    keeps no such stats, so there — and only there — the footprint is
+    the byte total of live jax.Arrays: what the framework allocated,
+    not what the runtime's pool holds."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return 0, int(sum(a.nbytes for a in jax.live_arrays()))
+    return int(dev.memory_stats()["bytes_in_use"]), 0
 
 
 def snapshot():

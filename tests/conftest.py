@@ -89,10 +89,25 @@ def pytest_sessionstart(session):
     record_invocations(RECORDED_OPS)
 
 
+@pytest.hookimpl(optionalhook=True)
+def pytest_testnodedown(node, error):
+    """xdist controller: fold a finished worker's recorded ops into
+    the set the gate below judges."""
+    RECORDED_OPS.update(getattr(node, "workeroutput", {})
+                        .get("recorded_ops", ()))
+
+
 def pytest_sessionfinish(session, exitstatus):
     _mxsan_gate(session)
     from mxnet_tpu.ndarray.register import record_invocations
     record_invocations(None)
+    if hasattr(session.config, "workerinput"):
+        # an xdist worker ran only its share of the files: the
+        # controller gates on the union (a worker — or a controller
+        # that ran nothing itself — judging alone would fail every
+        # green run)
+        session.config.workeroutput["recorded_ops"] = sorted(RECORDED_OPS)
+        return
     # only gate FULL runs (the driver's `pytest tests/`); -k / file
     # subsets would spuriously miss ops
     collected = getattr(session, "testscollected", 0)

@@ -308,8 +308,10 @@ with eng:
     ctl = chaos.controller()
     assert ctl is not None
     assert "armed0" in ctl._engines
+    # the fault counter ticks when the kill is ISSUED; the worker
+    # thread winds down a moment later — wait for both, one deadline
     deadline = time.monotonic() + 30
-    while time.monotonic() < deadline and ctl._seq < 1:
+    while time.monotonic() < deadline and (ctl._seq < 1 or eng.running):
         time.sleep(0.02)
     assert ctl._seq >= 1, "scheduled fault never injected"
     assert not eng.running          # kill_engine@0.1s did its job
@@ -338,10 +340,13 @@ def chaos_drill_env(monkeypatch, tmp_path):
     monkeypatch.setenv("MXNET_TPU_SLO_WINDOW_SCALE", "0.01")
     monkeypatch.setenv("MXNET_TPU_SLO_EVAL_S", "0.1")
     # margin matters: normal stub latency must stay WELL under the
-    # objective even instrumented (mxsan) — only the 80 ms hot-spot
-    # may breach it, or fleet-wide slow-burn tickets hold the
-    # incident open past the drill's patience
-    monkeypatch.setenv("MXNET_TPU_SLO_LATENCY_MS", "50")
+    # objective even instrumented (mxsan) or on a host shared with
+    # five other test workers — only the induced hot-spot (180 ms,
+    # below) may breach it, or fleet-wide slow-burn tickets hold the
+    # incident open past the drill's patience. At 50 ms / 80 ms the
+    # loaded host's own jitter crossed the objective; the scale is
+    # doubled (objectives snap to histogram boundaries: 50 → 100)
+    monkeypatch.setenv("MXNET_TPU_SLO_LATENCY_MS", "100")
     monkeypatch.setenv("MXNET_TPU_CANARY_INTERVAL_S", "0.25")
     monkeypatch.setenv("MXNET_TPU_CANARY_TIMEOUT_S", "5")
     monkeypatch.setenv("MXNET_TPU_FLIGHT_DIR", str(tmp_path / "flight"))
@@ -379,7 +384,7 @@ def test_chaos_drill_end_to_end(chaos_drill_env):
                              max_rows=2, engine_id=engine_id)
 
     report = run_chaos_drill(make_engine, n_engines=3, n_clients=6,
-                             hot_ms=80.0, phase_timeout_s=60.0,
+                             hot_ms=180.0, phase_timeout_s=60.0,
                              vocab=60, min_len=4, max_len=12)
     assert report["lost"] == 0
     assert report["completed"] == report["attempts"] > 0

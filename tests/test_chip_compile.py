@@ -1,0 +1,141 @@
+"""Main-path Pallas kernels COMPILE for a TPU v5e, at real widths.
+
+The chip's compiler is installed here and compiles for a chip that is
+described, not attached: this is what interpret mode cannot check —
+tile alignment, scoped-VMEM budgets, Mosaic's refusals. (It found the
+packed S=2048 flash backward overrunning VMEM at the default tiles.)
+A compile that passes is a compile: nothing runs, and nothing here is
+a chip measurement.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and every xdist worker
+imports every test file. All compile cases live in THIS one file for
+the same reason (a second file could land on another worker, whose
+fixture would skip it).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops.pallas import (flash_attention, layer_norm_fused,
+                                  lstm_layer_fused, paged_flash_attention,
+                                  softmax_xent_fused)
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip (the
+    # next run warns and recompiles): keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in shapes]
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+def _assert_kernels(text, *names):
+    assert "tpu_custom_call" in text
+    for name in names:
+        assert name in text, f"kernel {name} not in the compiled program"
+
+
+def _sum(x):
+    return x.astype(jnp.float32).sum()
+
+
+_FLASH = {
+    # name: (q shape, causal, kv_lens, segment_ids, (block_q, block_k))
+    "b64_s512": ((64, 12, 512, 64), False, False, False, None),
+    "b64_s512_kv_lens": ((64, 12, 512, 64), False, True, False, None),
+    "b8_s2048": ((8, 12, 2048, 64), False, False, False, None),
+    "packed_s2048": ((16, 12, 2048, 64), False, False, True, None),
+    "packed_s2048_causal": ((16, 12, 2048, 64), True, False, True, None),
+    "packed_s2048_256x256": ((16, 12, 2048, 64), False, False, True,
+                             (256, 256)),
+    "packed_s2048_causal_256x256": ((16, 12, 2048, 64), True, False, True,
+                                    (256, 256)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH))
+def test_flash_attention_fwd_bwd_compiles(one_chip, monkeypatch, case):
+    shape, causal, use_lens, use_segs, tiles = _FLASH[case]
+    if tiles:
+        monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_Q", str(tiles[0]))
+        monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_K", str(tiles[1]))
+    b, _, s, _ = shape
+
+    def step(q, k, v, lens, segs):
+        return jax.grad(lambda q, k, v: _sum(flash_attention(
+            q, k, v, None, causal, 0, False,
+            lens if use_lens else None, segs if use_segs else None)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(step, one_chip, (shape, BF16), (shape, BF16),
+                          (shape, BF16), ((b,), jnp.int32),
+                          ((b, s), jnp.int32))
+    _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd")
+
+
+def test_layer_norm_fwd_bwd_compiles(one_chip):
+    def step(x, g, b):
+        return jax.grad(lambda x, g, b: _sum(
+            layer_norm_fused(x, g, b, 1e-12, False)),
+            argnums=(0, 1, 2))(x, g, b)
+
+    text = _compiled_text(step, one_chip, ((32768, 768), BF16),
+                          ((768,), BF16), ((768,), BF16))
+    _assert_kernels(text, "mxtpu_layer_norm_fwd", "mxtpu_layer_norm_bwd")
+
+
+def test_softmax_xent_fwd_bwd_compiles(one_chip):
+    def step(logits, labels):
+        return jax.grad(lambda x: _sum(
+            softmax_xent_fused(x, labels, False)))(logits)
+
+    text = _compiled_text(step, one_chip, ((8192, 30522), BF16),
+                          ((8192,), jnp.int32))
+    _assert_kernels(text, "mxtpu_softmax_xent_fwd", "mxtpu_softmax_xent_bwd")
+
+
+@pytest.mark.parametrize("sq", [1, 64], ids=["decode", "chunked_prefill"])
+def test_paged_flash_attention_compiles(one_chip, sq):
+    fn = functools.partial(paged_flash_attention, interpret=False)
+    pool = ((2049, 12, 16, 64), BF16)
+    text = _compiled_text(fn, one_chip, ((32, 12, sq, 64), BF16), pool, pool,
+                          ((32, 64), jnp.int32), ((32,), jnp.int32))
+    _assert_kernels(text, "mxtpu_paged_flash_fwd")
+
+
+def test_lstm_fwd_bwd_compiles(one_chip):
+    def step(gin, w, h0, c0):
+        def loss(gin, w, h0, c0):
+            out, cseq = lstm_layer_fused(gin, w, h0, c0, False)
+            return _sum(out) + _sum(cseq[-1])
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(gin, w, h0, c0)
+
+    text = _compiled_text(step, one_chip, ((35, 128, 2600), BF16),
+                          ((650, 2600), BF16), ((128, 650), BF16),
+                          ((128, 650), BF16))
+    _assert_kernels(text, "mxtpu_lstm_fwd", "mxtpu_lstm_bwd")

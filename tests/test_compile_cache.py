@@ -42,9 +42,26 @@ class StubModel:
 # module units
 # ---------------------------------------------------------------------------
 
-def test_configure_respects_env_knobs(tmp_path, monkeypatch):
+@pytest.fixture
+def restore_cache_config():
+    """configure(force=True) re-points a process-wide jax setting:
+    put the session's own back afterwards."""
     import jax
 
+    saved = dict(compile_cache._state)
+    saved_dir = jax.config.jax_compilation_cache_dir
+    yield
+    compile_cache._state.update(saved)
+    jax.config.update("jax_compilation_cache_dir", saved_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      saved["min_s"] if saved["min_s"] is not None else 1.0)
+
+
+def test_configure_respects_env_knobs(tmp_path, monkeypatch,
+                                      restore_cache_config):
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv("MXNET_TPU_COMPILE_CACHE_DIR",
                        str(tmp_path / "cc"))
     monkeypatch.setenv("MXNET_TPU_COMPILE_CACHE_MIN_S", "0.25")
@@ -55,11 +72,52 @@ def test_configure_respects_env_knobs(tmp_path, monkeypatch):
     assert os.path.isdir(st["dir"])
     assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cc")
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.25
-    # idempotent: the no-arg call does not re-point anything
+    # idempotent: a plain call re-reads nothing
+    monkeypatch.setenv("MXNET_TPU_COMPILE_CACHE_DIR",
+                       str(tmp_path / "cc2"))
     assert compile_cache.configure()["dir"] == str(tmp_path / "cc")
-    # explicit argument wins over env
-    st = compile_cache.configure(cache_dir=str(tmp_path / "cc2"))
-    assert st["dir"] == str(tmp_path / "cc2")
+    assert compile_cache.configure(force=True)["dir"] \
+        == str(tmp_path / "cc2")
+
+
+def test_configure_yields_to_jax_own_env_var(tmp_path, monkeypatch,
+                                             restore_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: the cache lives there and NO code
+    path sets jax_compilation_cache_dir (the environment placed it)."""
+    import jax
+
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (updates.append(name), real_update(name, val))[1])
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outer"))
+    monkeypatch.setenv("MXNET_TPU_COMPILE_CACHE_DIR",
+                       str(tmp_path / "ignored"))
+    st = compile_cache.configure(force=True)
+    assert st["configured"] and st["dir"] == str(tmp_path / "outer")
+    assert "jax_compilation_cache_dir" not in updates
+    assert not (tmp_path / "ignored").exists()
+
+
+def test_default_cache_dir_is_fixed_in_the_checkout(tmp_path):
+    """Nothing set: a fresh process, whatever its $HOME and cwd, keeps
+    the cache in <checkout>/.jax_cache — never $HOME, a temp name, a
+    pid or a time (a cache that moves never hits)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", HOME=str(tmp_path),
+               PYTHONPATH=ROOT)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("MXNET_TPU_COMPILE_CACHE_DIR", None)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; from mxnet_tpu import compile_cache as cc; "
+         "print(cc.configure()['dir']); "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, timeout=120, env=env,
+        cwd=str(tmp_path))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [os.path.join(ROOT, ".jax_cache")] * 2
+    assert not list(tmp_path.iterdir())
 
 
 def test_configure_gate_off(monkeypatch):
@@ -196,11 +254,13 @@ def test_engine_snapshot_and_healthz_carry_cache_fields():
 # cross-process golden: the cache key survives a process restart
 # ---------------------------------------------------------------------------
 
-def _run_golden_worker(cache_dir):
+def _run_golden_worker(cache_dir, dir_var="MXNET_TPU_COMPILE_CACHE_DIR"):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MXNET_TPU_COMPILE_CACHE_DIR=str(cache_dir),
                MXNET_TPU_COMPILE_CACHE_MIN_S="0",
                MXNET_TPU_WATCHDOG="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("MXNET_TPU_COMPILE_CACHE_DIR", None)
+    env[dir_var] = str(cache_dir)
     out = subprocess.run(
         [sys.executable,
          os.path.join(ROOT, "tests", "compile_cache_worker.py")],
@@ -209,18 +269,21 @@ def _run_golden_worker(cache_dir):
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def test_cross_process_persistent_hit_golden(tmp_path):
+@pytest.mark.parametrize(
+    "dir_var", ["MXNET_TPU_COMPILE_CACHE_DIR", "JAX_COMPILATION_CACHE_DIR"])
+def test_cross_process_persistent_hit_golden(tmp_path, dir_var):
     """THE acceptance golden: process 1 cold-compiles (miss), process
     2 — same model, same bucket, same cache dir — serves off the disk
-    cache and records persistent_hit without a fresh backend compile."""
+    cache and records persistent_hit without a fresh backend compile.
+    Placed by either variable, the entries land in the named dir."""
     cache_dir = tmp_path / "shared_cache"
-    first = _run_golden_worker(cache_dir)
+    first = _run_golden_worker(cache_dir, dir_var)
     assert first["compile_cache"]["miss"] >= 1
     assert first["compile_cache"]["persistent_hit"] == 0
     assert first["state"]["dir"] == str(cache_dir)
     assert os.listdir(cache_dir), "nothing persisted to the cache dir"
 
-    second = _run_golden_worker(cache_dir)
+    second = _run_golden_worker(cache_dir, dir_var)
     assert second["compile_cache"]["persistent_hit"] >= 1
     assert second["compile_cache"]["miss"] == 0, \
         "second process recompiled despite the primed persistent cache"

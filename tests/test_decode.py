@@ -309,6 +309,40 @@ def test_donation_no_per_step_cache_allocation():
     assert grown < steps * cache_bytes / 8, (grown, steps, cache_bytes)
 
 
+def test_weights_are_step_arguments_not_baked_constants():
+    """Each compiled step takes the weights as an argument. Closed over,
+    they were baked into EVERY (rows, width) executable as constants —
+    invisible at toy size, 760 MB per bucket at 12x768 on the chip
+    (persistent cache refused the entries, the host ran out of memory
+    compiling the warm-up)."""
+    import jax
+
+    model = _mk_model(units=64, heads=4, layers=2)
+    weight_bytes = sum(a.nbytes for a in model.params.values())
+    with _mk_engine(model, page_size=8, n_pages=16, max_rows=2,
+                    prefill_bucket_lens=(8,)) as eng:
+        caches = eng.pool.caches
+    i32 = np.int32
+    zeros = np.zeros
+    traces = [
+        model._decode.trace(model.params, caches, zeros(2, i32),
+                            zeros(2, i32), zeros((2, 2), i32),
+                            zeros(2, np.float32), zeros(2, i32),
+                            np.ones(2, np.float32), zeros(2, i32)),
+        model._chunk.trace(model.params, caches, zeros(8, i32), i32(0),
+                           i32(8), zeros(2, i32), np.float32(0), i32(0),
+                           np.float32(1), i32(0)),
+        model._prefill.trace(model.params, caches, zeros(8, i32), i32(8),
+                             zeros(8, i32), zeros(8, i32), np.float32(0),
+                             i32(0), np.float32(1), i32(0)),
+    ]
+    assert weight_bytes > 100_000       # the bound must mean something
+    for t in traces:
+        baked = sum(getattr(c, "nbytes", 0) for c in t.jaxpr.consts)
+        assert baked < weight_bytes / 100, (baked, weight_bytes)
+    assert all(isinstance(a, jax.Array) for a in model.params.values())
+
+
 # ---------------------------------------------------------------------------
 # streamed dispatch: wire + HTTP chunked + router
 # ---------------------------------------------------------------------------

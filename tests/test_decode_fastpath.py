@@ -253,6 +253,24 @@ def test_prefix_hit_is_byte_identical_to_cold_run():
         assert isinstance(state["page_refcounts"], dict)
 
 
+def test_warmup_compiles_the_cow_page_copy():
+    """The copy-on-write page copy is a compiled program of the
+    serving loop too: warm-up must visit it, or the first prefix hit
+    on a part-shared page compiles in front of live traffic (seen on
+    the chip: one compile after a "complete" warm-up)."""
+    from mxnet_tpu.serving import kvcache
+
+    with _mk_engine(n_pages=37, prefill_bucket_lens=(8,),
+                    max_rows=1) as eng:     # 37: a geometry of its own
+        step = kvcache._copy_step(bool(eng.pool._donate))
+        before = step._cache_size()
+        eng.warmup(shapes=[])               # nothing but the copy
+        assert step._cache_size() == before + 1
+        with eng._forward_lock:
+            eng.pool.copy_pages([(0, 1)])
+        assert step._cache_size() == before + 1
+
+
 def test_chunked_prefill_interleaves_with_running_decode():
     import time
 

@@ -130,6 +130,48 @@ def test_trainer_step_one_reduce_dispatch(monkeypatch):
     assert calls[0] == 4  # 2 layers x (weight, bias)
 
 
+def test_unused_param_gradients_stay_on_their_replica(monkeypatch):
+    """A hybridized net with a parameter the loss never uses (BERT's
+    pooler under an MLM loss): its zero gradient — and the head's ones
+    — are CREATED by the reverse sweep, not computed from committed
+    arrays, and used to land on the process default device for every
+    replica. One such key made the kvstore decline the fused all-reduce
+    for the whole step and fall to the per-key path."""
+    class TwoHeads(gluon.HybridBlock):
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            with self.name_scope():
+                self.used = nn.Dense(3, in_units=6)
+                self.unused = nn.Dense(2, in_units=6)
+
+        def hybrid_forward(self, F, x):
+            self.unused(x)
+            return self.used(x)
+
+    per_key = []
+    real = kvstore.KVStore._reduce
+    monkeypatch.setattr(
+        kvstore.KVStore, "_reduce",
+        lambda self, arrays, key=None: (
+            per_key.append(key), real(self, arrays, key=key))[1])
+    net = TwoHeads()
+    net.initialize(ctx=CTXS)
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1}, kvstore="device")
+    xs = [nd.array(np.random.randn(4, 6).astype(np.float32), ctx=c)
+          for c in CTXS]
+    with autograd.record():
+        losses = [net(x).sum() for x in xs]
+    for l in losses:
+        l.backward()
+    for p in net.collect_params().values():
+        assert [g._data.device for g in p.list_grad()] \
+            == [c.jax_device for c in CTXS], p.name
+    trainer.step(4 * len(CTXS))
+    assert per_key == [], "fused pushpull declined"
+
+
 def test_row_sparse_pull_dense_and_sparse_dst():
     """On-device sparse pull: requested rows land in the dst (dense or
     row_sparse), duplicates merged, untouched rows zero — with no numpy

@@ -16,7 +16,7 @@ SRC = Path(__file__).resolve().parents[1] / "src" / "cc"
 @pytest.mark.skipif(shutil.which("make") is None or shutil.which("g++") is None,
                     reason="native toolchain unavailable")
 def test_native_io_cpp_suite(tmp_path):
-    build = subprocess.run(["make", "-C", str(SRC), "test_io"],
+    build = subprocess.run(["make", "-B", "-C", str(SRC), "test_io"],
                            capture_output=True, text=True, timeout=300)
     assert build.returncode == 0, build.stderr
     run = subprocess.run([str(SRC / "test_io"), str(tmp_path)],

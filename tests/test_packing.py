@@ -1,14 +1,10 @@
 """Sequence packing (io/packing.py): round-trip exactness, layout
-contract, data-layer wiring, and the packed bench leg's smoke.
+contract and data-layer wiring.
 
 The segment-isolation numerics (packed == unpacked through the flash
 kernel and the full BERT stack) live in test_pallas.py /
 test_transformer.py; this file owns the packing layer itself.
 """
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -190,30 +186,3 @@ def test_segment_valid_len_op_dispatch():
                             np.int32), dtype="int32")
     out = nd.segment_valid_len(seg)
     assert out.asnumpy().tolist() == [4, 1]
-
-
-@pytest.mark.slow
-def test_bench_packed_leg_smoke():
-    """bench.py BENCH_PACKED=1 runs end-to-end at toy size and reports
-    the packed-leg metrics (packing_efficiency, valid_tokens_per_sec)."""
-    import json
-
-    env = dict(os.environ, BENCH_MODEL="bert", BENCH_PACKED="1",
-               BENCH_STEPS="2", BENCH_CHAIN="1", BENCH_WINDOWS="1",
-               BENCH_BATCH="4", BENCH_SEQLEN="64",
-               BENCH_PACK_ROWLEN="128", JAX_PLATFORMS="cpu")
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    r = subprocess.run([sys.executable, bench], env=env,
-                       capture_output=True, text=True, timeout=560)
-    assert r.returncode == 0, r.stderr[-2000:]
-    line = [ln for ln in r.stdout.splitlines()
-            if ln.startswith('{"metric"')][-1]
-    rec = json.loads(line)
-    assert rec["packed"] is True
-    assert rec["packing_efficiency"] >= 0.9
-    assert rec["valid_tokens_per_sec"] > 0
-    # honest HBM accounting: the cost-model fallback must be flagged
-    assert rec.get("hbm_est", False) in (True, False)
-    if "hbm_frac" in rec and rec["hbm_frac"] > 1.0:
-        assert rec["hbm_est"] is True

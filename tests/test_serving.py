@@ -468,107 +468,6 @@ def test_loaded_traffic_packs_densely():
     assert snap["latency"]["queue"]["count"] == 96
 
 
-@pytest.mark.slow
-def test_bench_serving_leg_smoke():
-    """bench.py BENCH_MODEL=serving end-to-end at toy size: emits the
-    serving metric line with latency percentiles and packing stats."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ, BENCH_MODEL="serving", BENCH_SEQLEN="32",
-               BENCH_VOCAB="200", BENCH_SERVE_UNITS="32",
-               BENCH_SERVE_LAYERS="1", BENCH_SERVE_HEADS="2",
-               BENCH_SERVE_CLIENTS="8", BENCH_SERVE_REQS="4",
-               BENCH_SERVE_ROWS="4", BENCH_SERVE_BUCKETS="8,32",
-               JAX_PLATFORMS="cpu")
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    r = subprocess.run([sys.executable, bench], env=env,
-                       capture_output=True, text=True, timeout=560)
-    assert r.returncode == 0, r.stderr[-2000:]
-    rec = json.loads([ln for ln in r.stdout.splitlines()
-                      if ln.startswith('{"metric"')][-1])
-    assert rec["metric"] == "bert_serving_requests_per_sec_per_chip"
-    assert rec["value"] > 0
-    assert rec["requests"] == 32          # zero lost under the limit
-    assert rec["p50_ms"] > 0 and rec["p99_ms"] >= rec["p50_ms"]
-    assert 0 < rec["packing_efficiency"] <= 1.0
-
-
-@pytest.mark.slow
-def test_bench_serving_router_leg_smoke():
-    """bench.py BENCH_MODEL=serving_router end-to-end at toy size:
-    2 engines behind the router, per-engine share + failover count in
-    the metric line, aggregated-/metrics reconciliation asserted."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ, BENCH_MODEL="serving_router",
-               BENCH_SEQLEN="32", BENCH_VOCAB="200",
-               BENCH_SERVE_UNITS="32", BENCH_SERVE_LAYERS="1",
-               BENCH_SERVE_HEADS="2", BENCH_SERVE_CLIENTS="6",
-               BENCH_SERVE_REQS="4", BENCH_SERVE_ROWS="2",
-               BENCH_SERVE_BUCKETS="8,32", JAX_PLATFORMS="cpu")
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    r = subprocess.run([sys.executable, bench], env=env,
-                       capture_output=True, text=True, timeout=560)
-    assert r.returncode == 0, r.stderr[-2000:]
-    recs = {rec["metric"]: rec for rec in
-            (json.loads(ln) for ln in r.stdout.splitlines()
-             if ln.startswith('{"metric"'))}
-    rec = recs["bert_serving_router_requests_per_sec"]
-    assert rec["value"] > 0
-    assert rec["requests"] == 24
-    assert rec["engines"] == 2 and rec["engines_up"] == 2
-    assert set(rec["per_engine"]) == {"e0", "e1"}
-    assert abs(sum(rec["per_engine"].values()) - 1.0) < 0.01
-    assert rec["failover"] == 0
-    assert rec["telemetry_reconciled"] is True
-    # the wire-vs-JSON A/B record from the same leg: binary framing
-    # must beat JSON on serialized bytes and dispatch overhead
-    wab = recs["bert_serving_router_wire_requests_per_sec"]
-    assert wab["value"] > 0 and wab["transport"] == "wire"
-    assert wab["wire"]["bytes_per_request"] \
-        < wab["json"]["bytes_per_request"]
-    assert wab["wire"]["dispatch_overhead_p50_ms"] \
-        < wab["json"]["dispatch_overhead_p50_ms"]
-    assert wab["bytes_per_request_ratio"] < 1.0
-    assert wab["wire"]["fallbacks"] == 0
-
-
-@pytest.mark.slow
-def test_bench_packed_causal_leg_smoke():
-    """bench.py BENCH_MODEL=causal_lm (the packed CAUSAL ROADMAP
-    follow-up) runs end-to-end at toy size."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ, BENCH_MODEL="causal_lm", BENCH_STEPS="2",
-               BENCH_CHAIN="1", BENCH_WINDOWS="1", BENCH_BATCH="2",
-               BENCH_SEQLEN="32", BENCH_PACK_ROWLEN="64",
-               BENCH_VOCAB="200", BENCH_LM_UNITS="32",
-               BENCH_LM_LAYERS="1", BENCH_LM_HEADS="2",
-               JAX_PLATFORMS="cpu")
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    r = subprocess.run([sys.executable, bench], env=env,
-                       capture_output=True, text=True, timeout=560)
-    assert r.returncode == 0, r.stderr[-2000:]
-    rec = json.loads([ln for ln in r.stdout.splitlines()
-                      if ln.startswith('{"metric"')][-1])
-    assert rec["metric"] == "causal_lm_train_tokens_per_sec_per_chip"
-    assert rec["causal"] is True and rec["packed"] is True
-    assert rec["packing_efficiency"] >= 0.9
-    assert rec["valid_tokens_per_sec"] > 0
-
-
 # ---------------------------------------------------------------------------
 # engine-labeled metric families (ROADMAP per-chip router metrics)
 # ---------------------------------------------------------------------------

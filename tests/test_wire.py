@@ -89,7 +89,7 @@ def test_frame_cap_refused_before_allocation():
         a.sendall(struct.pack("<Q", 1 << 40))
         with pytest.raises(FrameTooLargeError) as ei:
             recv_frame(b, max_frame=1024)
-        # both historical refusal taxonomies hold
+        # both historical refusal hierarchies hold
         from mxnet_tpu.base import MXNetError
         assert isinstance(ei.value, (MXNetError,))
         assert isinstance(ei.value, ValueError)

@@ -54,6 +54,11 @@ def main():
                    f"cd {os.getcwd()} && {env} {' '.join(args.command)}"]
             procs.append(subprocess.Popen(cmd))
     else:
+        # This launcher imports no jax (standard library only), so it
+        # never holds a chip itself. A chip belongs to ONE process:
+        # workers sharing a TPU host must each be given their own
+        # (e.g. TPU_VISIBLE_DEVICES per rank in the caller's env) or
+        # run on the CPU backend, as the dist tests do.
         for rank in range(args.num_workers):
             env = dict(os.environ)
             # local launcher: every worker shares this host

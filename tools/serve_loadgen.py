@@ -550,7 +550,7 @@ class RouterClient:
             attempt += 1
         # the last router-shaped error body (e.g. a single router
         # answering "fleet down") still maps onto the serving
-        # taxonomy; with nothing parseable it's a client shed
+        # error classes; with nothing parseable it's a client shed
         if last_body is not None:
             return self._deliver(fut, last_body)
         raise NoEngineAvailableError(
