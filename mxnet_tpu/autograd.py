@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import profiler as _profiler
 from .base import MXNetError
 
 __all__ = [
@@ -193,6 +194,13 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     Mirrors MXAutogradBackwardEx semantics: default head gradient is
     ones; grad_req 'write' overwrites, 'add' accumulates, 'null' skips.
     """
+    with _profiler.span("mxtpu/autograd/backward") as sp:
+        sp.set(nodes=_sweep(heads, head_grads, retain_graph))
+
+
+def _sweep(heads, head_grads, retain_graph):
+    """`backward`'s body; returns the number of tape nodes whose
+    pullback ran."""
     from .ndarray.ndarray import NDArray
 
     if isinstance(heads, NDArray):
@@ -222,10 +230,11 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
                 "backward() head was not computed inside autograd."
                 "record() (or was mutated since); gradients will not "
                 "flow through it — did you call .sum() on the loss "
-                "AFTER the record block?", stacklevel=2)
+                "AFTER the record block?", stacklevel=3)
     touched_leaves = []
     leaf_slots: dict = {}  # id(leaf) → set of tape value-keys it fed
     used_nodes: set = set()  # nodes this sweep consumed (freed below)
+    swept = 0
     for node in reversed(tape):
         if all(r() is None for r in node.out_refs):
             # every output collected → no live head/consumer can reach
@@ -237,6 +246,7 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
         if all(c is None for c in outs_cot):
             continue
         used_nodes.add(id(node))
+        swept += 1
         # assemble cotangent structure matching the vjp output structure
         if node.raw_multi:
             # visible outputs lead; hidden raw outputs get zeros. We can
@@ -319,6 +329,7 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
                     if o is not None:
                         o._released = True
         _STATE.tape = [n for n in _STATE.tape if id(n) not in used_nodes]
+    return swept
 
 
 def _on_device_of(cots):

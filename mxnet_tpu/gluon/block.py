@@ -36,6 +36,7 @@ from ..ndarray import NDArray
 from ..ndarray.ndarray import _wrap
 from ..ndarray.register import Op, invoke
 from .. import autograd as _autograd
+from .. import profiler as _profiler
 from .. import random as _random
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
@@ -513,39 +514,46 @@ class HybridBlock(Block):
 
     # -- the CachedOp analog ----------------------------------------------
     def _call_cached_op(self, *args):
-        inputs = [a for a in args if isinstance(a, NDArray)]
-        ctx = inputs[0].ctx if inputs else current_context()
-        # make sure all params are concrete (deferred init finalized by an
-        # eager dry-run if needed)
-        try:
-            params = list(self.collect_params().values())
-            param_arrays = [p.data(ctx) for p in params]
-        except DeferredInitializationError:
-            with _autograd.pause(), _trace_guard():
-                self.forward(*args)
-            params = list(self.collect_params().values())
-            param_arrays = [p.data(ctx) for p in params]
+        with _profiler.span("mxtpu/cachedop/call", block=self.name) as sp:
+            inputs = [a for a in args if isinstance(a, NDArray)]
+            ctx = inputs[0].ctx if inputs else current_context()
+            # make sure all params are concrete (deferred init finalized
+            # by an eager dry-run if needed)
+            try:
+                params = list(self.collect_params().values())
+                param_arrays = [p.data(ctx) for p in params]
+            except DeferredInitializationError:
+                with _autograd.pause(), _trace_guard():
+                    self.forward(*args)
+                params = list(self.collect_params().values())
+                param_arrays = [p.data(ctx) for p in params]
 
-        training = _autograd.is_training()
-        from ..ndarray.register import dispatch_cast_generation
-        key = (tuple((tuple(a.shape), str(a.dtype)) for a in inputs), training,
-               dispatch_cast_generation())  # AMP on/off → fresh trace
-        entry = self._cached_graph.get(key)
-        if entry is None:
-            entry = self._build_cached_op(args, inputs, params, ctx, training)
-            self._cached_graph[key] = entry
-        op, structure, aux_params, n_flat_out = entry
+            training = _autograd.is_training()
+            from ..ndarray.register import dispatch_cast_generation
+            key = (tuple((tuple(a.shape), str(a.dtype)) for a in inputs),
+                   training,
+                   dispatch_cast_generation())  # AMP on/off → fresh trace
+            entry = self._cached_graph.get(key)
+            sp.set(built=int(entry is None))
+            if entry is None:
+                # the trace; the compile is this call's `invoke` below
+                _profiler.count("cachedop_builds")
+                with _profiler.span("mxtpu/cachedop/build", block=self.name):
+                    entry = self._build_cached_op(args, inputs, params, ctx,
+                                                  training)
+                self._cached_graph[key] = entry
+            op, structure, aux_params, n_flat_out = entry
 
-        rng = _wrap(_random._next_key(), ctx)
-        results = invoke(op, [rng] + inputs + param_arrays, {}, ctx=ctx)
-        if not isinstance(results, list):
-            results = [results]
-        flat_out, aux_out = results[:n_flat_out], results[n_flat_out:]
-        # write back running stats
-        with _autograd.pause():
-            for p, new in zip(aux_params, aux_out):
-                p.data(ctx)._set_data(new._data)
-        return _unflatten(flat_out, structure)
+            rng = _wrap(_random._next_key(), ctx)
+            results = invoke(op, [rng] + inputs + param_arrays, {}, ctx=ctx)
+            if not isinstance(results, list):
+                results = [results]
+            flat_out, aux_out = results[:n_flat_out], results[n_flat_out:]
+            # write back running stats
+            with _autograd.pause():
+                for p, new in zip(aux_params, aux_out):
+                    p.data(ctx)._set_data(new._data)
+            return _unflatten(flat_out, structure)
 
     def _build_cached_op(self, args, inputs, params, ctx, training):
         """Trace hybrid_forward into a jitted function (CachedOp ctor)."""

@@ -16,6 +16,7 @@ from __future__ import annotations
 from ..base import MXNetError
 from .. import optimizer as opt
 from .. import kvstore as _kvstore_mod
+from .. import profiler as _profiler
 from .parameter import ParameterDict, Parameter
 
 __all__ = ["Trainer"]
@@ -126,13 +127,15 @@ class Trainer:
 
     def step(self, batch_size, ignore_stale_grad=False):
         """allreduce grads + update (reference Trainer.step)."""
-        if not self._kv_initialized:
-            self._init_kvstore()
-        if self._params_to_init:
-            self._init_params()
-        self._optimizer.rescale_grad = self._scale / batch_size
-        self._allreduce_grads()
-        self._update(ignore_stale_grad)
+        with _profiler.span("mxtpu/trainer/step", batch_size=batch_size) as sp:
+            if not self._kv_initialized:
+                self._init_kvstore()
+            if self._params_to_init:
+                self._init_params()
+            self._optimizer.rescale_grad = self._scale / batch_size
+            self._allreduce_grads()
+            self._update(ignore_stale_grad)
+            sp.set(step=self._optimizer.num_update)
 
     def allreduce_grads(self):
         if not self._kv_initialized:
@@ -145,6 +148,12 @@ class Trainer:
         self._allreduce_grads()
 
     def _allreduce_grads(self):
+        with _profiler.span("mxtpu/trainer/allreduce") as sp:
+            sp.set(keys=self._reduce_grads())
+
+    def _reduce_grads(self):
+        """Sum every multi-replica gradient in place; returns the number
+        of keys (parameters) that took part."""
         if self._kvstore is None:
             # no kvstore configured, but multi-replica params still need
             # the sum — otherwise _update's update-once-and-broadcast
@@ -169,13 +178,15 @@ class Trainer:
                     pending.append(g)
             if pending:
                 comm.reduce_grad_ndarrays_inplace(pending)
-            return
+            return len(pending)
         if self._update_on_kvstore:
+            pushed = 0
             for i, param in enumerate(self._params):
                 if param.grad_req != "null":
                     # push grads; optimizer runs in kvstore; pull weights
                     self._kvstore.push(i, param.list_grad())
-            return
+                    pushed += 1
+            return pushed
         # batch every key into ONE fused pushpull: the kvstore reduces the
         # whole gradient set in a single compiled XLA computation (the
         # kvstore_nccl.h fused-pushpull analog; bucketing is the
@@ -190,6 +201,7 @@ class Trainer:
                     grads.append(g)
         if keys:
             self._kvstore.pushpull(keys, grads, out=grads)
+        return len(keys)
 
     def update(self, batch_size, ignore_stale_grad=False):
         if not self._kv_initialized:
@@ -210,6 +222,12 @@ class Trainer:
         replica) at the same traffic as a kvstore pull. Dense params
         batch into a single fused multi-tensor op
         (multi_sgd_* analog; Updater.update_multi)."""
+        with _profiler.span("mxtpu/trainer/update") as sp:
+            sp.set(params=self._apply_updates(ignore_stale_grad))
+
+    def _apply_updates(self, ignore_stale_grad):
+        """`_update`'s body; returns the number of parameters the
+        optimizer updated here (not those pulled from the kvstore)."""
         from ..ndarray.sparse import BaseSparseNDArray
 
         batch_idx, batch_w, batch_g, batch_bcast = [], [], [], []
@@ -234,6 +252,7 @@ class Trainer:
         for src, rest in batch_bcast:
             for dst in rest:
                 src.copyto(dst)
+        return len(batch_bcast)
 
     def save_states(self, fname):
         assert self._optimizer is not None

@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 
 from . import envvars
+from . import profiler as _profiler
 from .base import MXNetError
 from .ndarray import NDArray
 from .ndarray.ndarray import _wrap
@@ -134,10 +135,14 @@ class KVStore:
         Trainer.step dispatches exactly one executable per step."""
         keys, values = _normalize(key, value)
         outs = values if out is None else _normalize(key, out)[1]
-        if self._updater is None and self._try_fused_pushpull(keys, values, outs):
-            return
-        self.push(key, value, priority)
-        self.pull(key, out if out is not None else value, priority)
+        with _profiler.span("mxtpu/kvstore/pushpull", keys=len(keys)) as sp:
+            if sp.live:
+                sp.set(bytes=_payload_bytes(values))
+            if self._updater is None and \
+                    self._try_fused_pushpull(keys, values, outs):
+                return
+            self.push(key, value, priority)
+            self.pull(key, out if out is not None else value, priority)
 
     # -- fused reduce fast path -------------------------------------------
     def _reduce_devices(self, value_lists):
@@ -1142,3 +1147,10 @@ def _normalize(key, value):
     if isinstance(key, (list, tuple)):
         return list(key), list(value)
     return [key], [value]
+
+
+def _payload_bytes(values):
+    """Bytes one replica contributes to a reduce: the first array of
+    every key (a sparse array counts the values it stores)."""
+    return sum((v[0] if isinstance(v, (list, tuple)) else v)._data.nbytes
+               for v in values)

@@ -27,7 +27,6 @@ from __future__ import annotations
 import ast
 import functools
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +35,7 @@ import numpy as np
 from ..base import MXNetError, _Registry, dtype_np
 from ..context import Context, current_context
 from ..engine import engine
+from ..profiler import count as _count, op_span as _op_span
 
 __all__ = ["Op", "register_op", "invoke", "populate_namespace", "OP_REGISTRY"]
 
@@ -182,15 +182,6 @@ def set_dispatch_cast_hook(fn):
     _DISPATCH_CAST_GENERATION += 1
 
 
-def _profiler_running():
-    """Cheap hot-path probe: bound once so op dispatch pays one call,
-    not a module import, when profiling is off."""
-    global _profiler_running
-    from ..profiler import is_running
-    _profiler_running = is_running
-    return is_running()
-
-
 def dispatch_cast_generation():
     return _DISPATCH_CAST_GENERATION
 
@@ -268,24 +259,20 @@ def invoke(op: Op, inputs, params=None, out=None, ctx: Context | None = None,
         and any(isinstance(x, NDArray) for x in inputs)
     )
 
-    profiling = _profiler_running()
-    if profiling:
-        from .. import profiler as _profiler
-        t0_us = time.perf_counter_ns() // 1000
+    _count("invokes")
     device = ctx.jax_device
-    with jax.default_device(device):
+    # `mxtpu/op/<name>` while a profiler is active (the shared no-op
+    # otherwise): the dispatch-side op event (ThreadedEngine
+    # ProfileOperator analog; the device timeline is the jax profiler's
+    # — execution is async under PJRT, so this measures trace+dispatch,
+    # which equals execution under MXNET_ENGINE_TYPE=NaiveEngine)
+    with _op_span(op.name), jax.default_device(device):
         if record:
             fn = functools.partial(_call_positional, op, params, len(arrays))
             raw_out, vjp_fn = jax.vjp(fn, *arrays)
         else:
             raw_out = _call_positional(op, params, len(arrays), *arrays)
             vjp_fn = None
-    if profiling:
-        # dispatch-side op event (ThreadedEngine ProfileOperator analog;
-        # device timeline comes from the XProf delegation — execution is
-        # async under PJRT, so this measures trace+dispatch, which equals
-        # execution under MXNET_ENGINE_TYPE=NaiveEngine)
-        _profiler.record_op(op.name, t0_us, time.perf_counter_ns() // 1000)
 
     multi = isinstance(raw_out, (tuple, list))
     out_arrays = list(raw_out) if multi else [raw_out]

@@ -6,6 +6,14 @@ device timeline to jax.profiler (XProf/TensorBoard traces) — the
 SURVEY §5.1 plan. Op-level wall stats are collected at the dispatch
 layer when profiling is on and dumped as Chrome trace-event JSON, same
 consumption path (chrome://tracing) as the reference's profiler output.
+
+The Gluon training path's spans (`span`, `op_span`, `Scope`) are one
+primitive: a ``jax.profiler.TraceAnnotation`` whenever a jax profiler
+session is live, plus a `record_op` event under ``set_state('run')``;
+`active()` says whether either holds. `count(name)` bumps and
+`counters()` gives the always-on dispatch counts: the spans difference
+`invokes`; a training loop polls `cachedop_builds` with tracing off to
+catch a re-trace after warm-up (README, "Profiling a training step").
 """
 from __future__ import annotations
 
@@ -21,7 +29,7 @@ from .telemetry.trace import current_trace_id as _current_trace_id
 __all__ = ["set_config", "set_state", "state", "dump", "dumps", "pause",
            "resume", "Task", "Frame", "Event", "Counter", "Marker",
            "profiler_set_config", "profiler_set_state", "Scope",
-           "export_metrics"]
+           "export_metrics", "span", "active", "count", "counters"]
 
 _CONFIG = {
     "filename": "profile.json",
@@ -37,6 +45,13 @@ _STATE = {"running": False, "jax_trace": False}
 _EVENTS: list = []
 _AGGREGATE: dict = {}
 _LOCK = threading.Lock()
+# Always-on dispatch counts (`count` / `counters()`): `register.invoke`
+# bumps "invokes", `HybridBlock._call_cached_op` "cachedop_builds".
+# Not locked: a span reads the difference on its own thread, which is
+# exact while no other thread dispatches (a training loop).
+_COUNTS = {"invokes": 0, "cachedop_builds": 0}
+# bound once: `active()` is the one test `invoke` pays per op when off
+_session_live = jax.profiler.TraceAnnotation.is_enabled
 
 
 def set_config(**kwargs):
@@ -95,6 +110,28 @@ def peak_memory_bytes():
 
 def is_running():
     return _STATE["running"]
+
+
+def active():
+    """Do spans record now? Under ``set_state('run')``, or while ANY jax
+    profiler session is live (``jax.profiler.start_trace``, the xprof
+    server): whoever starts a device trace gets the program's spans in
+    it with no further switch."""
+    return _STATE["running"] or _session_live()
+
+
+def count(name):
+    """Bump one of the always-on counts (the dispatch layer's call)."""
+    _COUNTS[name] += 1
+
+
+def counters():
+    """The always-on dispatch counts, whether or not anything records:
+    ``invokes`` (`register.invoke` calls: what a span's ``invokes`` is
+    the difference of) and ``cachedop_builds`` (traces of hybridized
+    blocks: flat once every shape is warm, so a loop that logs it beside
+    its step time sees a re-trace without a profiler session)."""
+    return dict(_COUNTS)
 
 
 def _device_bytes_in_use():
@@ -253,40 +290,106 @@ class Marker(_Named):
         record_op(self.name, now, now, "marker")
 
 
-class Scope:
-    """with profiler.Scope('fwd'): ... — custom range.
+class _NoSpan:
+    """What `span` hands out while nothing records: one shared object."""
+    __slots__ = ()
+    live = False
 
-    Stamps the active telemetry trace id (serving request ids minted at
-    ``ServingEngine.submit``) into both the Chrome-trace event ``args``
-    and the xprof TraceAnnotation metadata, so one request correlates
-    across the wall-clock and device timelines. Degrades to
-    wall-clock-only when ``jax.profiler.TraceAnnotation`` raises (a
-    broken device-trace backend must not take the serving worker down,
-    and the started wall-clock Task must still be closed)."""
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One open span: a ``jax.profiler.TraceAnnotation`` (so it sits in
+    the profiler's trace, on the device's clock, nested by the thread's
+    own stack) and, under ``set_state('run')``, a `record_op` event for
+    `dump()` / `dumps()`. Degrades to the wall-clock event alone when
+    the annotation raises (a broken device-trace backend must not take
+    a serving worker down)."""
+    __slots__ = ("name", "attrs", "category", "record_as", "_ann", "_t0_us",
+                 "_invokes0")
+    live = True
+
+    def __init__(self, name, attrs, category="span", record_as=None):
+        self.name, self.attrs = name, attrs
+        self.category, self.record_as = category, record_as or name
+
+    def set(self, **attrs):
+        """Attributes known only before exit (counts, flags)."""
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        self._invokes0 = _COUNTS["invokes"]
+        self._t0_us = (time.perf_counter_ns() // 1000
+                       if _STATE["running"] else None)
+        try:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        except Exception:
+            self._ann = None          # wall-clock-only span
+        return self
+
+    def __exit__(self, *exc):
+        if self.category == "span":
+            self.attrs["invokes"] = _COUNTS["invokes"] - self._invokes0
+        if self._ann is not None:
+            try:
+                if self.attrs:
+                    self._ann.set_metadata(**self.attrs)
+                self._ann.__exit__(*exc)
+            except Exception:
+                pass
+        if self._t0_us is not None:
+            record_op(self.record_as, self._t0_us,
+                      time.perf_counter_ns() // 1000, self.category,
+                      args=self.attrs)
+        return False
+
+
+def span(name, **attrs):
+    """``with profiler.span('mxtpu/trainer/step', batch_size=n) as sp:``
+    — the one span primitive of the training path. Not `active()`: the
+    shared no-op, no allocation, no clock read. Active: the span lands in
+    the live jax profiler trace with ``attrs`` (and what ``sp.set(...)``
+    adds before exit) in the event's stats, plus ``invokes``, the number
+    of `register.invoke` calls made inside it; under ``set_state('run')``
+    it is also a ``category='span'`` event of `dump()` and a row of
+    `dumps()`. ``sp.live`` says whether attributes are worth computing."""
+    return _Span(name, attrs) if active() else _NO_SPAN
+
+
+def op_span(op_name):
+    """`register.invoke`'s span around one op's dispatch:
+    ``mxtpu/op/<op_name>`` in the trace; under ``set_state('run')`` the
+    operator event keeps the op's bare name, as `dumps()` lists it."""
+    if not active():
+        return _NO_SPAN
+    return _Span("mxtpu/op/" + op_name, None, "operator", op_name)
+
+
+class Scope:
+    """with profiler.Scope('fwd'): ... — custom range: a `span` that
+    also carries the active telemetry trace id (serving request ids
+    minted at ``ServingEngine.submit``), so one request correlates
+    across the wall-clock and device timelines."""
 
     def __init__(self, name="scope"):
         self.name = name
 
     def __enter__(self):
         tid = _current_trace_id()
-        self._t = Task(name=self.name,
-                       args={"trace_id": tid} if tid else None)
-        self._t.start()
-        self._jax_ctx = None
-        try:
-            ctx = (jax.profiler.TraceAnnotation(self.name, trace_id=tid)
-                   if tid else jax.profiler.TraceAnnotation(self.name))
-            ctx.__enter__()
-            self._jax_ctx = ctx
-        except Exception:
-            pass                      # wall-clock-only scope
+        self._span = span(self.name, **({"trace_id": tid} if tid else {}))
+        self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
-        if self._jax_ctx is not None:
-            try:
-                self._jax_ctx.__exit__(*exc)
-            except Exception:
-                pass
-        self._t.stop()
-        return False
+        return self._span.__exit__(*exc)

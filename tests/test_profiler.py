@@ -174,3 +174,280 @@ def test_profile_memory_samples_device_bytes():
     from mxnet_tpu.profiler import _EVENTS
     assert any("args" in e and "bytes_in_use" in e.get("args", {})
                for e in _EVENTS)
+
+
+# ---------------------------------------------------------------------------
+# spans of the Gluon training path (profiler.span / active / counters)
+# ---------------------------------------------------------------------------
+
+SPAN_TABLE = ("mxtpu/cachedop/call", "mxtpu/cachedop/build",
+              "mxtpu/autograd/backward", "mxtpu/trainer/step",
+              "mxtpu/trainer/allreduce", "mxtpu/trainer/update")
+
+
+def _small_loop():
+    """A hybridized two-layer block, a Trainer, and one
+    record → backward → step as a callable."""
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu"), nn.Dense(3))
+    net.initialize()
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "adam",
+                            {"learning_rate": 1e-3})
+    x = nd.ones((8, 6))
+
+    def step():
+        with autograd.record():
+            y = net(x).sum()
+        y.backward()
+        trainer.step(8)
+        return y
+
+    return step
+
+
+def _traced_spans(trace_dir):
+    """[(name, start, end, stats)] of the ``mxtpu/`` events of the one
+    trace under ``trace_dir``, in start order, with their thread line."""
+    import glob
+
+    import jax
+
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("mxtpu/"):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats), line.name))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def _trace(tmp_path, fn):
+    """Run ``fn`` under a jax profiler session as ``run.py --trace 1``
+    opens it; the ``mxtpu/`` spans it left."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _traced_spans(str(tmp_path))
+
+
+def _inside(child, parent):
+    return child[4] == parent[4] and parent[1] <= child[1] \
+        and child[2] <= parent[2]
+
+
+def test_span_off_is_the_shared_noop_and_records_nothing():
+    import jax
+
+    assert profiler.state() == "stop"
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert not profiler.active()
+    a, b = profiler.span("mxtpu/x", k=1), profiler.span("mxtpu/y")
+    assert a is b and a.live is False
+    with a as sp:
+        sp.set(anything=1)             # accepted, dropped
+    from mxnet_tpu.profiler import _AGGREGATE, _EVENTS
+    before = (len(_EVENTS), dict(_AGGREGATE))
+    invokes0 = profiler.counters()["invokes"]
+    step = _small_loop()
+    for _ in range(3):
+        step().wait_to_read()
+    assert (len(_EVENTS), dict(_AGGREGATE)) == before
+    # the counts are always on: per step the CachedOp, sum, 4 x adam_update
+    assert profiler.counters()["invokes"] - invokes0 >= 3 * 6
+
+
+def test_cachedop_builds_flags_a_retrace_with_tracing_off():
+    """The operator's use of the always-on `cachedop_builds` (README): a
+    loop logs it beside its step time, with no profiler session; it
+    stands still once every shape is warm, and a step that re-traced (a
+    new input shape here) shows as a rise of one."""
+    assert not profiler.active()
+    net = nn.HybridSequential()
+    net.add(nn.Dense(4))
+    net.initialize()
+    net.hybridize()
+    builds = lambda: profiler.counters()["cachedop_builds"]
+    b0 = builds()
+    net(nd.ones((8, 6))).wait_to_read()
+    assert builds() - b0 == 1                     # warm-up traced once
+    for _ in range(3):
+        net(nd.ones((8, 6))).wait_to_read()
+    assert builds() - b0 == 1                     # steady: flat
+    net(nd.ones((5, 6))).wait_to_read()
+    assert builds() - b0 == 2                     # the re-trace shows
+    with autograd.record():                       # so does train mode
+        net(nd.ones((8, 6))).wait_to_read()
+    assert builds() - b0 == 3
+
+
+def test_span_off_paths_stay_cheap():
+    """Off, a span site costs a function call and a test, and `invoke`
+    one counted call and an empty `with` more than it did (observed 0.07
+    / 0.25 us here; budgets ~100x, as test_spans.py's guards, so that they
+    catch a regression and not scheduler noise)."""
+    import time
+
+    from mxnet_tpu.profiler import _COUNTS, op_span
+
+    assert not profiler.active()
+    n = 20000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with profiler.span("mxtpu/hot", k=1):
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    t0 = time.perf_counter()
+    for _ in range(n):
+        profiler.count("invokes")
+        with op_span("hot"):
+            pass
+    per_invoke = (time.perf_counter() - t0) / n
+    _COUNTS["invokes"] -= n
+    assert per_span < 20e-6, f"span off {per_span * 1e6:.2f}us"
+    assert per_invoke < 20e-6, f"invoke's added work {per_invoke * 1e6:.2f}us"
+
+
+def test_spans_of_a_gluon_step_land_in_the_jax_trace(tmp_path):
+    """Any live jax profiler session switches the spans on: two steps of
+    record → backward → Trainer.step leave every span of the table in the
+    trace's host plane, nested as the table says, attributes in stats."""
+    step = _small_loop()
+
+    def two_steps():
+        assert profiler.active() and profiler.state() == "stop"
+        for _ in range(2):
+            step().wait_to_read()
+
+    spans = _trace(tmp_path, two_steps)
+    assert not profiler.active()
+    by = {n: [s for s in spans if s[0] == n] for n in SPAN_TABLE}
+    assert [len(by[n]) for n in SPAN_TABLE] == [2, 1, 2, 2, 2, 2]
+    assert all(s[4] == spans[0][4] for s in spans)      # one thread line
+
+    calls, build = by["mxtpu/cachedop/call"], by["mxtpu/cachedop/build"][0]
+    assert [c[3]["built"] for c in calls] == [1, 0]
+    assert _inside(build, calls[0]) and not _inside(build, calls[1])
+    assert calls[0][3]["block"] == build[3]["block"] != ""
+    for i, st in enumerate(by["mxtpu/trainer/step"]):
+        assert st[3]["step"] == i + 1 and st[3]["batch_size"] == 8
+        assert _inside(by["mxtpu/trainer/allreduce"][i], st)
+        assert _inside(by["mxtpu/trainer/update"][i], st)
+        assert by["mxtpu/trainer/update"][i][3]["params"] == 4
+        assert by["mxtpu/trainer/allreduce"][i][3]["keys"] == 0
+    assert [b[3]["nodes"] for b in by["mxtpu/autograd/backward"]] == [2, 2]
+
+    ops = [s for s in spans if s[0].startswith("mxtpu/op/")]
+    assert {"mxtpu/op/adam_update", "mxtpu/op/sum"} <= {o[0] for o in ops}
+    assert sum(o[0].startswith("mxtpu/op/CachedOp_") for o in ops) == 2
+    for name in ("mxtpu/trainer/step", "mxtpu/autograd/backward",
+                 "mxtpu/cachedop/call"):
+        for s in by[name]:
+            inside = sum(_inside(o, s) for o in ops)
+            assert s[3]["invokes"] == inside, (name, s[3], inside)
+    assert [s[3]["invokes"] for s in by["mxtpu/trainer/step"]] == [4, 4]
+    assert calls[1][3]["invokes"] == 1
+    assert [b[3]["invokes"] for b in by["mxtpu/autograd/backward"]] == [0, 0]
+
+
+def test_pushpull_span_sits_in_allreduce_across_two_contexts(tmp_path):
+    """The multi-device spans, which one context never opens: two
+    replicas through the `device` store's fused pushpull. The span lies
+    inside `mxtpu/trainer/allreduce`, `keys` counts the parameters and
+    `bytes` is what one replica contributes: its gradients' bytes."""
+    import mxnet_tpu as mx
+
+    ctxs = [mx.cpu(0), mx.cpu(1)]
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu"), nn.Dense(3))
+    net.initialize(ctx=ctxs)
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1}, kvstore="device")
+    xs = [nd.ones((4, 6), ctx=c) for c in ctxs]
+
+    def step():
+        with autograd.record():
+            ys = [net(x).sum() for x in xs]
+        for y in ys:
+            y.backward()
+        trainer.step(8)
+        ys[0].wait_to_read()
+
+    step()                                         # inits the store
+    spans = _trace(tmp_path, step)
+    pp, = [s for s in spans if s[0] == "mxtpu/kvstore/pushpull"]
+    ar, = [s for s in spans if s[0] == "mxtpu/trainer/allreduce"]
+    st, = [s for s in spans if s[0] == "mxtpu/trainer/step"]
+    assert _inside(pp, ar) and _inside(ar, st)
+    params = list(net.collect_params().values())
+    grad_bytes = sum(p.list_grad()[0]._data.nbytes for p in params)
+    assert grad_bytes == 4 * (6 * 16 + 16 + 16 * 3 + 3)
+    assert pp[3]["keys"] == ar[3]["keys"] == len(params) == 4
+    assert pp[3]["bytes"] == grad_bytes
+    # both replicas hold the summed gradient afterwards
+    for p in params:
+        g0, g1 = (g.asnumpy() for g in p.list_grad())
+        assert (g0 == g1).all() and abs(g0).sum() > 0
+
+
+def test_pushpull_span_counts_the_rows_a_row_sparse_value_stores(tmp_path):
+    """A row-sparse gradient takes the push + pull path, inside the same
+    span; `bytes` counts the rows it stores, not its dense shape."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ndarray import sparse as sp
+
+    ctxs = [mx.cpu(0), mx.cpu(1)]
+    kv = mx.kv.create("device")
+    kv.init("emb", nd.zeros((10, 4)))
+    grads = [sp.row_sparse_array(
+        (np.full((2, 4), i + 1.0, np.float32), np.array([1, 3 + i])),
+        shape=(10, 4), ctx=c) for i, c in enumerate(ctxs)]
+    outs = [nd.zeros((10, 4), ctx=c) for c in ctxs]
+    spans = _trace(tmp_path,
+                   lambda: kv.pushpull("emb", grads, out=outs))
+    pp, = [s for s in spans if s[0] == "mxtpu/kvstore/pushpull"]
+    assert pp[3]["keys"] == 1 and pp[3]["bytes"] == 2 * 4 * 4
+    want = np.zeros((10, 4), np.float32)
+    want[1], want[3], want[4] = 3.0, 1.0, 2.0
+    for o in outs:
+        assert (o.asnumpy() == want).all()
+
+
+def test_spans_show_in_dumps_and_dump_under_set_state_run(tmp_path):
+    """With no xprof: ``set_state('run')`` feeds the same spans into the
+    Chrome trace (category 'span', attributes in args) and the table."""
+    f = str(tmp_path / "trace.json")
+    profiler.set_config(filename=f, aggregate_stats=True)
+    step = _small_loop()
+    profiler.set_state("run")
+    try:
+        assert profiler.active()
+        step().wait_to_read()
+    finally:
+        profiler.set_state("stop")
+    table = profiler.dumps()
+    for name in SPAN_TABLE + ("adam_update",):
+        assert name in table, name
+    profiler.dump()
+    with open(f) as fh:
+        events = json.load(fh)["traceEvents"]
+    upd = [e for e in events if e["name"] == "mxtpu/trainer/update"]
+    assert upd and upd[-1]["cat"] == "span"
+    assert upd[-1]["args"] == {"params": 4, "invokes": 4}
+    ops = [e for e in events if e["name"] == "adam_update"]
+    assert len(ops) >= 4 and all(e["cat"] == "operator" for e in ops)
+    assert not any(e["name"].startswith("mxtpu/op/") for e in events)
