@@ -219,40 +219,36 @@ class Trainer:
         broadcast the new weight to the other replicas — gradients are
         identical after _allreduce_grads, so one update + copy keeps
         optimizer state/schedules exact (no shared-state mutation per
-        replica) at the same traffic as a kvstore pull. Dense params
-        batch into a single fused multi-tensor op
-        (multi_sgd_* analog; Updater.update_multi)."""
+        replica) at the same traffic as a kvstore pull. The parameters go
+        to `Optimizer.update_multi` in one call: one compiled program
+        for the dense ones of an optimizer with a rule (``fused``), the
+        per-key loop for the rest (``looped``)."""
         with _profiler.span("mxtpu/trainer/update") as sp:
-            sp.set(params=self._apply_updates(ignore_stale_grad))
+            fused, looped = self._apply_updates(ignore_stale_grad)
+            sp.set(params=fused + looped, fused=fused, looped=looped)
 
     def _apply_updates(self, ignore_stale_grad):
-        """`_update`'s body; returns the number of parameters the
-        optimizer updated here (not those pulled from the kvstore)."""
-        from ..ndarray.sparse import BaseSparseNDArray
-
-        batch_idx, batch_w, batch_g, batch_bcast = [], [], [], []
+        """`_update`'s body; returns how many parameters the optimizer
+        updated here (not those pulled from the kvstore), as
+        `Optimizer.update_multi`'s (fused, looped)."""
+        if self._update_on_kvstore and self._kvstore is not None:
+            for i, param in enumerate(self._params):
+                if param.grad_req != "null":
+                    # weights now live in the kvstore; pull them back
+                    self._kvstore.pull(i, param.list_data(), ignore_sparse=False)
+            return 0, 0
+        idx, weights, grads = [], [], []
         for i, param in enumerate(self._params):
-            if param.grad_req == "null":
-                continue
-            if self._update_on_kvstore and self._kvstore is not None:
-                # weights now live in the kvstore; pull them back
-                self._kvstore.pull(i, param.list_data(), ignore_sparse=False)
-                continue
-            datas, grads = param.list_data(), param.list_grad()
-            if isinstance(grads[0], BaseSparseNDArray):
-                # sparse updates keep the per-key path (rsp ops)
-                self._updaters[0](i, grads[0], datas[0])
-            else:
-                batch_idx.append(i)
-                batch_w.append(datas[0])
-                batch_g.append(grads[0])
-            batch_bcast.append((datas[0], datas[1:]))
-        if batch_idx:
-            self._updaters[0].update_multi(batch_idx, batch_g, batch_w)
-        for src, rest in batch_bcast:
+            if param.grad_req != "null":
+                idx.append(i)
+                weights.append(param.list_data())
+                grads.append(param.list_grad()[0])
+        counts = self._updaters[0].update_multi(
+            idx, grads, [w[0] for w in weights])
+        for src, *rest in weights:
             for dst in rest:
                 src.copyto(dst)
-        return len(batch_bcast)
+        return counts
 
     def save_states(self, fname):
         assert self._optimizer is not None

@@ -3,11 +3,15 @@
 Analog of the reference's ``src/operator/optimizer_op.{cc,cu}``
 (sgd_update, sgd_mom_update, mp_sgd_* multi-precision, adam_update,
 ftrl_update, rmsprop_update, signsgd/signum, nag, lamb_* (v≥1.6),
-multi-tensor multi_sgd_*). Each is a pure jax function; the imperative
-API writes results back through ``out=`` (NDArray._set_data — the
-in-place engine-write analog), and the jitted Trainer path uses them
-functionally inside one XLA computation so weight/state updates fuse
-into a single HBM-bandwidth-bound kernel per parameter bucket.
+multi-tensor multi_sgd_*). Each is a pure jax function with two callers.
+The imperative API (``nd.<op>``, ``Optimizer.update``) runs it eagerly,
+one device program per jax primitive in it, and writes results back
+through ``out=`` (NDArray._set_data — the in-place engine-write analog).
+``Optimizer.update_multi`` (Gluon ``Trainer.step``) calls the same
+function for every parameter inside ONE jitted program
+(optimizer/optimizer.py ``_fused_update``), where lr, wd and
+rescale_grad arrive as traced scalars: an implementation must stay
+traceable in them (no ``float(lr)``, no branch on their value).
 
 All ops are registered non-differentiable (the reference marks them
 TIsBackward-free utility ops; one never differentiates through an
@@ -195,10 +199,11 @@ def lamb_update_phase1(weight, grad, mean, var, beta1=0.9, beta2=0.999,
 
 
 # ----------------------------------------------------------------------
-# multi-tensor fused updates (reference multi_sgd_update/multi_sgd_mom_
-# update/multi_mp_sgd_*: one kernel updating MANY parameters — the
-# anti-small-op-overhead device for Trainer.step; here one XLA
-# computation covering the whole parameter list)
+# multi-tensor updates (reference multi_sgd_update/multi_sgd_mom_update/
+# multi_mp_sgd_*: one op call over MANY parameters). Kept as `nd` API:
+# called eagerly they are a Python loop, a few device programs per
+# parameter. `Trainer.step` does not use them: `Optimizer.update_multi`
+# compiles the single-parameter ops above over the whole list.
 # ----------------------------------------------------------------------
 def _per_weight(vals, i, default):
     if vals is None:

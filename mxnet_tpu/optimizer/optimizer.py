@@ -4,22 +4,30 @@ Same surface as the reference: an ``Optimizer`` registry, per-parameter
 state creation (``create_state``), index-keyed ``update``, lr/wd
 multipliers, gradient rescale/clipping, multi-precision (fp32 master
 weights for bf16/fp16 params — the mp_sgd path), and an ``Updater``
-wrapper that KVStore server-side updates use. The update math itself
-dispatches to the fused optimizer ops (ndarray/op_impl_optimizer.py),
-which write back through ``out=``: on TPU each update is one XLA
-computation per parameter (and Trainer's jitted path fuses whole
-buckets).
+wrapper that KVStore server-side updates use.
+
+The update math is the optimizer ops' (ndarray/op_impl_optimizer.py). An
+optimizer whose dense update is one such op says so once, in
+``dense_rule``. ``update`` (per key: kvstore, Module, sparse gradients)
+invokes that op eagerly and writes back through ``out=``;
+``update_multi`` (Gluon ``Trainer.step``) applies the same op to the
+whole dense parameter list inside ONE compiled program, `_fused_update`,
+with the multi-precision casts and the state writes in it.
 """
 from __future__ import annotations
 
+import functools
 import math
 import pickle
 
+import jax
 import numpy as np
 
 from ..base import _Registry, MXNetError
-from ..ndarray import NDArray, zeros
+from ..engine import engine as _engine
+from ..ndarray import NDArray, sparse as _sparse, zeros
 from ..ndarray.register import invoke as _invoke, get_op as _get_op
+from ..profiler import count as _count, op_span as _op_span
 
 __all__ = ["Optimizer", "Updater", "get_updater", "create", "register"]
 
@@ -37,8 +45,69 @@ def create(name, **kwargs):
     return _REG.get(name)(**kwargs)
 
 
+# dense op -> the op that applies the same rule to a row-sparse gradient
+_ROW_SPARSE_TWIN = {"sgd_update": "sgd_update_rsp",
+                    "sgd_mom_update": "sgd_mom_update_rsp",
+                    "adam_update": "adam_update_rsp",
+                    "adagrad_update": "adagrad_update_rsp"}
+
+
+def _leaves(state):
+    """A parameter's state as the op's tensor inputs after (weight, grad)."""
+    if state is None:
+        return ()
+    return tuple(state) if isinstance(state, (tuple, list)) else (state,)
+
+
+def _pinned(x):
+    """``x``'s array, committed to its device: `zeros` makes a fresh state
+    uncommitted and a program's output is committed, and jit compiles
+    once for each; this way the first step's program is every step's."""
+    a = x._data
+    return a if a.committed else jax.device_put(a, x.ctx.jax_device)
+
+
+def _definer(cls, attr):
+    return next(c for c in cls.__mro__ if attr in vars(c))
+
+
+@functools.partial(jax.jit, static_argnames="rules",
+                   donate_argnames=("states", "masters"))
+def _fused_update(rules, weights, grads, states, masters, hyper, rescale_grad):
+    """Every parameter's rule in one program: ``rules[i]`` is parameter
+    i's (op name, constant hyperparameters), static; ``hyper`` maps the
+    op's per-step keywords (lr, wd) to one float32 vector over the
+    parameters and ``rescale_grad`` is a float32 scalar, all traced, so a
+    schedule, Adam's bias correction or a new batch size compile nothing.
+    A traced scalar is strongly typed where the eager op's Python float is
+    not: each is cast to the dtype of the array the eager op would
+    multiply it into, and every output to the array it replaces. The
+    optimizer's own arrays (``states``, ``masters``) are donated; weights
+    and gradients are the caller's and are not."""
+    new_weights, new_states, new_masters = [], [], []
+    for i, (name, consts) in enumerate(rules):
+        op, weight, grad, master = _get_op(name), weights[i], grads[i], masters[i]
+        if master is not None:
+            weight, grad = master, grad.astype(master.dtype)
+        kw = {k: v[i].astype(weight.dtype) for k, v in hyper.items()}
+        out = op.fn(weight, grad, *states[i],
+                    rescale_grad=rescale_grad.astype(grad.dtype), **kw,
+                    **dict(consts))
+        out = out if isinstance(out, tuple) else (out,)
+        written = {in_idx: out[out_idx] for out_idx, in_idx in op.mutates}
+        new_states.append(tuple(written[2 + j].astype(s.dtype)
+                                for j, s in enumerate(states[i])))
+        new = out[0] if master is None else out[0].astype(master.dtype)
+        new_masters.append(None if master is None else new)
+        new_weights.append(new.astype(weights[i].dtype))
+    return new_weights, new_states, new_masters
+
+
 class Optimizer:
-    """Base optimizer. Subclasses implement create_state + update."""
+    """Base optimizer. A subclass implements ``create_state`` and either
+    ``dense_rule`` (its update is one optimizer op: ``update`` and the
+    compiled ``update_multi`` both follow from it) or ``update`` itself
+    (anything else: ``update_multi`` then loops over it)."""
 
     def __init__(self, rescale_grad=1.0, param_idx2name=None, wd=0.0,
                  clip_gradient=None, learning_rate=0.01, lr_scheduler=None,
@@ -67,18 +136,52 @@ class Optimizer:
     def create_state(self, index, weight):
         return None
 
+    def _has_master(self, weight):
+        return self.multi_precision and str(weight.dtype) in ("float16", "bfloat16")
+
     def create_state_multi_precision(self, index, weight):
         """fp32 master weight for low-precision params (mp_* ops)."""
-        if self.multi_precision and str(weight.dtype) in ("float16", "bfloat16"):
+        if self._has_master(weight):
             weight_master_copy = weight.astype("float32")
             return (self.create_state(index, weight_master_copy), weight_master_copy)
         return self.create_state(index, weight)
 
+    def dense_rule(self, state):
+        """``(op name, constant hyperparameters)`` of the op in
+        ndarray/op_impl_optimizer.py that updates one dense parameter
+        whose state is ``state``: inputs ``(weight, grad, *state)``,
+        keywords the constants, ``rescale_grad``, ``clip_gradient`` when
+        set, and what `_step_hyper` gives. None: no such op."""
+        return None
+
+    def _step_hyper(self, index):
+        """The op's keywords that change from step to step and from
+        parameter to parameter (schedules, multipliers)."""
+        return {"lr": self._get_lr(index), "wd": self._get_wd(index)}
+
+    def _rule(self, state):
+        rule = self.dense_rule(state)
+        if rule is None or self.clip_gradient is None:
+            return rule
+        return rule[0], dict(rule[1], clip_gradient=self.clip_gradient)
+
     def update(self, index, weight, grad, state):
-        raise NotImplementedError
+        rule = self._rule(state)
+        if rule is None:
+            raise NotImplementedError
+        self._update_count(index)
+        name, consts = rule
+        kw = dict(consts, rescale_grad=self.rescale_grad,
+                  **self._step_hyper(index))
+        if isinstance(grad, _sparse.RowSparseNDArray) and name in _ROW_SPARSE_TWIN:
+            getattr(_sparse, _ROW_SPARSE_TWIN[name])(
+                weight, grad, *_leaves(state), **kw)
+        else:
+            _invoke(_get_op(name), [weight, grad, *_leaves(state)], kw,
+                    out=weight)
 
     def update_multi_precision(self, index, weight, grad, state):
-        if self.multi_precision and str(weight.dtype) in ("float16", "bfloat16"):
+        if self._has_master(weight):
             inner_state, weight32 = state
             grad32 = grad.astype("float32")
             self.update(index, weight32, grad32, inner_state)
@@ -87,11 +190,60 @@ class Optimizer:
             self.update(index, weight, grad, state)
 
     def update_multi(self, indices, weights, grads, states):
-        """Aggregated update over many parameters — base: a loop;
-        optimizers with multi-tensor fused ops (SGD → multi_sgd_*)
-        override to one op call (reference aggregate_num path)."""
+        """The whole parameter list in one call (Gluon ``Trainer.step``).
+        Dense parameters of an optimizer that declares a ``dense_rule`` go
+        through ONE compiled program (`_fused_update`: one dispatch, one
+        ``invokes``, one ``mxtpu/op/fused_<op>`` span). The rest take the
+        per-key loop: row-sparse gradients or weights, optimizers without
+        a rule, and a subclass that overrides ``update`` (or
+        ``update_multi_precision``) below the class that declared the
+        rule, whose override the program would not run. Returns
+        (fused, looped), the number of parameters each way."""
+        cls = type(self)
+        rule_cls = _definer(cls, "dense_rule")
+        current = all(issubclass(rule_cls, _definer(cls, m))
+                      for m in ("update", "update_multi_precision"))
+        fused = []      # (index, weight, grad, state's arrays, master, rule)
         for i, w, g, s in zip(indices, weights, grads, states):
-            self.update_multi_precision(i, w, g, s)
+            inner, master = s if self._has_master(w) else (s, None)
+            dense = current and not isinstance(w, _sparse.BaseSparseNDArray) \
+                and not isinstance(g, _sparse.BaseSparseNDArray)
+            rule = self._rule(inner) if dense else None
+            if rule is None:
+                self.update_multi_precision(i, w, g, s)
+            else:
+                fused.append((i, w, g, _leaves(inner), master,
+                              (rule[0], tuple(sorted(rule[1].items())))))
+        if fused:
+            self._update_fused(*zip(*fused))
+        looped = len(indices) - len(fused)
+        _count("fused", len(fused))
+        _count("looped", looped)
+        return len(fused), looped
+
+    def _update_fused(self, indices, weights, grads, states, masters, rules):
+        hypers = []
+        for i in indices:      # count, then read lr: as `update` does per key
+            self._update_count(i)
+            hypers.append(self._step_hyper(i))
+        hyper = {k: np.array([h[k] for h in hypers], np.float32)
+                 for k in hypers[0]}
+        _count("invokes")
+        with _op_span("fused_" + "+".join(sorted({name for name, _ in rules}))), \
+                jax.default_device(weights[0].ctx.jax_device):
+            new_w, new_s, new_m = _fused_update(
+                rules, [_pinned(w) for w in weights], [_pinned(g) for g in grads],
+                [tuple(_pinned(a) for a in st) for st in states],
+                [None if m is None else _pinned(m) for m in masters],
+                hyper, np.float32(self.rescale_grad))
+        written = []
+        for targets, arrays in ((weights, new_w), (masters, new_m),
+                                *zip(states, new_s)):
+            for t, a in zip(targets, arrays):
+                if t is not None:
+                    t._set_data(a)
+                    written.append(a)
+        _engine.on_dispatch(written)
 
     # -- lr/wd plumbing (mirrors reference semantics)
     def set_learning_rate(self, lr):
@@ -142,13 +294,6 @@ class Optimizer:
             wd *= self.wd_mult[name]
         return wd
 
-    def _common_kwargs(self, index):
-        kw = {"lr": self._get_lr(index), "wd": self._get_wd(index),
-              "rescale_grad": self.rescale_grad}
-        if self.clip_gradient is not None:
-            kw["clip_gradient"] = self.clip_gradient
-        return kw
-
 
 @register
 class SGD(Optimizer):
@@ -164,71 +309,19 @@ class SGD(Optimizer):
             return None
         return zeros(weight.shape, weight.ctx, dtype=weight.dtype)
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        kw = self._common_kwargs(index)
-        from ..ndarray.sparse import RowSparseNDArray, sgd_update_rsp, \
-            sgd_mom_update_rsp
-
-        if isinstance(grad, RowSparseNDArray):
-            kw.pop("wd_lh", None)
-            if state is None:
-                sgd_update_rsp(weight, grad, **kw)
-            else:
-                sgd_mom_update_rsp(weight, grad, state,
-                                   momentum=self.momentum,
-                                   lazy_update=self.lazy_update, **kw)
-            return
+    def dense_rule(self, state):
         if state is None:
-            _invoke(_get_op("sgd_update"), [weight, grad], kw, out=weight)
-        else:
-            kw["momentum"] = self.momentum
-            _invoke(_get_op("sgd_mom_update"), [weight, grad, state], kw, out=weight)
-
-    def update_multi(self, indices, weights, grads, states):
-        """ONE fused multi-tensor op over the whole parameter list
-        (reference multi_sgd_update/multi_sgd_mom_update — SURVEY §2.1
-        optimizer row): one XLA computation, one dispatch, per step."""
-        from ..ndarray.sparse import BaseSparseNDArray
-        if (self.multi_precision
-                or any(isinstance(g, BaseSparseNDArray) for g in grads)
-                or any(isinstance(w, BaseSparseNDArray) for w in weights)):
-            return super().update_multi(indices, weights, grads, states)
-        self._update_count(list(indices))
-        lrs = [self._get_lr(i) for i in indices]
-        wds = [self._get_wd(i) for i in indices]
-        kw = {"lrs": lrs, "wds": wds, "rescale_grad": self.rescale_grad,
-              "num_weights": len(indices)}
-        if self.clip_gradient is not None:
-            kw["clip_gradient"] = self.clip_gradient
-        if self.momentum == 0.0:
-            args = []
-            for w, g in zip(weights, grads):
-                args += [w, g]
-            _invoke(_get_op("multi_sgd_update"), args, kw, out=list(weights))
-        else:
-            kw["momentum"] = self.momentum
-            args = []
-            outs = []
-            for w, g, m in zip(weights, grads, states):
-                args += [w, g, m]
-                outs += [w, m]
-            _invoke(_get_op("multi_sgd_mom_update"), args, kw, out=outs)
+            return "sgd_update", {}
+        return "sgd_mom_update", {"momentum": self.momentum,
+                                  "lazy_update": self.lazy_update}
 
 
 @register
 class NAG(SGD):
-    # NAG math differs from SGD — no multi_sgd fusion
-    update_multi = Optimizer.update_multi
-
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        kw = self._common_kwargs(index)
+    def dense_rule(self, state):
         if state is None:
-            _invoke(_get_op("sgd_update"), [weight, grad], kw, out=weight)
-        else:
-            kw["momentum"] = self.momentum
-            _invoke(_get_op("nag_mom_update"), [weight, grad, state], kw, out=weight)
+            return "sgd_update", {}
+        return "nag_mom_update", {"momentum": self.momentum}
 
 
 @register
@@ -243,37 +336,24 @@ class Adam(Optimizer):
         return (zeros(weight.shape, weight.ctx, dtype=weight.dtype),
                 zeros(weight.shape, weight.ctx, dtype=weight.dtype))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        t = self._index_update_count[index]
-        kw = self._common_kwargs(index)
+    def _step_hyper(self, index):
+        kw = super()._step_hyper(index)
         # bias correction folded into lr (reference Adam does the same)
-        coef1 = 1.0 - self.beta1 ** t
-        coef2 = 1.0 - self.beta2 ** t
-        kw["lr"] = kw["lr"] * math.sqrt(coef2) / coef1
-        kw.update(beta1=self.beta1, beta2=self.beta2, epsilon=self.epsilon)
-        mean, var = state
-        from ..ndarray.sparse import RowSparseNDArray, adam_update_rsp
+        t = self._index_update_count[index]
+        kw["lr"] *= math.sqrt(1.0 - self.beta2 ** t) / (1.0 - self.beta1 ** t)
+        return kw
 
-        if isinstance(grad, RowSparseNDArray):
-            adam_update_rsp(weight, grad, mean, var,
-                            lazy_update=self.lazy_update, **kw)
-            return
-        _invoke(_get_op("adam_update"), [weight, grad, mean, var], kw, out=weight)
+    def dense_rule(self, state):
+        return "adam_update", {"beta1": self.beta1, "beta2": self.beta2,
+                               "epsilon": self.epsilon,
+                               "lazy_update": self.lazy_update}
 
 
 @register
 class AdamW(Adam):
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        t = self._index_update_count[index]
-        kw = self._common_kwargs(index)
-        coef1 = 1.0 - self.beta1 ** t
-        coef2 = 1.0 - self.beta2 ** t
-        kw["lr"] = kw["lr"] * math.sqrt(coef2) / coef1
-        kw.update(beta1=self.beta1, beta2=self.beta2, epsilon=self.epsilon)
-        mean, var = state
-        _invoke(_get_op("adamw_update"), [weight, grad, mean, var], kw, out=weight)
+    def dense_rule(self, state):
+        return "adamw_update", {"beta1": self.beta1, "beta2": self.beta2,
+                                "epsilon": self.epsilon}
 
 
 @register
@@ -285,16 +365,8 @@ class AdaGrad(Optimizer):
     def create_state(self, index, weight):
         return zeros(weight.shape, weight.ctx, dtype=weight.dtype)
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        kw = self._common_kwargs(index)
-        kw["epsilon"] = self.float_stable_eps
-        from ..ndarray.sparse import RowSparseNDArray, adagrad_update_rsp
-
-        if isinstance(grad, RowSparseNDArray):
-            adagrad_update_rsp(weight, grad, state, **kw)
-            return
-        _invoke(_get_op("adagrad_update"), [weight, grad, state], kw, out=weight)
+    def dense_rule(self, state):
+        return "adagrad_update", {"epsilon": self.float_stable_eps}
 
 
 @register
@@ -307,15 +379,11 @@ class AdaDelta(Optimizer):
         return (zeros(weight.shape, weight.ctx, dtype=weight.dtype),
                 zeros(weight.shape, weight.ctx, dtype=weight.dtype))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        kw = {"wd": self._get_wd(index), "rescale_grad": self.rescale_grad,
-              "rho": self.rho, "epsilon": self.epsilon}
-        if self.clip_gradient is not None:
-            kw["clip_gradient"] = self.clip_gradient
-        acc_g, acc_delta = state
-        _invoke(_get_op("adadelta_update"), [weight, grad, acc_g, acc_delta], kw,
-                out=weight)
+    def _step_hyper(self, index):
+        return {"wd": self._get_wd(index)}     # the rule has no lr
+
+    def dense_rule(self, state):
+        return "adadelta_update", {"rho": self.rho, "epsilon": self.epsilon}
 
 
 @register
@@ -335,19 +403,13 @@ class RMSProp(Optimizer):
                     zeros(weight.shape, weight.ctx, dtype=weight.dtype))
         return zeros(weight.shape, weight.ctx, dtype=weight.dtype)
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        kw = self._common_kwargs(index)
-        kw.update(gamma1=self.gamma1, epsilon=self.epsilon)
+    def dense_rule(self, state):
+        consts = {"gamma1": self.gamma1, "epsilon": self.epsilon}
         if self.clip_weights:
-            kw["clip_weights"] = self.clip_weights
+            consts["clip_weights"] = self.clip_weights
         if self.centered:
-            n, g, delta = state
-            kw["gamma2"] = self.gamma2
-            _invoke(_get_op("rmspropalex_update"), [weight, grad, n, g, delta], kw,
-                    out=weight)
-        else:
-            _invoke(_get_op("rmsprop_update"), [weight, grad, state], kw, out=weight)
+            return "rmspropalex_update", dict(consts, gamma2=self.gamma2)
+        return "rmsprop_update", consts
 
 
 @register
@@ -360,12 +422,8 @@ class Ftrl(Optimizer):
         return (zeros(weight.shape, weight.ctx, dtype=weight.dtype),
                 zeros(weight.shape, weight.ctx, dtype=weight.dtype))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        kw = self._common_kwargs(index)
-        kw.update(lamda1=self.lamda1, beta=self.beta)
-        z, n = state
-        _invoke(_get_op("ftrl_update"), [weight, grad, z, n], kw, out=weight)
+    def dense_rule(self, state):
+        return "ftrl_update", {"lamda1": self.lamda1, "beta": self.beta}
 
 
 @register
@@ -380,14 +438,11 @@ class Signum(Optimizer):
             return None
         return zeros(weight.shape, weight.ctx, dtype=weight.dtype)
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        kw = self._common_kwargs(index)
+    def dense_rule(self, state):
         if state is None:
-            _invoke(_get_op("signsgd_update"), [weight, grad], kw, out=weight)
-        else:
-            kw.update(momentum=self.momentum, wd_lh=self.wd_lh)
-            _invoke(_get_op("signum_update"), [weight, grad, state], kw, out=weight)
+            return "signsgd_update", {}
+        return "signum_update", {"momentum": self.momentum,
+                                 "wd_lh": self.wd_lh}
 
 
 @register
@@ -474,10 +529,8 @@ class DCASGD(Optimizer):
 
 @register
 class LBSGD(SGD):
-    """Large-batch SGD with LARS-style layer-wise scaling (reference LBSGD)."""
-
-    # LARS trust-ratio math differs per layer — no multi_sgd fusion
-    update_multi = Optimizer.update_multi
+    """Large-batch SGD with LARS-style layer-wise scaling (reference LBSGD).
+    The trust ratio is read on the host, so ``update`` is its own."""
 
     def __init__(self, momentum=0.0, warmup_strategy="linear",
                  warmup_epochs=5, batch_scale=1, updates_per_epoch=32,
@@ -530,15 +583,15 @@ class Updater:
         self.optimizer.update_multi_precision(index, weight, grad, self.states[index])
 
     def update_multi(self, indices, grads, weights):
-        """Aggregated entry (Trainer fast path): one fused op for
-        optimizers that support it."""
+        """Aggregated entry (``Trainer.step``): `Optimizer.update_multi`
+        over these keys' states; returns its (fused, looped)."""
         for index, weight in zip(indices, weights):
             if index not in self.states:
                 self.states[index] = \
                     self.optimizer.create_state_multi_precision(index, weight)
                 self.states_synced[index] = True
-        self.optimizer.update_multi(indices, weights, grads,
-                                    [self.states[i] for i in indices])
+        return self.optimizer.update_multi(indices, weights, grads,
+                                           [self.states[i] for i in indices])
 
     def set_states(self, states):
         payload = pickle.loads(states)

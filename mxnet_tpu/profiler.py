@@ -13,7 +13,8 @@ session is live, plus a `record_op` event under ``set_state('run')``;
 `active()` says whether either holds. `count(name)` bumps and
 `counters()` gives the always-on dispatch counts: the spans difference
 `invokes`; a training loop polls `cachedop_builds` with tracing off to
-catch a re-trace after warm-up (README, "Profiling a training step").
+catch a re-trace after warm-up, and `fused` / `looped` to see whether its
+optimizer takes the compiled update (README, "Profiling a training step").
 """
 from __future__ import annotations
 
@@ -46,10 +47,12 @@ _EVENTS: list = []
 _AGGREGATE: dict = {}
 _LOCK = threading.Lock()
 # Always-on dispatch counts (`count` / `counters()`): `register.invoke`
-# bumps "invokes", `HybridBlock._call_cached_op` "cachedop_builds".
-# Not locked: a span reads the difference on its own thread, which is
-# exact while no other thread dispatches (a training loop).
-_COUNTS = {"invokes": 0, "cachedop_builds": 0}
+# bumps "invokes", `HybridBlock._call_cached_op` "cachedop_builds",
+# `Optimizer.update_multi` "fused" and "looped" (and "invokes", once, for
+# its compiled program). Not locked: a span reads the difference on its
+# own thread, which is exact while no other thread dispatches (a training
+# loop).
+_COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -120,17 +123,21 @@ def active():
     return _STATE["running"] or _session_live()
 
 
-def count(name):
+def count(name, n=1):
     """Bump one of the always-on counts (the dispatch layer's call)."""
-    _COUNTS[name] += 1
+    _COUNTS[name] += n
 
 
 def counters():
     """The always-on dispatch counts, whether or not anything records:
     ``invokes`` (`register.invoke` calls: what a span's ``invokes`` is
-    the difference of) and ``cachedop_builds`` (traces of hybridized
+    the difference of), ``cachedop_builds`` (traces of hybridized
     blocks: flat once every shape is warm, so a loop that logs it beside
-    its step time sees a re-trace without a profiler session)."""
+    its step time sees a re-trace without a profiler session), and
+    ``fused`` / ``looped`` (parameters `Optimizer.update_multi` put
+    through its one compiled program / through the per-key loop: a
+    ``looped`` that grows by the model's size each step names an
+    optimizer that dispatches eagerly, parameter by parameter)."""
     return dict(_COUNTS)
 
 

@@ -304,12 +304,13 @@ def test_multi_mp_sgd_updates():
 
 
 def test_trainer_fused_update_single_dispatch():
-    """Trainer._update batches every dense param into ONE multi-tensor
-    op call (VERDICT r1 weak #2: no per-param eager dispatch loop)."""
+    """Trainer._update puts every dense param through ONE dispatch
+    (VERDICT r1 weak #2: no per-param eager dispatch loop): the always-on
+    `invokes` counter rises by one over `Trainer.step`, whatever the
+    program is called."""
     import mxnet_tpu as mx
-    from mxnet_tpu import autograd, gluon
+    from mxnet_tpu import autograd, gluon, profiler
     from mxnet_tpu.gluon import nn
-    from mxnet_tpu.ndarray import register as reg
 
     net = nn.HybridSequential()
     net.add(nn.Dense(8, activation="relu", in_units=4), nn.Dense(2, in_units=8))
@@ -320,22 +321,12 @@ def test_trainer_fused_update_single_dispatch():
     with autograd.record():
         loss = (net(x) ** 2).sum()
     loss.backward()
-
-    from mxnet_tpu.optimizer import optimizer as opt_mod
-    calls = []
-    orig = opt_mod._invoke
-
-    def spy(op, inputs, params=None, **kw):
-        calls.append(op.name)
-        return orig(op, inputs, params, **kw)
-
-    opt_mod._invoke = spy
-    try:
-        trainer.step(4)
-    finally:
-        opt_mod._invoke = orig
-    assert calls.count("multi_sgd_mom_update") == 1, calls
-    assert "sgd_mom_update" not in calls, calls
+    before = profiler.counters()
+    trainer.step(4)
+    after = profiler.counters()
+    assert after["invokes"] - before["invokes"] == 1
+    assert (after["fused"] - before["fused"],
+            after["looped"] - before["looped"]) == (4, 0)
 
 
 def test_multi_sgd_default_lrs_usable():

@@ -4,6 +4,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon, nd, profiler
@@ -185,14 +186,14 @@ SPAN_TABLE = ("mxtpu/cachedop/call", "mxtpu/cachedop/build",
               "mxtpu/trainer/allreduce", "mxtpu/trainer/update")
 
 
-def _small_loop():
+def _small_loop(optimizer="adam"):
     """A hybridized two-layer block, a Trainer, and one
     record → backward → step as a callable."""
     net = nn.HybridSequential()
     net.add(nn.Dense(16, activation="relu"), nn.Dense(3))
     net.initialize()
     net.hybridize()
-    trainer = gluon.Trainer(net.collect_params(), "adam",
+    trainer = gluon.Trainer(net.collect_params(), optimizer,
                             {"learning_rate": 1e-3})
     x = nd.ones((8, 6))
 
@@ -264,8 +265,8 @@ def test_span_off_is_the_shared_noop_and_records_nothing():
     for _ in range(3):
         step().wait_to_read()
     assert (len(_EVENTS), dict(_AGGREGATE)) == before
-    # the counts are always on: per step the CachedOp, sum, 4 x adam_update
-    assert profiler.counters()["invokes"] - invokes0 >= 3 * 6
+    # the counts are always on: per step the CachedOp, sum, the compiled update
+    assert profiler.counters()["invokes"] - invokes0 >= 3 * 3
 
 
 def test_cachedop_builds_flags_a_retrace_with_tracing_off():
@@ -290,6 +291,26 @@ def test_cachedop_builds_flags_a_retrace_with_tracing_off():
     with autograd.record():                       # so does train mode
         net(nd.ones((8, 6))).wait_to_read()
     assert builds() - b0 == 3
+
+
+@pytest.mark.parametrize("optimizer, fused, looped", [
+    ("adam", 4, 0),           # declares a rule: one compiled program
+    ("lamb", 0, 4),           # two phases with norms: the per-key loop
+])
+def test_fused_and_looped_count_the_update_path_with_tracing_off(
+        optimizer, fused, looped):
+    """The operator's use of the always-on `fused` / `looped`: with no
+    profiler session, they say how many parameters each `Trainer.step`
+    put through the compiled update and how many through the loop."""
+    assert not profiler.active()
+    step = _small_loop(optimizer)
+    step().wait_to_read()
+    c0 = profiler.counters()
+    for _ in range(3):
+        step().wait_to_read()
+    c1 = profiler.counters()
+    assert c1["fused"] - c0["fused"] == 3 * fused
+    assert c1["looped"] - c0["looped"] == 3 * looped
 
 
 def test_span_off_paths_stay_cheap():
@@ -344,19 +365,22 @@ def test_spans_of_a_gluon_step_land_in_the_jax_trace(tmp_path):
         assert st[3]["step"] == i + 1 and st[3]["batch_size"] == 8
         assert _inside(by["mxtpu/trainer/allreduce"][i], st)
         assert _inside(by["mxtpu/trainer/update"][i], st)
-        assert by["mxtpu/trainer/update"][i][3]["params"] == 4
+        upd = by["mxtpu/trainer/update"][i][3]
+        assert (upd["params"], upd["fused"], upd["looped"]) == (4, 4, 0)
+        assert upd["invokes"] == 1           # the one compiled program
         assert by["mxtpu/trainer/allreduce"][i][3]["keys"] == 0
     assert [b[3]["nodes"] for b in by["mxtpu/autograd/backward"]] == [2, 2]
 
     ops = [s for s in spans if s[0].startswith("mxtpu/op/")]
-    assert {"mxtpu/op/adam_update", "mxtpu/op/sum"} <= {o[0] for o in ops}
+    assert {"mxtpu/op/fused_adam_update", "mxtpu/op/sum"} <= {o[0] for o in ops}
+    assert sum(o[0] == "mxtpu/op/fused_adam_update" for o in ops) == 2
     assert sum(o[0].startswith("mxtpu/op/CachedOp_") for o in ops) == 2
     for name in ("mxtpu/trainer/step", "mxtpu/autograd/backward",
                  "mxtpu/cachedop/call"):
         for s in by[name]:
             inside = sum(_inside(o, s) for o in ops)
             assert s[3]["invokes"] == inside, (name, s[3], inside)
-    assert [s[3]["invokes"] for s in by["mxtpu/trainer/step"]] == [4, 4]
+    assert [s[3]["invokes"] for s in by["mxtpu/trainer/step"]] == [1, 1]
     assert calls[1][3]["invokes"] == 1
     assert [b[3]["invokes"] for b in by["mxtpu/autograd/backward"]] == [0, 0]
 
@@ -440,14 +464,15 @@ def test_spans_show_in_dumps_and_dump_under_set_state_run(tmp_path):
     finally:
         profiler.set_state("stop")
     table = profiler.dumps()
-    for name in SPAN_TABLE + ("adam_update",):
+    for name in SPAN_TABLE + ("fused_adam_update",):
         assert name in table, name
     profiler.dump()
     with open(f) as fh:
         events = json.load(fh)["traceEvents"]
     upd = [e for e in events if e["name"] == "mxtpu/trainer/update"]
     assert upd and upd[-1]["cat"] == "span"
-    assert upd[-1]["args"] == {"params": 4, "invokes": 4}
-    ops = [e for e in events if e["name"] == "adam_update"]
-    assert len(ops) >= 4 and all(e["cat"] == "operator" for e in ops)
+    assert upd[-1]["args"] == {"params": 4, "fused": 4, "looped": 0,
+                               "invokes": 1}
+    ops = [e for e in events if e["name"] == "fused_adam_update"]
+    assert len(ops) == 1 and ops[0]["cat"] == "operator"
     assert not any(e["name"].startswith("mxtpu/op/") for e in events)
