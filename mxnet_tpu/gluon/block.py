@@ -325,18 +325,40 @@ def _collecting_aux():
     return getattr(_AUX_COLLECT, "sink", None)
 
 
-def defer_aux_update(param: Parameter, new_value):
+def defer_aux_update(param: Parameter, new_value, increment=False):
     """Called by layers with running state. Inside a hybridize trace the
     new (traced) value is collected as an extra output; eagerly it is
-    written immediately."""
+    written immediately. ``increment``: ``new_value`` is what to ADD to the
+    state (a tally), so that several calls in one trace — a block called
+    more than once, or row by row under ``remat_rows`` — add up instead of
+    the last one winning."""
     sink = _collecting_aux()
     if sink is not None:
-        sink.append((param, new_value))
+        sink.append((param, new_value, increment))
     else:
         with _autograd.pause():
             arr = param.data()
-            arr._set_data(new_value._data if isinstance(new_value, NDArray)
-                          else new_value)
+            raw = new_value._data if isinstance(new_value, NDArray) else new_value
+            arr._set_data(arr._data + raw.astype(arr._data.dtype) if increment
+                          else raw)
+
+
+def _raw(value):
+    return value._data if isinstance(value, NDArray) else value
+
+
+def _resolve_aux(sink, current):
+    """The sink's entries as one replacement value per parameter, in first-seen
+    order: an increment adds to the parameter's pending value, else to its
+    value in this trace (``current``: {parameter: traced array})."""
+    pending = {}
+    for param, value, increment in sink:
+        value = _raw(value)
+        if increment:
+            base = pending[param][1] if param in pending else current[param]
+            value = base + value.astype(base.dtype)
+        pending[param] = (param, value)
+    return list(pending.values())
 
 
 class HybridBlock(Block):
@@ -349,7 +371,7 @@ class HybridBlock(Block):
         self._cached_graph = {}
 
     def hybridize(self, active=True, static_alloc=False, static_shape=False,
-                  remat=None, remat_policy=None, **kwargs):
+                  remat=None, remat_policy=None, remat_rows=None, **kwargs):
         """Activate compiled execution. static_alloc/static_shape are
         accepted for API parity — XLA always plans memory statically.
 
@@ -367,15 +389,24 @@ class HybridBlock(Block):
         ``remat=False`` to clear explicitly. ``remat_policy`` selects
         what the forward saves (a ``jax.checkpoint_policies`` name, or
         "names:conv_out" to save conv outputs and recompute only the
-        elementwise chain)."""
+        elementwise chain). ``remat_rows=N`` (with ``remat`` on a marked
+        child) takes the batch N rows at a time through the checkpointed
+        block, one group after the other (a ``lax.map``), so the backward
+        rebuilds N rows' activations at once, not the batch's; the
+        block's inputs and outputs must all lead with the batch axis, and
+        a tally kept with ``defer_aux_update(..., increment=True)`` adds
+        up over the groups."""
         prev = self._flags
         if remat is None:
             remat = prev.get("remat", False)
         if remat_policy is None:
             remat_policy = prev.get("remat_policy")
+        if remat_rows is None:
+            remat_rows = prev.get("remat_rows")
         self._active = active
         self._flags = dict(static_alloc=static_alloc, static_shape=static_shape,
-                           remat=remat, remat_policy=remat_policy, **kwargs)
+                           remat=remat, remat_policy=remat_policy,
+                           remat_rows=remat_rows, **kwargs)
         self._cached_graph = {}
         super().hybridize(active, **kwargs)
 
@@ -481,9 +512,8 @@ class HybridBlock(Block):
                 _random.pop_trace_key()
             flat, structure = _flatten(out)
             box["structure"] = structure
-            box["aux_params"] = [p for p, _ in sink]
-            aux = tuple(n._data if isinstance(n, NDArray) else n
-                        for _, n in sink)
+            box["aux_params"] = [(p, inc) for p, _, inc in sink]
+            aux = tuple(_raw(n) for _, n, _ in sink)
             return tuple(f._data for f in flat), aux
 
         policy = self._flags.get("remat_policy")
@@ -499,9 +529,24 @@ class HybridBlock(Block):
                 policy = getattr(jax.checkpoint_policies, policy)
         ckpt = jax.checkpoint(pure, policy=policy)
         key = _random._next_key()
-        out_datas, aux_datas = ckpt(key, in_datas, p_datas)
-        for p, new in zip(box["aux_params"], aux_datas):
-            defer_aux_update(p, _wrap(new, ctx))
+        rows = self._flags.get("remat_rows")
+        batch = in_datas[0].shape[0] if in_datas and in_datas[0].ndim else 0
+        if rows and batch > rows and batch % rows == 0 \
+                and all(d.ndim and d.shape[0] == batch for d in in_datas):
+            # ``rows`` rows at a time, one after the other (a lax.map: the
+            # backward holds one group's rebuilt activations, not the batch's)
+            n = batch // rows
+            groups = [d.reshape((n, rows) + d.shape[1:]) for d in in_datas]
+            outs, auxs = jax.lax.map(
+                lambda kx: ckpt(kx[0], list(kx[1]), p_datas),
+                (jax.random.split(key, n), groups))
+            out_datas = tuple(o.reshape((batch,) + o.shape[2:]) for o in outs)
+            aux_datas = tuple(a.sum(0).astype(a.dtype) if inc else a[-1]
+                              for a, (_, inc) in zip(auxs, box["aux_params"]))
+        else:
+            out_datas, aux_datas = ckpt(key, in_datas, p_datas)
+        for (p, inc), new in zip(box["aux_params"], aux_datas):
+            defer_aux_update(p, _wrap(new, ctx), increment=inc)
         flat = [_wrap(d, ctx) for d in out_datas]
         return _unflatten(flat, box["structure"])
 
@@ -605,9 +650,9 @@ class HybridBlock(Block):
             flat, structure = _flatten(out)
             aux_arrays = []
             aux_params_order.clear()
-            for p, new in sink:
+            for p, new in _resolve_aux(sink, dict(zip(params, p_arrays))):
                 aux_params_order.append(p)
-                aux_arrays.append(new._data if isinstance(new, NDArray) else new)
+                aux_arrays.append(new)
             traced._structure = structure
             return tuple(x._data if isinstance(x, NDArray) else x
                          for x in flat) + tuple(aux_arrays)

@@ -3,5 +3,7 @@ from .vision import get_model
 from . import bert
 from .bert import (BERTModel, BERTMLMHead, BERTNSPHead, bert_base,
                    bert_large, bert_serving_entry, get_bert)
+from . import kimi_linear as kimi_linear_zoo
+from .kimi_linear import KimiLinearModel, kimi_linear
 from . import wide_deep as wide_deep_zoo
 from .wide_deep import WideDeep, wide_deep

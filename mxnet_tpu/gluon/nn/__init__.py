@@ -8,6 +8,9 @@ from .transformer import (
     MultiHeadAttention, PositionwiseFFN, TransformerEncoderCell,
     TransformerEncoder,
 )
+from .decoder import (
+    RMSNorm, GatedMLP, CausalConv1D, KDAMixer, MLAMixer, HeldExperts,
+)
 from .conv_layers import (
     Conv1D, Conv2D, Conv3D, Conv1DTranspose, Conv2DTranspose, Conv3DTranspose,
     MaxPool1D, MaxPool2D, MaxPool3D, AvgPool1D, AvgPool2D, AvgPool3D,

@@ -19,7 +19,30 @@ from .basic_layers import Activation, Dense, Dropout, LayerNorm
 from ..block import HybridBlock
 
 __all__ = ["MultiHeadAttention", "PositionwiseFFN",
-           "TransformerEncoderCell", "TransformerEncoder"]
+           "TransformerEncoderCell", "TransformerEncoder", "remat_per_layer"]
+
+
+def _split_heads(F, x, heads):
+    """(B, S, heads * D) -> (B, heads, S, D): the one head split of this
+    package (attention here, the KDA and MLA mixers in decoder.py)."""
+    x = F.reshape(x, shape=(0, 0, heads, -1))
+    return F.transpose(x, axes=(0, 2, 1, 3))
+
+
+def _merge_heads(F, x):
+    """(B, heads, S, D) -> (B, S, heads * D)."""
+    x = F.transpose(x, axes=(0, 2, 1, 3))
+    return F.reshape(x, shape=(0, 0, -1))
+
+
+def remat_per_layer(cells, rows=None):
+    """Mark each of ``cells`` for recomputation in the backward
+    (``hybridize(active=False, remat=True)``): the root stays one plain
+    CachedOp and a layer's activations are rebuilt, not kept. ``rows``:
+    rebuild that many rows of the batch at a time (``remat_rows``). The one
+    place the encoder and decoder stacks mark it."""
+    for cell in cells:
+        cell.hybridize(active=False, remat=True, remat_rows=rows)
 
 
 class MultiHeadAttention(HybridBlock):
@@ -57,11 +80,6 @@ class MultiHeadAttention(HybridBlock):
                                   prefix="out_")
             self.dropout = Dropout(attention_dropout) if attention_dropout else None
 
-    def _split_heads(self, F, x):
-        # (B, S, C) -> (B, H, S, D)
-        x = F.reshape(x, shape=(0, 0, self._heads, -1))
-        return F.transpose(x, axes=(0, 2, 1, 3))
-
     def hybrid_forward(self, F, x, mask=None, valid_length=None,
                        segment_ids=None):
         from ... import autograd as _autograd
@@ -71,9 +89,9 @@ class MultiHeadAttention(HybridBlock):
         q = F.slice_axis(qkv, axis=-1, begin=0, end=c)
         k = F.slice_axis(qkv, axis=-1, begin=c, end=2 * c)
         v = F.slice_axis(qkv, axis=-1, begin=2 * c, end=3 * c)
-        q = self._split_heads(F, q)
-        k = self._split_heads(F, k)
-        v = self._split_heads(F, v)
+        q = _split_heads(F, q, self._heads)
+        k = _split_heads(F, k, self._heads)
+        v = _split_heads(F, v, self._heads)
 
         # packed rows (io/packing.py): segment_ids (B, S) make attention
         # block-diagonal per sequence. The flash path needs the row's
@@ -124,9 +142,7 @@ class MultiHeadAttention(HybridBlock):
                 probs = self.dropout(probs)
             out = F.batch_dot_attention_apply(probs, v)
 
-        out = F.transpose(out, axes=(0, 2, 1, 3))    # (B, S, H, D)
-        out = F.reshape(out, shape=(0, 0, -1))       # (B, S, C)
-        return self.out_proj(out)
+        return self.out_proj(_merge_heads(F, out))   # (B, S, C)
 
 
 class PositionwiseFFN(HybridBlock):
@@ -222,6 +238,9 @@ class TransformerEncoder(HybridBlock):
                 self.cells.append(cell)
             self.final_ln = (LayerNorm(epsilon=layer_norm_eps, prefix="final_ln_")
                              if pre_norm else None)
+
+    def remat_per_layer(self, rows=None):
+        remat_per_layer(self.cells, rows)
 
     def hybrid_forward(self, F, x, mask=None, valid_length=None,
                        segment_ids=None):

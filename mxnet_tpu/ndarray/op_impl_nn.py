@@ -493,6 +493,13 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
     io/packing.py; requires Sq == Skv): tokens attend only tokens with
     the same segment id, cross-block tiles with disjoint id ranges are
     skipped whole.
+
+    The q.k width and the v width may differ (latent attention: 192 and
+    128). The default scale is 1/sqrt of the q.k width. The kernel has one
+    head width, so the Pallas path zero-pads q, k and v to the next
+    multiple of 128 of the wider one and cuts the output back to v's: the
+    pad adds nothing to q.k and its output columns are dropped. The jnp
+    fallback needs no pad.
     """
     from ..ops import pallas as _pallas
 
@@ -500,6 +507,8 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
         valid_len = valid_len.astype(jnp.int32).reshape(-1)
     if segment_ids is not None:
         segment_ids = segment_ids.astype(jnp.int32)
+    d, dv = query.shape[-1], value.shape[-1]
+    scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
     if (_pallas.pallas_ok_for(query)
             and query.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
             and query.ndim == 4):
@@ -507,11 +516,16 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
         # 0 is global position skv - sq, matching the tril(k=sk-sq)
         # fallback below
         q_off = key.shape[2] - query.shape[2] if causal else 0
-        return _pallas.flash_attention(query, key, value, sm_scale,
-                                       bool(causal), q_off, None, valid_len,
-                                       segment_ids)
-    d = query.shape[-1]
-    scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
+        if d != dv:
+            wide = -(-max(d, dv) // 128) * 128
+            query, key, value = (
+                jnp.pad(t, ((0, 0),) * 3 + ((0, wide - t.shape[-1]),))
+                for t in (query, key, value))
+            sm_scale = scale
+        out = _pallas.flash_attention(query, key, value, sm_scale,
+                                      bool(causal), q_off, None, valid_len,
+                                      segment_ids)
+        return out[..., :dv]
     s = jnp.einsum("bhqd,bhkd->bhqk",
                    query.astype(jnp.float32),
                    key.astype(jnp.float32)) * scale
@@ -985,3 +999,125 @@ def spatial_transformer(data, loc, target_shape=(0, 0),
     grid = grid_generator(loc, transform_type="affine",
                           target_shape=target_shape)
     return bilinear_sampler(data, grid)
+
+
+# ----------------------------------------------------------------------
+# Blocks of current open decoders — NEW ops, no reference analog: RMS
+# normalisation, the SiLU-gated product, a depthwise causal short
+# convolution, the gated delta rule (KDA) in chunks, and the experts a
+# chip holds (ops/pallas/kda.py, ops/pallas/moe.py).
+#
+# The elementwise ones compute in float32 and return the input's type.
+# Differentiated as written, jax would keep their float32 intermediates for
+# the backward (two to four times the bfloat16 input, per op; 4.5 GB of a
+# KDA mixer's 5.5 at 2 x 8,192 tokens): `_lean` keeps the inputs only and
+# rebuilds the intermediates in the backward.
+# ----------------------------------------------------------------------
+def _lean(fn):
+    @functools.wraps(fn)
+    def wrapped(*arrays, **static):
+        return jax.checkpoint(functools.partial(fn, **static))(*arrays)
+
+    return wrapped
+
+
+@register_op("RMSNorm")
+@_lean
+def rms_norm(data, gamma, eps=1e-5):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis, in float32."""
+    x = data.astype(jnp.float32)
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register_op("swiglu")
+@_lean
+def swiglu(data):
+    """SiLU(first half) * second half of the last axis."""
+    f = data.shape[-1] // 2
+    return (jax.nn.silu(data[..., :f].astype(jnp.float32))
+            * data[..., f:].astype(jnp.float32)).astype(data.dtype)
+
+
+@register_op("causal_conv1d")
+@_lean
+def causal_conv1d(data, weight, activation=None):
+    """Depthwise causal convolution over time: data (B, S, C), weight
+    (C, K); y_t = sum_i weight[:, i] * x_{t-(K-1)+i} (the last tap is the
+    current token), zeros before the row's start. ``activation='silu'``
+    applies SiLU to the result. A sum of K shifted products in float32."""
+    k = weight.shape[1]
+    s = data.shape[1]
+    x = jnp.pad(data.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    y = sum(x[:, i:i + s] * w[:, i] for i in range(k))
+    if activation == "silu":
+        y = jax.nn.silu(y)
+    elif activation is not None:
+        raise ValueError(f"causal_conv1d: unknown activation {activation!r}")
+    return y.astype(data.dtype)
+
+
+@register_op("kda_gate")
+@_lean
+def kda_gate(data, a_log, dt_bias, num_heads=1):
+    """KDA's per-channel log-decay, float32: -exp(a_log[head]) *
+    softplus(data + dt_bias). data (B, S, H*d), a_log (H,), dt_bias (H*d,)."""
+    f32 = jnp.float32
+    d = data.shape[-1] // num_heads
+    rate = jnp.repeat(jnp.exp(a_log.astype(f32)), d)
+    return -rate * jax.nn.softplus(data.astype(f32) + dt_bias.astype(f32))
+
+
+@register_op("kda_chunked")
+def kda_chunked_op(query, key, value, log_decay, beta, chunk_size=64,
+                   scale=None, qk_l2norm=True):
+    """Gated delta-rule linear attention over (B, H, S, d) heads, in chunks
+    (ops/pallas/kda.py): state S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t))
+    S_{t-1} + beta_t k_t v_t^T, output S_t^T q_t * scale (default d_k^-1/2).
+    ``log_decay`` (B, H, S, d_k) is float32 and <= 0; ``beta`` (B, H, S).
+    ``qk_l2norm`` normalises q and k over d first (KDA's definition). On
+    the chip the chunk-to-chunk walk is a Pallas kernel pair with a
+    hand-written backward (``mxtpu_kda_fwd`` / ``mxtpu_kda_bwd``); elsewhere
+    a ``lax.scan`` differentiated by jax."""
+    from ..ops import pallas as _pallas
+    from ..ops.pallas import kda as _kda
+
+    if qk_l2norm:
+        @jax.checkpoint
+        def unit(x):
+            x32 = x.astype(jnp.float32)
+            return (x32 * lax.rsqrt(jnp.sum(jnp.square(x32), -1, keepdims=True)
+                                    + 1e-6)).astype(x.dtype)
+        query, key = unit(query), unit(key)
+    use_kernel = (_pallas.pallas_ok_for(query)
+                  and query.dtype in (jnp.float32, jnp.bfloat16))
+    return _kda.kda_chunked(query, key, value, log_decay, beta, scale=scale,
+                            chunk_size=int(chunk_size), use_kernel=use_kernel)
+
+
+@register_op("moe_experts_held")
+def moe_experts_held(data, router_weight, score_bias, gate_up, down,
+                     top_k=8, routed_scaling_factor=1.0, renormalize=True,
+                     first_held=0):
+    """The part of a routed expert layer that the experts held here give
+    (ops/pallas/moe.py): data (T, D); router_weight (E, D) over ALL experts,
+    sigmoid scores in float32, the top ``top_k`` of score + score_bias;
+    gate_up (E_held, 2F, D) and down (E_held, D, F) of the experts
+    [first_held, first_held + E_held). Dropless at static shapes: a row
+    buffer of T rows (+ a tile an expert), and the
+    dense branch of one ``lax.cond`` for a step that needs more. Returns
+    (partial sum (T, D), [slots per held expert..., unplaced slots] float32
+    (E_held + 1,): what the layer adds to its running count)."""
+    from ..ops import pallas as _pallas
+    from ..ops.pallas import moe as _moe
+
+    ids, weights = _moe.route(data, router_weight, score_bias, int(top_k),
+                              float(routed_scaling_factor), bool(renormalize))
+    use_kernel = (_pallas.pallas_ok_for(data)
+                  and data.dtype in (jnp.float32, jnp.bfloat16))
+    y, counts, unplaced = _moe.experts_held(
+        data, ids, weights, gate_up, down, int(first_held),
+        use_kernel=use_kernel)
+    seen = jnp.concatenate([counts, unplaced[None]]).astype(jnp.float32)
+    return y, lax.stop_gradient(seen)

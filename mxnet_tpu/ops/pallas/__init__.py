@@ -16,6 +16,12 @@ we only drop to Pallas where XLA's own fusion genuinely loses:
   heads: avoids materializing the (N, V) log-softmax for backward).
 - ``lstm`` — whole-sequence fused LSTM layer (weight-stationary
   recurrent matmul + gates in one kernel; the cudnn_rnn-inl.h analog).
+- ``kda`` — the gated delta rule (KDA linear attention) in chunks: the
+  state's walk over the chunks with the state in VMEM, forward and a
+  hand-written backward (``kda_chunked``).
+- ``moe`` — the experts a chip holds: dispatch tables at static shapes
+  and a grouped matmul over the held experts' rows (``grouped_matmul``,
+  ``experts_held``).
 
 Dispatch contract: every kernel here has a pure-jnp twin used when the
 backend is not TPU (tests run on the CPU mesh) or when
@@ -33,6 +39,8 @@ from .flash_attention import (paged_attention_reference,  # noqa: E402
                               paged_flash_attention)
 from .softmax_xent import softmax_xent_fused  # noqa: E402
 from .lstm import lstm_layer_fused  # noqa: E402
+from .kda import kda_chunked  # noqa: E402
+from .moe import experts_held, grouped_matmul  # noqa: E402
 
 __all__ = [
     "pallas_enabled",
@@ -45,4 +53,7 @@ __all__ = [
     "paged_attention_reference",
     "softmax_xent_fused",
     "lstm_layer_fused",
+    "kda_chunked",
+    "grouped_matmul",
+    "experts_held",
 ]

@@ -877,7 +877,9 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, q_offset=0,
     blockwise in VMEM. ``kv_lens`` (B,) int32 masks keys at/after each
     example's valid length (variable-length batches, e.g. BERT
     padding); ``segment_ids`` (B, S) int32 makes attention
-    block-diagonal over packed sequences (see module docstring)."""
+    block-diagonal over packed sequences (see module docstring). q, k
+    and v share one head width; ``mx.nd.flash_attention`` pads a wider
+    q.k (192 against v's 128) up to it and passes the true ``sm_scale``."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     o, _ = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
@@ -1058,7 +1060,9 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, kv_lens,
     columns past each row's ``kv_len`` (causal within the Sq chunk)
     and runs a plain max-subtracted softmax. Every row's computation
     is independent of the others — the property the join/leave
-    solo-parity golden leans on."""
+    solo-parity golden leans on. One head width ``d`` for q, k and v, as
+    the kernels here: unequal q.k and v widths (latent attention) are the
+    op's business (``flash_attention_op`` zero-pads them to one width)."""
     b, h, sq, d = q.shape
     page_size = k_pages.shape[2]
     npages = page_table.shape[1]

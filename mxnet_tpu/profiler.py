@@ -21,6 +21,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import weakref
 import time
 
 import jax
@@ -128,7 +129,7 @@ def count(name, n=1):
     _COUNTS[name] += n
 
 
-def counters():
+def counters(device=True):
     """The always-on dispatch counts, whether or not anything records:
     ``invokes`` (`register.invoke` calls: what a span's ``invokes`` is
     the difference of), ``cachedop_builds`` (traces of hybridized
@@ -137,8 +138,33 @@ def counters():
     ``fused`` / ``looped`` (parameters `Optimizer.update_multi` put
     through its one compiled program / through the per-key loop: a
     ``looped`` that grows by the model's size each step names an
-    optimizer that dispatches eagerly, parameter by parameter)."""
-    return dict(_COUNTS)
+    optimizer that dispatches eagerly, parameter by parameter).
+
+    Blocks that count on the device (`register_device_counters`: an expert
+    layer's ``running_slots``) are read here, when the operator polls and
+    never inside a step: ``moe_slots`` (slots sent to the experts held,
+    summed over the layers), ``moe_dropped`` (slots no branch computed:
+    0), and ``moe_slots/<layer>`` (the list per held expert).
+    ``device=False`` leaves them out: the host counts alone, free of any
+    device read, for a loop that polls every step."""
+    out = dict(_COUNTS)
+    for block in list(_DEVICE_COUNTERS) if device else ():
+        for key, value in block.device_counters().items():
+            if isinstance(value, list):
+                out[key] = value
+            else:
+                out[key] = out.get(key, 0.0) + value
+    return out
+
+
+_DEVICE_COUNTERS = weakref.WeakSet()
+
+
+def register_device_counters(block):
+    """Have `counters()` include ``block.device_counters()`` ({name: number,
+    summed over blocks, or a list, kept per block}) for as long as the
+    block lives."""
+    _DEVICE_COUNTERS.add(block)
 
 
 def _device_bytes_in_use():

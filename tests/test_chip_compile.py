@@ -20,9 +20,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from mxnet_tpu.ops.pallas import (flash_attention, layer_norm_fused,
-                                  lstm_layer_fused, paged_flash_attention,
-                                  softmax_xent_fused)
+from mxnet_tpu.ops.pallas import (experts_held, flash_attention, kda_chunked,
+                                  layer_norm_fused, lstm_layer_fused,
+                                  paged_flash_attention, softmax_xent_fused)
 
 BF16 = jnp.bfloat16
 
@@ -75,6 +75,8 @@ _FLASH = {
                              (256, 256)),
     "packed_s2048_causal_256x256": ((16, 12, 2048, 64), True, False, True,
                                     (256, 256)),
+    # latent attention at 8k: q.k 192 and v 128 reach the kernel padded to 256
+    "mla_s8192_d256_causal": ((1, 32, 8192, 256), True, False, False, None),
 }
 
 
@@ -139,3 +141,37 @@ def test_lstm_fwd_bwd_compiles(one_chip):
                           ((650, 2600), BF16), ((128, 650), BF16),
                           ((128, 650), BF16))
     _assert_kernels(text, "mxtpu_lstm_fwd", "mxtpu_lstm_bwd")
+
+
+def test_kda_walk_fwd_bwd_compiles(one_chip):
+    """One row of Kimi Linear's KDA at published widths: 32 heads x 128,
+    8,192 tokens, chunks of 64 (the walk's kernels; the chunk terms are XLA)."""
+    def step(q, k, v, g, beta):
+        return jax.grad(lambda *a: _sum(kda_chunked(
+            *a, chunk_size=64, use_kernel=True, interpret=False)),
+            argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    heads = (1, 32, 8192, 128)
+    text = _compiled_text(step, one_chip, (heads, BF16), (heads, BF16),
+                          (heads, BF16), (heads, jnp.float32),
+                          (heads[:3], BF16))
+    _assert_kernels(text, "mxtpu_kda_fwd", "mxtpu_kda_bwd")
+
+
+def test_grouped_expert_matmul_fwd_bwd_compiles(one_chip):
+    """8 held experts 1,024 wide on hidden 2,304, 8,192 tokens routed 8 of
+    256: the grouped matmul, its transposed-weight twin and the weight
+    gradient's kernel."""
+    tokens, hidden, width, held, top_k = 8192, 2304, 1024, 8, 8
+
+    def step(x, ids, weights, gate_up, down):
+        return jax.grad(lambda x, w, gu, dn: _sum(experts_held(
+            x, ids, w, gu, dn, 0, use_kernel=True, interpret=False)[0]),
+            argnums=(0, 1, 2, 3))(x, weights, gate_up, down)
+
+    text = _compiled_text(step, one_chip, ((tokens, hidden), BF16),
+                          ((tokens, top_k), jnp.int32),
+                          ((tokens, top_k), jnp.float32),
+                          ((held, 2 * width, hidden), BF16),
+                          ((held, hidden, width), BF16))
+    _assert_kernels(text, "mxtpu_moe_gmm", "mxtpu_moe_tgmm")
