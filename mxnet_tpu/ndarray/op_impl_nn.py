@@ -1077,9 +1077,11 @@ def kda_chunked_op(query, key, value, log_decay, beta, chunk_size=64,
     S_{t-1} + beta_t k_t v_t^T, output S_t^T q_t * scale (default d_k^-1/2).
     ``log_decay`` (B, H, S, d_k) is float32 and <= 0; ``beta`` (B, H, S).
     ``qk_l2norm`` normalises q and k over d first (KDA's definition). On
-    the chip the chunk-to-chunk walk is a Pallas kernel pair with a
-    hand-written backward (``mxtpu_kda_fwd`` / ``mxtpu_kda_bwd``); elsewhere
-    a ``lax.scan`` differentiated by jax."""
+    the chip both phases are Pallas kernel pairs with hand-written
+    backwards: a chunk's terms in VMEM (``mxtpu_kda_chunk_fwd`` /
+    ``mxtpu_kda_chunk_bwd``) and the chunk-to-chunk walk (``mxtpu_kda_fwd``
+    / ``mxtpu_kda_bwd``); elsewhere ``jax.numpy`` and a ``lax.scan``,
+    differentiated by jax."""
     from ..ops import pallas as _pallas
     from ..ops.pallas import kda as _kda
 

@@ -29,11 +29,17 @@ channel loses more than e^-80 inside 16 tokens). Log-decays accumulate in
 float32.
 
 Two phases. The first (A, B, T, W, U0, the decayed Q and K) is parallel
-over chunks: plain ``jax.numpy``, differentiated by jax. The second carries
-S from chunk to chunk: on the chip a Pallas kernel with the state in VMEM
-(``mxtpu_kda_fwd``) and a hand-written reverse kernel
-(``mxtpu_kda_bwd``) that reads the saved chunk-start states; elsewhere
-the same arithmetic as a ``lax.scan`` (the twin).
+over chunks; the second carries S from chunk to chunk. On the chip both are
+Pallas kernels with hand-written backwards. ``mxtpu_kda_chunk_fwd`` builds
+the terms of whole tiles of chunks in VMEM (nothing wider than the keys
+reaches HBM) and saves T; ``mxtpu_kda_chunk_bwd`` rebuilds the cheap terms
+from the inputs and reads T instead of inverting again. ``mxtpu_kda_fwd``
+walks the chunks with the state in VMEM and ``mxtpu_kda_bwd`` walks back
+over the saved chunk-start states. A grid step of either pair takes several
+independent chains (tiles of chunks; heads of the walk) side by side, so that
+one's wait for a product is another's work. Elsewhere (the twin: the CPU, the tests'
+oracle) the terms are plain ``jax.numpy`` and the walk a ``lax.scan``, both
+differentiated by jax.
 """
 from __future__ import annotations
 
@@ -93,25 +99,49 @@ def _dot(a, b, dims, precision):
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 
+def _in_step(chains):
+    """Run generators stage by stage: every chain's first stage, then every
+    chain's second, ... (a chain ``yield``s where its next product needs the
+    last one's result). The compiler overlaps two chains of dependent products
+    only where their products stand side by side in the program."""
+    chains, done = list(chains), object()
+    while chains:
+        chains = [c for c in chains if next(c, done) is not done]
+
+
+_HEADS_PER_STEP = 4    # heads a grid step of the walk takes, in step with each other
+
+# The four ``pallas_call`` wrappers below are jitted: a grid step's unrolled
+# chains make a kernel body of thousands of operations, and a model traces the
+# op eight times a layer (forward, the vjp, remat's forward again, the
+# backward rules). Under ``jax.jit`` the body is traced once a process for
+# each shape, not each time (17 s of a 78 s set-up in the benchmark's cell).
+
+
 def _fwd_kernel(w_ref, u0_ref, qg_ref, bm_ref, kend_ref, ec_ref,
                 o_ref, s_all_ref, s_sc, *, precision):
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_sc[:] = jnp.zeros_like(s_sc)
 
-    s = s_sc[:]
-    s_all_ref[0, 0] = s
     f32 = jnp.float32
-    w, qg, kend = (r[0, 0].astype(f32) for r in (w_ref, qg_ref, kend_ref))
     op = w_ref.dtype
-    s_op = s.astype(op).astype(f32)
-    u = u0_ref[0, 0].astype(f32) - _dot(w, s_op, _NN, precision)
-    u_op = u.astype(op).astype(f32)
-    o = _dot(qg, s_op, _NN, precision) \
-        + _dot(bm_ref[0, 0].astype(f32), u_op, _NN, precision)
-    o_ref[0, 0] = o.astype(o_ref.dtype)
-    # ec arrives as a (dk, 1) column so that it scales S's rows
-    s_sc[:] = ec_ref[0, 0] * s + _dot(kend, u_op, _TN, precision)
+
+    def head(h):
+        s = s_sc[h]
+        s_all_ref[h, 0] = s
+        w, qg, kend = (r[h, 0].astype(f32) for r in (w_ref, qg_ref, kend_ref))
+        s_op = s.astype(op).astype(f32)
+        u = u0_ref[h, 0].astype(f32) - _dot(w, s_op, _NN, precision)
+        o = _dot(qg, s_op, _NN, precision)
+        yield
+        u_op = u.astype(op).astype(f32)
+        o = o + _dot(bm_ref[h, 0].astype(f32), u_op, _NN, precision)
+        o_ref[h, 0] = o.astype(o_ref.dtype)
+        # ec arrives as a (dk, 1) column so that it scales S's rows
+        s_sc[h] = ec_ref[h, 0] * s + _dot(kend, u_op, _TN, precision)
+
+    _in_step(head(h) for h in range(w_ref.shape[0]))
 
 
 def _bwd_kernel(w_ref, u0_ref, qg_ref, bm_ref, kend_ref, ec_ref, s_ref, do_ref,
@@ -123,71 +153,81 @@ def _bwd_kernel(w_ref, u0_ref, qg_ref, bm_ref, kend_ref, ec_ref, s_ref, do_ref,
 
     f32 = jnp.float32
     op = w_ref.dtype
-    ds_out = ds_sc[:]                       # d loss / d (this chunk's end state)
-    s = s_ref[0, 0]
-    w, qg, kend, bm = (r[0, 0].astype(f32)
-                       for r in (w_ref, qg_ref, kend_ref, bm_ref))
-    do = do_ref[0, 0].astype(f32)
-    s_op = s.astype(op).astype(f32)
-    u = u0_ref[0, 0].astype(f32) - _dot(w, s_op, _NN, precision)
-    u_op = u.astype(op).astype(f32)
-    ds_op = ds_out.astype(op).astype(f32)
-    du = _dot(bm, do, _TN, precision) + _dot(kend, ds_op, _NN, precision)
-    du_op = du.astype(op).astype(f32)
-    dbm_ref[0, 0] = _dot(do, u_op, _NT, precision).astype(dbm_ref.dtype)
-    dqg_ref[0, 0] = _dot(do, s_op, _NT, precision).astype(dqg_ref.dtype)
-    dkend_ref[0, 0] = _dot(u_op, ds_op, _NT, precision).astype(dkend_ref.dtype)
-    dec_ref[0, 0] = jnp.sum(ds_out * s, axis=1, keepdims=True)
-    du0_ref[0, 0] = du.astype(du0_ref.dtype)
-    dw_ref[0, 0] = (-_dot(du_op, s_op, _NT, precision)).astype(dw_ref.dtype)
-    ds_sc[:] = (_dot(qg, do, _TN, precision) + ec_ref[0, 0] * ds_out
-                - _dot(w, du_op, _TN, precision))
+
+    def head(h):
+        ds_out = ds_sc[h]                   # d loss / d (this chunk's end state)
+        s = s_ref[h, 0]
+        w, qg, kend, bm = (r[h, 0].astype(f32)
+                           for r in (w_ref, qg_ref, kend_ref, bm_ref))
+        do = do_ref[h, 0].astype(f32)
+        s_op = s.astype(op).astype(f32)
+        u = u0_ref[h, 0].astype(f32) - _dot(w, s_op, _NN, precision)
+        ds_op = ds_out.astype(op).astype(f32)
+        du = _dot(bm, do, _TN, precision) + _dot(kend, ds_op, _NN, precision)
+        dqg_ref[h, 0] = _dot(do, s_op, _NT, precision).astype(dqg_ref.dtype)
+        dec_ref[h, 0] = jnp.sum(ds_out * s, axis=1, keepdims=True)
+        yield
+        u_op = u.astype(op).astype(f32)
+        du_op = du.astype(op).astype(f32)
+        dbm_ref[h, 0] = _dot(do, u_op, _NT, precision).astype(dbm_ref.dtype)
+        dkend_ref[h, 0] = _dot(u_op, ds_op, _NT, precision).astype(dkend_ref.dtype)
+        du0_ref[h, 0] = du.astype(du0_ref.dtype)
+        dw_ref[h, 0] = (-_dot(du_op, s_op, _NT, precision)).astype(dw_ref.dtype)
+        ds_sc[h] = (_dot(qg, do, _TN, precision) + ec_ref[h, 0] * ds_out
+                    - _dot(w, du_op, _TN, precision))
+
+    _in_step(head(h) for h in range(w_ref.shape[0]))
 
 
-def _specs(n, c, dk, dv, rev):
+def _specs(bh, n, c, dk, dv, rev):
+    """(heads a grid step, the block specs): the walk takes ``_HEADS_PER_STEP``
+    heads a step where that divides their number."""
+    hb = max(d for d in range(1, _HEADS_PER_STEP + 1) if bh % d == 0)
     at = (lambda b, i: (b, n - 1 - i, 0, 0)) if rev else (lambda b, i: (b, i, 0, 0))
 
     def spec(rows, cols):
-        return pl.BlockSpec((1, 1, rows, cols), at, memory_space=pltpu.VMEM)
+        return pl.BlockSpec((hb, 1, rows, cols), at, memory_space=pltpu.VMEM)
 
-    return spec(c, dk), spec(c, dv), spec(c, c), spec(dk, 1), spec(dk, dv)
+    return hb, (spec(c, dk), spec(c, dv), spec(c, c), spec(dk, 1), spec(dk, dv))
 
 
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 @x32
+@functools.partial(jax.jit, static_argnames=("precision", "interpret"))
 def _scan_fwd_pallas(w, u0, qg, bm, kend, ec, precision, interpret):
     bh, n, c, dk = w.shape
     dv = u0.shape[-1]
-    ck, cv, cc, col, st = _specs(n, c, dk, dv, rev=False)
+    hb, (ck, cv, cc, col, st) = _specs(bh, n, c, dk, dv, rev=False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, precision=precision),
-        grid=(bh, n),
+        grid=(bh // hb, n),
         in_specs=[ck, cv, ck, cc, ck, col],
         out_specs=[cv, st],
         out_shape=[jax.ShapeDtypeStruct((bh, n, c, dv), u0.dtype),
                    jax.ShapeDtypeStruct((bh, n, dk, dv), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret, name="mxtpu_kda_fwd",
     )(w, u0, qg, bm, kend, jnp.swapaxes(ec, -1, -2))
 
 
 @x32
+@functools.partial(jax.jit, static_argnames=("precision", "interpret"))
 def _scan_bwd_pallas(w, u0, qg, bm, kend, ec, s_all, do, precision, interpret):
     bh, n, c, dk = w.shape
     dv = u0.shape[-1]
-    ck, cv, cc, col, st = _specs(n, c, dk, dv, rev=True)
+    hb, (ck, cv, cc, col, st) = _specs(bh, n, c, dk, dv, rev=True)
     shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype)
               for a in (w, u0, qg, bm, kend)]
     shapes.append(jax.ShapeDtypeStruct((bh, n, dk, 1), jnp.float32))
     outs = pl.pallas_call(
         functools.partial(_bwd_kernel, precision=precision),
-        grid=(bh, n),
+        grid=(bh // hb, n),
         in_specs=[ck, cv, ck, cc, ck, col, st, cv],
         out_specs=[ck, cv, ck, cc, ck, col],
         out_shape=shapes,
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret, name="mxtpu_kda_bwd",
     )(w, u0, qg, bm, kend, jnp.swapaxes(ec, -1, -2), s_all, do)
     return tuple(outs[:5]) + (jnp.swapaxes(outs[5], -1, -2),)
@@ -210,7 +250,7 @@ def _scan_pallas_bwd(precision, interpret, res, do):
 _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
 
 
-# ---- phase 1: everything inside a chunk ----------------------------------------
+# ---- phase 1, the twin: everything inside a chunk in jax.numpy ------------------
 
 def _doubling_inverse(a, precision):
     """(I + a)^-1 for strictly lower triangular ``a`` (..., n, n) by
@@ -302,23 +342,390 @@ def _chunk_terms(q, k, v, g, beta, scale, c):
             kend.astype(op), jnp.exp(g_end), precision)
 
 
+# ---- phase 1 on the chip: the same terms, built in VMEM ----------------------------
+#
+# A tile is ``m`` whole chunks of one head (128 tokens where the length allows: two
+# chunks of 64), taken as ONE block-diagonal (tile x tile) problem: A, B, the
+# inverse's products and T [K e^G beta, V beta] then fill whole MXU passes, and
+# the block structure costs a mask. A grid step works through several tiles in
+# step with each other (``_in_step``): a tile's inverse is a chain of products
+# each waiting for the last.
+
+_TILE = 128            # tokens a tile holds (more only where one chunk is longer)
+_TILES_PER_STEP = 4    # tiles a grid step works through
+
+
+def _mm(a, b, dims, precision):
+    """One product in VMEM, float32 out. HIGHEST: float32 operands as they are.
+    HIGH: three bfloat16 passes split by hand (Mosaic has no HIGH; the split is
+    XLA's: hi*hi + hi*lo + lo*hi). DEFAULT: one pass on bfloat16 operands."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    if precision == lax.Precision.HIGHEST:
+        return _dot(a.astype(f32), b.astype(f32), dims, precision)
+    if precision == lax.Precision.HIGH:
+        a, b = a.astype(f32), b.astype(f32)
+        a_hi, b_hi = a.astype(bf16), b.astype(bf16)
+        a_lo = (a - a_hi.astype(f32)).astype(bf16)
+        b_lo = (b - b_hi.astype(f32)).astype(bf16)
+        one = lax.Precision.DEFAULT      # explicit: the process-wide default is HIGH
+        return (_dot(a_hi, b_hi, dims, one) + _dot(a_hi, b_lo, dims, one)
+                + _dot(a_lo, b_hi, dims, one))
+    return _dot(a.astype(bf16), b.astype(bf16), dims, precision)
+
+
+def _cumsum_rows(x, pos, c, reverse=False):
+    """The running sum down the rows inside each chunk of ``c`` rows (``pos``: a
+    row's place in its chunk), float32, by doubling over sublane rolls."""
+    r = x.shape[0]
+    shift = 1
+    while shift < c:
+        if reverse:
+            x = x + jnp.where(pos + shift < c, pltpu.roll(x, r - shift, 0), 0.0)
+        else:
+            x = x + jnp.where(pos >= shift, pltpu.roll(x, shift, 0), 0.0)
+        shift *= 2
+    return x
+
+
+def _rows_of(x, picks, span):
+    """Row ``p`` of ``x`` repeated over ``span`` rows, for each p of ``picks``
+    in turn (None: zeros)."""
+    width = x.shape[1]
+    return jnp.concatenate(
+        [jnp.zeros((span, width), x.dtype) if p is None
+         else jnp.broadcast_to(x[p:p + 1], (span, width)) for p in picks], axis=0)
+
+
+def _block_diag(blocks):
+    """(m c, m c) float32 with the (c, c) ``blocks`` down its diagonal."""
+    m, c = len(blocks), blocks[0].shape[0]
+    rows = []
+    for i, blk in enumerate(blocks):
+        parts = [jnp.zeros((c, i * c), jnp.float32)] if i else []
+        parts.append(blk.astype(jnp.float32))
+        if i < m - 1:
+            parts.append(jnp.zeros((c, (m - 1 - i) * c), jnp.float32))
+        rows.append(jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0])
+    return jnp.concatenate(rows, axis=0) if m > 1 else rows[0]
+
+
+def _tile_pairwise(q, k, g, beta_row, c, precision):
+    """What the forward and the backward kernel both build first of a tile:
+    the running log-decay, the row and column decays through one reference
+    point a sub-chunk (as ``_chunk_terms``: same clamp, same mask), and A and B.
+    q, k (r, dk) as served; g (r, dk) float32; beta_row (1, r) float32."""
+    f32 = jnp.float32
+    op = q.dtype
+    r, dk = k.shape
+    m, sub = r // c, min(_SUB, c)
+    ns = c // sub
+    log_c, log_sub = c.bit_length() - 1, sub.bit_length() - 1
+    pos = lax.broadcasted_iota(jnp.int32, (r, dk), 0) & (c - 1)
+    gc = _cumsum_rows(g, pos, c)
+
+    def before(chunk, i):            # the row whose running sum slot i refers to
+        return chunk * c + i * sub - 1 if i else None
+
+    own = _rows_of(gc, [before(ch, i) for ch in range(m) for i in range(ns)], sub)
+    row = jnp.exp(gc - own)
+    refs = [_rows_of(gc, [before(ch, i) for ch in range(m)], c) for i in range(ns)]
+    cols = [jnp.where(pos < (i + 1) * sub,
+                      jnp.exp(jnp.minimum(refs[i] - gc, _EXP_CLAMP)), 0.0)
+            for i in range(ns)]
+    kf, qf = k.astype(f32), q.astype(f32)
+    # slot i of the contraction holds row sub-chunk i's operands: the rows that
+    # are of sub-chunk i (zero elsewhere) against every column as that
+    # sub-chunk sees it, so ONE product gives every row its own reference
+    kcol = jnp.concatenate([(kf * col).astype(op) for col in cols], axis=1)
+    x = jnp.concatenate([kf * row, qf * row], axis=0)               # (2r, dk)
+    slot = jnp.concatenate([pos, pos], axis=0) >> log_sub
+    lhs = jnp.concatenate([jnp.where(slot == i, x, 0.0).astype(op)
+                           for i in range(ns)], axis=1)             # (2r, ns dk)
+    pair = _mm(lhs, kcol, _NT, precision)                           # (2r, r)
+    ti = lax.broadcasted_iota(jnp.int32, (r, r), 0)
+    tj = lax.broadcasted_iota(jnp.int32, (r, r), 1)
+    same = (ti >> log_c) == (tj >> log_c)
+    beta_col = jnp.sum(jnp.where(ti == tj, beta_row, 0.0), axis=1, keepdims=True)
+    return dict(gc=gc, row=row, refs=refs, cols=cols, kf=kf, qf=qf, kcol=kcol,
+                lhs=lhs, slot=slot, pos=pos, ti=ti, tj=tj, beta_col=beta_col,
+                pair_k=pair[:r], pair_q=pair[r:],
+                below=same & (ti > tj), upto=same & (ti >= tj),
+                ends=_rows_of(gc, [(ch + 1) * c - 1 for ch in range(m)], c))
+
+
+def _tile_inverse(a, ti, tj, c, precision):
+    """(I + a)^-1 for a tile: ``a`` (r, r) strictly lower triangular and block
+    diagonal over the chunks. ``_unit_lower_inverse``'s algorithm on whole
+    tiles: the 16 x 16 diagonal blocks by doubling (as one block-diagonal
+    matrix, whose powers stay block diagonal), then block forward
+    substitution, two neighbours at a time: [[P, 0], [R, Q]]^-1 =
+    X - X [[0, 0], [R, 0]] X with X = diag(P^-1, Q^-1). A generator
+    (``_in_step``): it yields after each product and returns the inverse."""
+    eye = (ti == tj).astype(jnp.float32)
+    mm = functools.partial(_mm, dims=_NN, precision=precision)
+
+    def within(size):                # both indices in one size x size diagonal block
+        shift = size.bit_length() - 1
+        return (ti >> shift) == (tj >> shift)
+
+    size = min(_SUB, c)
+    power = jnp.where(within(size), -a, 0.0)
+    inv = eye + power
+    span = 2
+    while span < size:
+        power = mm(power, power)
+        yield
+        inv = inv + mm(inv, power)
+        yield
+        span *= 2
+    while size < c:
+        below = mm(jnp.where(within(2 * size) & ~within(size), a, 0.0), inv)
+        yield
+        inv = inv - mm(inv, below)
+        yield
+        size *= 2
+    return inv
+
+
+def _terms_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                      w_ref, u0_ref, qg_ref, bm_ref, kend_ref, ec_ref, t_ref,
+                      *, c, r, scale, precision, inv_precision):
+    f32 = jnp.float32
+    dk = k_ref.shape[-1]
+    m = r // c
+
+    def tile(i):
+        rows = slice(i * r, (i + 1) * r)
+        p = _tile_pairwise(q_ref[0, rows], k_ref[0, rows], g_ref[0, rows],
+                           beta_ref[0, 0, i:i + 1, :].astype(f32), c, precision)
+        yield
+        a = jnp.where(p["below"], p["pair_k"] * p["beta_col"], 0.0)
+        t = yield from _tile_inverse(a, p["ti"], p["tj"], c, inv_precision)
+        bm = jnp.where(p["upto"], p["pair_q"] * scale, 0.0)
+        eg = jnp.exp(p["gc"])
+        rhs = jnp.concatenate([p["kf"] * eg * p["beta_col"],
+                               v_ref[0, rows].astype(f32) * p["beta_col"]], axis=1)
+        wu = _mm(t, rhs, _NN, inv_precision)
+        w_ref[0, rows] = wu[:, :dk].astype(w_ref.dtype)
+        u0_ref[0, rows] = wu[:, dk:].astype(u0_ref.dtype)
+        qg_ref[0, rows] = (p["qf"] * eg * scale).astype(qg_ref.dtype)
+        kend_ref[0, rows] = (p["kf"] * jnp.exp(p["ends"] - p["gc"])).astype(kend_ref.dtype)
+        for ch in range(m):
+            blk = slice(ch * c, (ch + 1) * c)
+            bm_ref[0, i * m + ch] = bm[blk, blk].astype(bm_ref.dtype)
+            t_ref[0, i * m + ch] = t[blk, blk]
+            ec_ref[0, i * m + ch] = jnp.exp(p["gc"][(ch + 1) * c - 1:(ch + 1) * c])
+
+    _in_step(tile(i) for i in range(q_ref.shape[1] // r))
+
+
+def _terms_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref,
+                      dw_ref, du0_ref, dqg_ref, dbm_ref, dkend_ref, dec_ref,
+                      dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                      *, c, r, scale, precision, inv_precision):
+    """The chunk terms' cotangents, a tile at a time: the cheap terms rebuilt
+    from the inputs, T read as the forward saved it. With wu = T rhs and
+    d rhs = T^T d wu: dA = -T^T (d wu rhs^T) T^T = -(d rhs) wu^T."""
+    f32 = jnp.float32
+    dk = k_ref.shape[-1]
+    m, sub = r // c, min(_SUB, c)
+    ns = c // sub
+
+    def tile(i):
+        rows = slice(i * r, (i + 1) * r)
+        chunks = range(i * m, (i + 1) * m)
+        p = _tile_pairwise(q_ref[0, rows], k_ref[0, rows], g_ref[0, rows],
+                           beta_ref[0, 0, i:i + 1, :].astype(f32), c, precision)
+        yield
+        gc, kf, qf, beta_col = p["gc"], p["kf"], p["qf"], p["beta_col"]
+        vf = v_ref[0, rows].astype(f32)
+        t = _block_diag([t_ref[0, ch] for ch in chunks])
+        eg = jnp.exp(gc)
+        to_end = jnp.exp(p["ends"] - gc)
+        keg = kf * eg
+        # ---- through T [K e^G beta, V beta]
+        dwu = jnp.concatenate([dw_ref[0, rows].astype(f32),
+                               du0_ref[0, rows].astype(f32)], axis=1)
+        rhs = jnp.concatenate([keg * beta_col, vf * beta_col], axis=1)
+        drhs = _mm(t, dwu, _TN, inv_precision)
+        wu = _mm(t, rhs, _NN, inv_precision)
+        yield
+        da = jnp.where(p["below"], -_mm(drhs, wu, _NT, inv_precision), 0.0)
+        drhs_k, drhs_v = drhs[:, :dk], drhs[:, dk:]
+        dbeta_col = (jnp.sum(da * p["pair_k"], axis=1, keepdims=True)
+                     + jnp.sum(drhs_k * keg, axis=1, keepdims=True)
+                     + jnp.sum(drhs_v * vf, axis=1, keepdims=True))
+        # ---- through A and B's one product
+        dbm = _block_diag([dbm_ref[0, ch] for ch in chunks])
+        dpair = jnp.concatenate([da * beta_col,
+                                 jnp.where(p["upto"], dbm * scale, 0.0)], axis=0)
+        yield
+        dlhs = _mm(dpair, p["kcol"], _NN, precision)                # (2r, ns dk)
+        dkcol = _mm(dpair, p["lhs"], _TN, precision)                # (r, ns dk)
+        yield
+        dx = sum(jnp.where(p["slot"] == s, dlhs[:, s * dk:(s + 1) * dk], 0.0)
+                 for s in range(ns))
+        dx_k, dx_q = dx[:r], dx[r:]
+        drow = (dx_k * kf + dx_q * qf) * p["row"]       # d (gc - own)
+        dq = dx_q * p["row"]
+        dk_ = dx_k * p["row"]
+        dgc = drow
+        dcols = []
+        for s in range(ns):
+            dkc = dkcol[:, s * dk:(s + 1) * dk]
+            dk_ = dk_ + dkc * p["cols"][s]
+            dcol = jnp.where(p["refs"][s] - gc < _EXP_CLAMP,
+                             dkc * kf * p["cols"][s], 0.0)          # d (ref_s - gc)
+            dgc = dgc - dcol
+            dcols.append(dcol)
+        # ---- Q e^G, K e^(G_C - G), e^(G_C), and e^G inside rhs
+        dqg = dqg_ref[0, rows].astype(f32)
+        dkend = dkend_ref[0, rows].astype(f32)
+        dq = dq + dqg * eg * scale
+        dk_ = dk_ + dkend * to_end + drhs_k * eg * beta_col
+        dend = dkend * kf * to_end                                  # d (G_C - gc)
+        dgc = dgc + (dqg * qf * scale + drhs_k * kf * beta_col) * eg - dend
+        # ---- the reverse running sum. What reached a reference point (the
+        # row before a sub-chunk, the chunk's last row) goes to every row up
+        # to it: one vector a sub-chunk, added after the rows' own reverse sum
+        adds = []
+        for n, ch in enumerate(chunks):
+            lo = n * c
+            tail = (jnp.sum(dend[lo:lo + c], axis=0, keepdims=True)
+                    + dec_ref[0, ch] * jnp.exp(gc[lo + c - 1:lo + c]))
+            per_slot = [None] * ns
+            for s in reversed(range(ns)):
+                per_slot[s] = tail
+                if s:
+                    at = lo + s * sub
+                    tail = (tail + jnp.sum(dcols[s][lo:lo + c], axis=0, keepdims=True)
+                            - jnp.sum(drow[at:at + sub], axis=0, keepdims=True))
+            adds += [jnp.broadcast_to(v_, (sub, dk)) for v_ in per_slot]
+        dg = _cumsum_rows(dgc, p["pos"], c, reverse=True) + jnp.concatenate(adds, axis=0)
+        dq_ref[0, rows] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows] = dk_.astype(dk_ref.dtype)
+        dv_ref[0, rows] = (drhs_v * beta_col).astype(dv_ref.dtype)
+        dg_ref[0, rows] = dg
+        dbeta_ref[0, 0, i:i + 1, :] = jnp.sum(
+            jnp.where(p["ti"] == p["tj"], dbeta_col, 0.0), axis=0, keepdims=True)
+
+    _in_step(tile(i) for i in range(q_ref.shape[1] // r))
+
+
+def _terms_specs(bh, n, c, dk, dv):
+    """The grid and block specs for ``n`` chunks of ``c`` tokens a head: ``m``
+    chunks a tile and ``per`` tiles a grid step, both dividing what they
+    count, so no tile is ragged."""
+    m = max(d for d in range(1, n + 1) if n % d == 0 and (d * c <= _TILE or d == 1))
+    tiles = n // m
+    per = max(d for d in range(1, _TILES_PER_STEP + 1) if tiles % d == 0)
+    r = m * c
+    steps = tiles // per
+
+    def spec(*block):
+        zeros = (0,) * (len(block) - 1)
+        return pl.BlockSpec((1,) + block, lambda b, i: (b, i) + zeros,
+                            memory_space=pltpu.VMEM)
+
+    return dict(r=r, grid=(bh, steps), beta_shape=(bh, steps, per, r),
+                k=spec(per * r, dk), v=spec(per * r, dv), beta=spec(1, per, r),
+                cc=spec(per * m, c, c), ec=spec(per * m, 1, dk))
+
+
+_TERMS_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
+
+
+@x32
+@functools.partial(jax.jit, static_argnames=("scale", "c", "interpret"))
+def _terms_fwd_pallas(q, k, v, g, beta, scale, c, interpret):
+    """q, k, g (BH, S, dk), v (BH, S, dv), beta (BH, S), S a multiple of ``c``:
+    the walk's six operands, and T (BH, N, c, c) float32 for the backward."""
+    bh, s, dk = k.shape
+    dv = v.shape[-1]
+    n = s // c
+    z = _terms_specs(bh, n, c, dk, dv)
+    precision, inv_precision = _precisions(q.dtype)
+    op = q.dtype
+    rows = lambda width: jax.ShapeDtypeStruct((bh, s, width), op)
+    w, u0, qg, bm, kend, ec, t = pl.pallas_call(
+        functools.partial(_terms_fwd_kernel, c=c, r=z["r"], scale=scale,
+                          precision=precision, inv_precision=inv_precision),
+        grid=z["grid"],
+        in_specs=[z["k"], z["k"], z["v"], z["k"], z["beta"]],
+        out_specs=[z["k"], z["v"], z["k"], z["cc"], z["k"], z["ec"], z["cc"]],
+        out_shape=[rows(dk), rows(dv), rows(dk),
+                   jax.ShapeDtypeStruct((bh, n, c, c), op), rows(dk),
+                   jax.ShapeDtypeStruct((bh, n, 1, dk), jnp.float32),
+                   jax.ShapeDtypeStruct((bh, n, c, c), jnp.float32)],
+        compiler_params=_TERMS_PARAMS, interpret=interpret,
+        name="mxtpu_kda_chunk_fwd",
+    )(q, k, v, g, beta.reshape(z["beta_shape"]))
+    chunked = lambda x: x.reshape(bh, n, c, x.shape[-1])
+    return chunked(w), chunked(u0), chunked(qg), bm, chunked(kend), ec, t
+
+
+@x32
+@functools.partial(jax.jit, static_argnames=("scale", "c", "interpret"))
+def _terms_bwd_pallas(q, k, v, g, beta, t, cts, scale, c, interpret):
+    bh, s, dk = k.shape
+    dv = v.shape[-1]
+    n = s // c
+    z = _terms_specs(bh, n, c, dk, dv)
+    precision, inv_precision = _precisions(q.dtype)
+    dw, du0, dqg, dbm, dkend, dec = cts
+    flat = lambda x: x.reshape(bh, s, x.shape[-1])
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_terms_bwd_kernel, c=c, r=z["r"], scale=scale,
+                          precision=precision, inv_precision=inv_precision),
+        grid=z["grid"],
+        in_specs=[z["k"], z["k"], z["v"], z["k"], z["beta"], z["cc"],
+                  z["k"], z["v"], z["k"], z["cc"], z["k"], z["ec"]],
+        out_specs=[z["k"], z["k"], z["v"], z["k"], z["beta"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(g.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(z["beta_shape"], jnp.float32)],
+        compiler_params=_TERMS_PARAMS, interpret=interpret,
+        name="mxtpu_kda_chunk_bwd",
+    )(q, k, v, g, beta.reshape(z["beta_shape"]), t,
+      flat(dw), flat(du0), flat(dqg), dbm, flat(dkend), dec)
+    return dq, dk_, dv_, dg, dbeta.reshape(bh, s).astype(beta.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _terms_pallas(q, k, v, g, beta, scale, c, interpret):
+    return _terms_fwd_pallas(q, k, v, g, beta, scale, c, interpret)[:6]
+
+
+def _terms_pallas_fwd(q, k, v, g, beta, scale, c, interpret):
+    *terms, t = _terms_fwd_pallas(q, k, v, g, beta, scale, c, interpret)
+    return tuple(terms), (q, k, v, g, beta, t)
+
+
+def _terms_pallas_bwd(scale, c, interpret, res, cts):
+    return _terms_bwd_pallas(*res, cts, scale, c, interpret)
+
+
+_terms_pallas.defvjp(_terms_pallas_fwd, _terms_pallas_bwd)
+
+
 @jax.named_scope("mxtpu_kda")
 def kda_chunked(q, k, v, g, beta, scale=None, chunk_size=64, use_kernel=False,
-                interpret=None, heads_per_group=8):
+                interpret=None):
     """``o`` (B, H, S, d_v) of the gated delta rule over q, k (B, H, S, d_k),
     v (B, H, S, d_v), per-step log-decay g (B, H, S, d_k; <= 0, float32) and
     beta (B, H, S). ``chunk_size`` is a power of two (16 or more: a multiple
     of 16); S need not be a multiple of it (the tail is padded with tokens
-    that neither decay nor write). ``use_kernel``: walk the chunks in the
-    Pallas kernels instead of the ``lax.scan`` twin.
-
-    The B*H heads are taken ``heads_per_group`` at a time (a ``lax.map`` whose
-    body is checkpointed: the backward rebuilds one group's chunk terms, so
-    the pairwise-decay operands of one group, not of all heads, are live).
+    that neither decay nor write). ``use_kernel``: both phases in Pallas
+    kernels (the chunk terms of all B*H heads in ``mxtpu_kda_chunk_fwd`` /
+    ``_bwd``, the walk in ``mxtpu_kda_fwd`` / ``_bwd``) instead of the
+    ``jax.numpy`` terms and the ``lax.scan`` twin.
 
     Everything here runs under the name scope ``mxtpu_kda``: XLA keeps it in
-    each instruction's ``op_name``, which is how a device trace finds the
-    chunk terms' fusions and the groups' loop beside the walk's kernels."""
+    each instruction's ``op_name``, which is how a device trace finds all of
+    the mechanism's device time, the kernels and what little is left
+    beside them (the padding, the reshapes)."""
     b, h, s, dk = q.shape
     dv = v.shape[-1]
     c = int(chunk_size)
@@ -328,28 +735,22 @@ def kda_chunked(q, k, v, g, beta, scale=None, chunk_size=64, use_kernel=False,
     n = -(-s // c)
     pad = n * c - s
     bh = b * h
-    per = heads_per_group if bh % heads_per_group == 0 else bh
-    groups = bh // per
 
-    def chunks(x, width):
+    def heads(x, width):
         x = x.reshape((bh, s) + ((width,) if width else ()))
         if pad:
             x = jnp.pad(x, ((0, 0), (0, pad)) + (((0, 0),) if width else ()))
-        return x.reshape((groups, per, n, c) + ((width,) if width else ()))
+        return x
 
-    def group(xs):
-        w, u0, qg, bm, kend, ec, precision = _chunk_terms(*xs, scale, c)
-        if use_kernel:
-            return _scan_pallas(w, u0, qg, bm, kend, ec, precision,
-                                resolve_interpret(interpret))
-        return _scan_twin(w, u0, qg, bm, kend, ec, precision)
-
-    xs = (chunks(q, dk), chunks(k, dk), chunks(v, dv), chunks(g, dk),
-          chunks(beta, 0))
-    if groups == 1:
-        o = group(tuple(x[0] for x in xs))
+    xs = (heads(q, dk), heads(k, dk), heads(v, dv), heads(g, dk), heads(beta, 0))
+    if use_kernel:
+        interpret = resolve_interpret(interpret)
+        terms = _terms_pallas(*xs[:3], xs[3].astype(jnp.float32), xs[4], scale, c,
+                              interpret)
+        o = _scan_pallas(*terms, _precisions(q.dtype)[0], interpret)
     else:
-        o = lax.map(jax.checkpoint(group), xs)
+        o = _scan_twin(*_chunk_terms(
+            *(x.reshape((bh, n, c) + x.shape[2:]) for x in xs), scale, c))
     o = o.reshape(bh, n * c, dv)[:, :s]
     return o.reshape(b, h, s, dv).astype(v.dtype)
 

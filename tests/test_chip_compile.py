@@ -145,7 +145,8 @@ def test_lstm_fwd_bwd_compiles(one_chip):
 
 def test_kda_walk_fwd_bwd_compiles(one_chip):
     """One row of Kimi Linear's KDA at published widths: 32 heads x 128,
-    8,192 tokens, chunks of 64 (the walk's kernels; the chunk terms are XLA)."""
+    8,192 tokens, chunks of 64: the chunk terms' kernels (tiles of two
+    chunks, the inverse's hand-split three-pass products) and the walk's."""
     def step(q, k, v, g, beta):
         return jax.grad(lambda *a: _sum(kda_chunked(
             *a, chunk_size=64, use_kernel=True, interpret=False)),
@@ -155,7 +156,8 @@ def test_kda_walk_fwd_bwd_compiles(one_chip):
     text = _compiled_text(step, one_chip, (heads, BF16), (heads, BF16),
                           (heads, BF16), (heads, jnp.float32),
                           (heads[:3], BF16))
-    _assert_kernels(text, "mxtpu_kda_fwd", "mxtpu_kda_bwd")
+    _assert_kernels(text, "mxtpu_kda_chunk_fwd", "mxtpu_kda_chunk_bwd",
+                    "mxtpu_kda_fwd", "mxtpu_kda_bwd")
 
 
 def test_grouped_expert_matmul_fwd_bwd_compiles(one_chip):

@@ -61,8 +61,11 @@ def _kda_inputs(seed, s, dtype=np.float32, strong=False, b=2, h=2, d=16):
 
 
 @pytest.mark.parametrize("use_kernel", [False, True], ids=["scan_twin", "pallas_interpret"])
-@pytest.mark.parametrize("chunk,s", [(16, 40), (32, 64), (64, 100)])
+@pytest.mark.parametrize("chunk,s", [(16, 40), (32, 64), (64, 100), (64, 250), (64, 300)])
 def test_chunked_kda_matches_the_token_recurrence_float32(chunk, s, use_kernel):
+    """Ragged lengths at three chunk sizes; on the kernel path also two tiles
+    a grid step (250 tokens: four chunks, two to a tile) and a tile of one
+    chunk (300: five chunks, which no pair divides)."""
     args = _kda_inputs(chunk + s, s, strong=(s % 3 == 1))
     probe = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
 
@@ -260,11 +263,60 @@ def test_dispatch_tables_place_every_held_slot_once():
     assert (np.asarray(ids).reshape(-1)[placed] - 4 == experts).all()
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr, through its sub-jaxprs (remat, custom
+    derivatives, loops) but not into a kernel's body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_the_kernel_path_keeps_the_chunk_terms_in_the_kernels():
+    """On the chip's path everything inside a chunk is built in VMEM: outside
+    the kernels the gradient's program holds no array larger than the keys
+    (the pairwise-decay operand, four times their size, is gone), no loop
+    over head groups, and under the layer's remat the chunk terms are built
+    twice (forward, remat's forward) and rebuilt once inside their backward
+    kernel, which reads the saved inverse: not three XLA builds and a
+    differentiated fourth."""
+    r = np.random.default_rng(5)
+    shape = (1, 16, 256, 16)
+    q, k, v, g = (jnp.asarray(r.normal(size=shape), jnp.float32) for _ in range(4))
+    beta = jnp.asarray(r.uniform(size=shape[:3]), jnp.float32)
+
+    def loss(*a):
+        return jnp.sum(kda.kda_chunked(*a, chunk_size=64, use_kernel=True, interpret=True))
+
+    for wrap, want in ((lambda f: f, {"mxtpu_kda_chunk_fwd": 1, "mxtpu_kda_chunk_bwd": 1,
+                                      "mxtpu_kda_fwd": 1, "mxtpu_kda_bwd": 1}),
+                       (jax.checkpoint, {"mxtpu_kda_chunk_fwd": 2, "mxtpu_kda_chunk_bwd": 1,
+                                         "mxtpu_kda_fwd": 2, "mxtpu_kda_bwd": 1})):
+        program = jax.make_jaxpr(jax.grad(wrap(loss), argnums=(0, 1, 2, 3, 4)))(
+            q, k, v, -jnp.abs(g), beta)
+        kernels, largest = {}, 0
+        for eqn in _equations(program.jaxpr):
+            name = eqn.primitive.name
+            assert name not in ("while", "scan", "cond"), name
+            if name == "pallas_call":
+                kernels[eqn.params["name"]] = kernels.get(eqn.params["name"], 0) + 1
+            elif name != "jit":     # a jitted kernel wrapper hands on what its kernel made
+                largest = max([largest] + [int(np.prod(o.aval.shape)) for o in eqn.outvars])
+        assert kernels == want
+        assert largest <= int(np.prod(shape))
+
+
 def test_the_name_scopes_reach_the_compiled_program():
     """``kda_roofline`` and ``moe_expert_roofline`` find their mechanism's device
-    time by the name scope XLA keeps on each instruction: the loops that nest
-    the chunk terms (forward, and the backward's rebuild) and the expert
-    layer's branch carry it."""
+    time by the name scope XLA keeps on each instruction: the twin's loops, every
+    product, exponential and loop of the kernel path (forward and backward
+    kernels, here as the interpreter unrolls them), and the expert layer's
+    branch carry it."""
     r = np.random.default_rng(12)
     q, k, v, g = (jnp.asarray(r.normal(size=(1, 16, 64, 16)), jnp.float32) for _ in range(4))
     beta = jnp.asarray(r.uniform(size=(1, 16, 64)), jnp.float32)
@@ -272,6 +324,12 @@ def test_the_name_scopes_reach_the_compiled_program():
     loops = [l for l in step.lower(q, k, v, -jnp.abs(g), beta).compile().as_text().splitlines()
              if " while(" in l]
     assert len(loops) >= 2 and all("mxtpu_kda" in l for l in loops)
+    step = jax.jit(jax.grad(lambda *a: jnp.sum(kda.kda_chunked(
+        *a, chunk_size=16, use_kernel=True, interpret=True)), (0, 1, 2, 3, 4)))
+    lines = step.lower(q, k, v, -jnp.abs(g), beta).compile().as_text().splitlines()
+    for kind in (" while(", " dot(", " exponential("):
+        found = [l for l in lines if kind in l]
+        assert found and all("mxtpu_kda" in l for l in found), kind
     ids = jnp.asarray(r.integers(0, 8, (32, 2)), jnp.int32)
     ones = functools.partial(jnp.ones, dtype=jnp.float32)
     x, w = ones((32, 16)), ones((32, 2))
@@ -405,13 +463,16 @@ def test_remat_rows_changes_neither_the_gradients_nor_the_tally():
         assert np.max(np.abs(g1[name] - g)) <= 1e-4 * max(1.0, np.max(np.abs(g))), name
 
 
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["scan_twin", "pallas_interpret"])
 @pytest.mark.parametrize("chunk", [16, 64])
-def test_chunked_kda_stays_exact_when_keys_repeat(chunk):
+def test_chunked_kda_stays_exact_when_keys_repeat(chunk, use_kernel):
     """Keys that nearly repeat from token to token (what a few optimizer
     steps can do to a fresh model) make I + A far from the identity: the
     chunk's inverse by blocked forward substitution stays with the token
     recurrence, where doubling over the whole chunk was off by 1e19 and sent
-    a chip run to NaN in its fourth step (PR 27)."""
+    a chip run to NaN in its fourth step (PR 27). Both inverses are held to
+    it, the twin's and the kernels' own (whole tiles of chunks at once), and
+    so are the gradients: the kernels' backward reads the saved inverse."""
     r = np.random.default_rng(21)
     b, h, s, d = 1, 2, 128, 16
     base = r.normal(size=(1, 1, 1, d))
@@ -421,6 +482,16 @@ def test_chunked_kda_stays_exact_when_keys_repeat(chunk):
     args = tuple(jnp.asarray(a, jnp.float32) for a in (
         q, k, r.normal(size=(b, h, s, d)), -0.01 * np.abs(r.normal(size=(b, h, s, d))),
         0.9 + 0.1 * r.uniform(size=(b, h, s))))
+    probe = jnp.asarray(np.random.default_rng(3).normal(size=(b, h, s, d)), jnp.float32)
+
+    def chunked(*a):
+        return kda.kda_chunked(*a, chunk_size=chunk, use_kernel=use_kernel, interpret=True)
+
     want = kda.kda_recurrent(*args)
-    out = kda.kda_chunked(*args, chunk_size=chunk)
+    out = chunked(*args)
     assert float(jnp.max(jnp.abs(out - want))) <= 2e-3 * float(jnp.max(jnp.abs(want)))
+    got = jax.grad(lambda *a: jnp.sum(probe * chunked(*a)), argnums=(0, 1, 2, 3, 4))(*args)
+    ref_g = jax.grad(lambda *a: jnp.sum(probe * kda.kda_recurrent(*a)),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("q k v log_decay beta".split(), got, ref_g):
+        assert float(jnp.max(jnp.abs(a - b))) <= 3e-3 * float(jnp.max(jnp.abs(b))), name
