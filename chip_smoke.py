@@ -128,8 +128,7 @@ def check_losses(losses, expect_first, name):
 
 def build_mlm(ctx, vocab=VOCAB, max_length=512, **bert_kw):
     """BERT-base + MLM head + fused cross-entropy as ONE hybridized
-    block (bench.py main_bert's model; ``bert_kw`` shrinks it for CPU
-    rehearsals only). Returns the block; its output is the batch's
+    block (``bert_kw`` shrinks it for CPU rehearsals only). Returns the block; its output is the batch's
     SUMMED token loss, shape (1,)."""
     import mxnet_tpu as mx
     from mxnet_tpu import gluon

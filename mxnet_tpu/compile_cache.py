@@ -4,8 +4,8 @@ Every engine restart used to recompile every shape bucket from
 scratch: the CachedOp contract is "one engine op per subgraph,
 compiled once", and this module extends that *once* across process
 lifetimes. It is the single place the framework configures JAX's
-on-disk compilation cache (``bench.py``, ``CachedOp`` tracing in
-``gluon/block.py`` and executor binding in ``executor.py`` all route
+on-disk compilation cache (``CachedOp`` tracing in
+``gluon/block.py`` and executor binding in ``executor.py`` route
 through :func:`configure`/:func:`ensure`), plus the warm-restart
 manifest plumbing the serving fleet uses to replay visited shape
 buckets before admitting traffic.

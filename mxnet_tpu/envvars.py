@@ -9,9 +9,9 @@ across kernels, dist, serving and telemetry) until this module. Now:
   subsystem scope — and READ here only: :func:`get` returns the parsed,
   typed value (or the declared default), :func:`get_raw` the raw string.
   ``tools/mxlint``'s ``env-raw-read`` pass forbids raw ``os.environ``
-  access to ``MXNET_TPU_*`` names anywhere else in ``mxnet_tpu/``,
-  ``tools/`` and ``bench.py``, and its ``env-unregistered`` check
-  rejects :func:`get` calls for names not declared here;
+  access to ``MXNET_TPU_*`` names anywhere else in ``mxnet_tpu/`` and
+  ``tools/``, and its ``env-unregistered`` check rejects :func:`get`
+  calls for names not declared here;
 - the README "Configuration reference" table is GENERATED from this
   registry (``python -m tools.mxlint --write-envdoc``) and the mxlint
   gate fails when a registered variable is missing from it — the docs
@@ -132,15 +132,6 @@ register("MXNET_TPU_MATMUL_PRECISION", "str", "high",
          "f32 matmul precision: ``high`` = multi-pass bf16 (~f32 "
          "accuracy), ``default`` = fastest single-pass bf16",
          scope="runtime")
-register("MXNET_TPU_CONV_NHWC", "bool", False,
-         "execute 2-D convs internally in NHWC (bench knob; measured "
-         "±0 — XLA's layout assignment is already optimal)",
-         scope="runtime")
-register("MXNET_TPU_EMB_GRAD", "str", "plain",
-         "embedding-backward lowering: ``plain`` take-VJP scatter, "
-         "``sorted`` sort+segment-sum, ``bf16`` bf16-accumulated "
-         "scatter (A/B knob; both alternatives measured slower on v5e)",
-         scope="runtime")
 register("MXNET_TPU_MODEL_STORE", "path", None,
          "model-zoo download/cache root (falls back to "
          "``$MXNET_HOME/models``, then ``~/.mxnet/models``)",
@@ -175,22 +166,6 @@ register("MXNET_TPU_PALLAS_INTERPRET", "bool", False,
 register("MXNET_TPU_DISABLE_PALLAS", "bool", False,
          "force the plain jnp/XLA lowering for every fused-kernel op",
          scope="kernels")
-register("MXNET_TPU_FLASH_BLOCK_Q", "int", 512,
-         "flash-attention query-tile cap (v5e-measured optimum 512)",
-         scope="kernels")
-register("MXNET_TPU_FLASH_BLOCK_K", "int", 2048,
-         "flash-attention kv-tile cap (effective tile is "
-         "``min(seq, cap)``)", scope="kernels")
-register("MXNET_TPU_FLASH_SPLIT_BWD", "bool", False,
-         "use the two-kernel flash-attention backward instead of the "
-         "fused one-pass kernel (A/B + fallback)", scope="kernels")
-register("MXNET_TPU_FUSED_LSTM", "bool", False,
-         "opt-in whole-sequence Pallas LSTM kernel (XLA's scan measured "
-         "faster at WikiText-2 shapes; see BASELINE.md)", scope="kernels")
-register("MXNET_TPU_XENT_BLOCK_N", "int", 128,
-         "fused softmax-CE kernel row-tile cap", scope="kernels")
-register("MXNET_TPU_XENT_BLOCK_V", "int", 2048,
-         "fused softmax-CE kernel vocab-tile cap", scope="kernels")
 
 # -- distributed ------------------------------------------------------------
 register("MXNET_TPU_COORDINATOR", "str", None,
@@ -343,8 +318,8 @@ register("MXNET_TPU_ATTRIBUTION_TOP", "int", 3,
 # -- telemetry: continuous profiler / resource accounting -------------------
 register("MXNET_TPU_PROF", "bool", True,
          "always-on continuous sampling profiler daemon (Google-Wide-"
-         "Profiling style): started by serving engines/routers and "
-         "bench legs, samples every thread's Python stack into bounded "
+         "Profiling style): started by serving engines/routers, "
+         "samples every thread's Python stack into bounded "
          "folded-stack counts served at ``/profile``; ``0`` disables",
          scope="telemetry")
 register("MXNET_TPU_PROF_HZ", "float", 19.0,
@@ -724,15 +699,6 @@ register("MXNET_TPU_SANITIZE_HOLD_MS", "float", 100.0,
          "reported (``long-hold``) — the convoy shape, not mere "
          "slowness", scope="sanitize")
 
-# -- bench ------------------------------------------------------------------
-register("MXNET_TPU_PEAK_TFLOPS", "float", None,
-         "override the per-chip peak dense bf16 TFLOP/s used for "
-         "bench.py MFU (unset = inferred from device kind)",
-         scope="bench")
-register("MXNET_TPU_PEAK_HBM_GBPS", "float", None,
-         "override the per-chip peak HBM bandwidth GB/s used for "
-         "bench.py roofline fields", scope="bench")
-
 # -- tests / dev harness ----------------------------------------------------
 register("MXNET_TPU_TEST_REAL_DEVICE", "bool", False,
          "run the test suite against the real backend instead of the "
@@ -765,7 +731,6 @@ _SCOPE_TITLES = OrderedDict([
     ("history", "Retrospective history"),
     ("capture", "Traffic capture & shadow validation"),
     ("sanitize", "Concurrency sanitizer"),
-    ("bench", "Benchmarks"),
     ("tests", "Tests / dev harness"),
 ])
 

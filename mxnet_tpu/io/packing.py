@@ -30,14 +30,14 @@ Loss masks derive as ``segment_ids > 0``.
 Positions are bounded by each SAMPLE's length, not the row length —
 so a model with a finite position table (BERT ``max_length``) can pack
 into rows LONGER than the table as long as every individual sample
-stays within it (the bench packs 512-max samples into 2048-slot rows
-against a 512-entry table).
+stays within it (e.g. 512-max samples in 2048-slot rows against a
+512-entry table).
 
 ``pack_sequences`` is greedy first-fit in arrival order — the online
 algorithm a streaming corpus reader can run (rows stay open until the
-stream ends). For a bench-style fixed row budget, pack a modest
-oversample and keep the fullest rows (bench.py does this; first-fit's
-open tail rows are the only low-occupancy ones).
+stream ends). For a fixed row budget, pack a modest oversample and
+keep the fullest rows (first-fit's open tail rows are the only
+low-occupancy ones).
 """
 from __future__ import annotations
 

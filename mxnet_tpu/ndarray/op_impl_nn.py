@@ -52,20 +52,6 @@ def fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False, fla
 _CONV_DN = {1: ("NCW", "OIW", "NCW"), 2: ("NCHW", "OIHW", "NCHW"),
             3: ("NCDHW", "OIDHW", "NCDHW")}
 
-# Internal NHWC execution for 2-D convs (MXNET_TPU_CONV_NHWC=1): the API
-# stays NCHW (MXNet contract) but each conv transposes to NHWC — the
-# layout the TPU vector unit natively tiles — and back. Consecutive
-# convs' transpose pairs cancel in XLA; measured as a bench.py knob.
-# Read per call (at trace time) so setting the env before building a
-# model takes effect even if mxnet_tpu was imported earlier. NOTE:
-# already-compiled jit caches are keyed on shapes only — toggling the
-# knob affects new traces, not cached executables.
-
-
-def _conv_nhwc():
-    from .. import envvars
-    return envvars.get("MXNET_TPU_CONV_NHWC")
-
 
 @register_op("Convolution")
 def convolution(data, weight, bias=None, kernel=None, stride=None, dilate=None,
@@ -77,30 +63,15 @@ def convolution(data, weight, bias=None, kernel=None, stride=None, dilate=None,
     pad = _tup(pad, nd_) or (0,) * nd_
     # bf16 convs accumulate in f32 on the MXU natively; forcing
     # preferred_element_type would break the VJP's dtype contract
-    if _conv_nhwc() and nd_ == 2:
-        xt = jnp.transpose(data, (0, 2, 3, 1))
-        wt = jnp.transpose(weight, (2, 3, 1, 0))
-        dn = lax.conv_dimension_numbers(xt.shape, wt.shape,
-                                        ("NHWC", "HWIO", "NHWC"))
-        out = lax.conv_general_dilated(
-            xt, wt,
-            window_strides=stride,
-            padding=[(p, p) for p in pad],
-            rhs_dilation=dilate,
-            dimension_numbers=dn,
-            feature_group_count=int(num_group),
-        )
-        out = jnp.transpose(out, (0, 3, 1, 2))
-    else:
-        dn = lax.conv_dimension_numbers(data.shape, weight.shape, _CONV_DN[nd_])
-        out = lax.conv_general_dilated(
-            data, weight,
-            window_strides=stride,
-            padding=[(p, p) for p in pad],
-            rhs_dilation=dilate,
-            dimension_numbers=dn,
-            feature_group_count=int(num_group),
-        )
+    dn = lax.conv_dimension_numbers(data.shape, weight.shape, _CONV_DN[nd_])
+    out = lax.conv_general_dilated(
+        data, weight,
+        window_strides=stride,
+        padding=[(p, p) for p in pad],
+        rhs_dilation=dilate,
+        dimension_numbers=dn,
+        feature_group_count=int(num_group),
+    )
     if bias is not None and not no_bias:
         out = out + bias.reshape((1, -1) + (1,) * nd_)
     # remat-policy anchor: under jax.checkpoint with
@@ -748,75 +719,10 @@ def dropout(data, p=0.5, mode="training", axes=None, _training=True, _rng_key=No
 # ----------------------------------------------------------------------
 # Embedding (src/operator/tensor/indexing_op.cc Embedding)
 # ----------------------------------------------------------------------
-@jax.custom_vjp
-def _take_rows_sorted_grad(weight, idx):
-    return jnp.take(weight, idx, axis=0)
-
-
-def _take_rows_fwd(weight, idx):
-    # residuals must be JAX types: a zero-size slice carries the
-    # table's row count and dtype without holding the table alive
-    token = jnp.zeros((weight.shape[0], 0), weight.dtype)
-    return jnp.take(weight, idx, axis=0), (idx, token)
-
-
-def _take_rows_bwd(res, g):
-    # table gradient via SORT + segment-sum instead of the default take
-    # VJP's random-order scatter-add: collisions (duplicate ids in the
-    # batch) serialize scatter writes on TPU, while a sorted
-    # segment_sum (indices_are_sorted) accumulates each table row's
-    # contributions in one linear pass — the kvstore_local.h
-    # unique-rowid merge, in-graph
-    idx, token = res
-    flat_idx = idx.reshape(-1)
-    gf = g.reshape(-1, g.shape[-1])
-    order = jnp.argsort(flat_idx)
-    dW = jax.ops.segment_sum(gf[order], flat_idx[order],
-                             num_segments=token.shape[0],
-                             indices_are_sorted=True)
-    return dW.astype(token.dtype), None
-
-
-_take_rows_sorted_grad.defvjp(_take_rows_fwd, _take_rows_bwd)
-
-
-@jax.custom_vjp
-def _take_rows_bf16_grad(weight, idx):
-    return jnp.take(weight, idx, axis=0)
-
-
-def _take_rows_bf16_bwd(res, g):
-    # accumulate the table gradient scatter in bf16 (32B rows vs 64B
-    # against the VMEM-write-bound scatter unit), densify to the
-    # table's dtype after — trades collision-accumulation precision
-    # for scatter bytes
-    idx, token = res
-    flat_idx = idx.reshape(-1)
-    gf = g.reshape(-1, g.shape[-1]).astype(jnp.bfloat16)
-    dW = jnp.zeros((token.shape[0], g.shape[-1]), jnp.bfloat16)
-    dW = dW.at[flat_idx].add(gf)
-    return dW.astype(token.dtype), None
-
-
-_take_rows_bf16_grad.defvjp(_take_rows_fwd, _take_rows_bf16_bwd)
-
-
 @register_op("Embedding")
 def embedding(data, weight, input_dim=None, output_dim=None, dtype="float32",
               sparse_grad=False):
     idx = data.astype(jnp.int32)
-    # MXNET_TPU_EMB_GRAD=sorted: sort+segment-sum table gradient
-    # (kvstore unique-rowid merge in-graph). A/B on v5e (W&D b8192,
-    # chain=10): 428.9k vs 618.1k ex/s — the 213k-row sort+permute
-    # costs MORE than scatter collision serialization saves, so the
-    # default stays the plain take VJP; the option remains for
-    # narrow-table/high-collision workloads.
-    from .. import envvars as _envvars
-    mode = _envvars.get("MXNET_TPU_EMB_GRAD")
-    if mode == "sorted":
-        return _take_rows_sorted_grad(weight, idx)
-    if mode == "bf16":
-        return _take_rows_bf16_grad(weight, idx)
     return jnp.take(weight, idx, axis=0)
 
 

@@ -118,27 +118,6 @@ def _run_layer(x, w_i2h, w_h2h, b_i2h, b_h2h, h0, c0, mode, reverse=False):
         h_last, out = lax.scan(scan_fn, h0, gin_x)
         c_last = None
     elif mode == "lstm":
-        from ..ops.pallas._util import pallas_ok_for
-        from .. import envvars as _envvars
-        if pallas_ok_for(x) and _envvars.get("MXNET_TPU_FUSED_LSTM"):
-            # OPT-IN fused whole-sequence kernel (weight-stationary
-            # recurrent matmul + gates in VMEM, one kernel for the
-            # T-step loop — the cudnn_rnn-inl.h analog). Measured on
-            # the WikiText-2 LM (650x2): b128 379k tok/s vs 382k for
-            # the lax.scan path, b32 140k vs 157k — XLA's unrolled
-            # while-loop + fusion already wins at these shapes, so the
-            # kernel is not the default; it remains available (and
-            # golden-tested) for dispatch-bound deployments.
-            from ..ops.pallas.lstm import lstm_layer_fused
-            out, cseq = lstm_layer_fused(
-                (gin_x + b_h2h).astype(x.dtype),
-                w_h2h.T.astype(x.dtype), h0, c0)
-            # final state = last PROCESSED step — grab it before the
-            # reverse direction flips out back to forward-time order
-            h_last = out[-1]
-            if reverse:
-                out = jnp.flip(out, axis=0)
-            return out, h_last, cseq[-1].astype(c0.dtype)
         step = _cell_step(mode, H)
 
         def scan_fn(carry, gx):

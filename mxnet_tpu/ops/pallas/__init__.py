@@ -14,8 +14,6 @@ we only drop to Pallas where XLA's own fusion genuinely loses:
   performance play for the BERT north star).
 - ``softmax_xent`` — fused large-vocab softmax cross-entropy (LM
   heads: avoids materializing the (N, V) log-softmax for backward).
-- ``lstm`` — whole-sequence fused LSTM layer (weight-stationary
-  recurrent matmul + gates in one kernel; the cudnn_rnn-inl.h analog).
 - ``kda`` — the gated delta rule (KDA linear attention) in chunks: the
   state's walk over the chunks with the state in VMEM, forward and a
   hand-written backward (``kda_chunked``).
@@ -38,7 +36,6 @@ from .flash_attention import flash_attention, flash_attention_with_lse  # noqa: 
 from .flash_attention import (paged_attention_reference,  # noqa: E402
                               paged_flash_attention)
 from .softmax_xent import softmax_xent_fused  # noqa: E402
-from .lstm import lstm_layer_fused  # noqa: E402
 from .kda import kda_chunked  # noqa: E402
 from .moe import experts_held, grouped_matmul  # noqa: E402
 
@@ -52,7 +49,6 @@ __all__ = [
     "paged_flash_attention",
     "paged_attention_reference",
     "softmax_xent_fused",
-    "lstm_layer_fused",
     "kda_chunked",
     "grouped_matmul",
     "experts_held",

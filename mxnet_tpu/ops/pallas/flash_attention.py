@@ -448,20 +448,21 @@ def _pad0(x, pad):
     return jnp.pad(x, pad, constant_values=np.zeros((), x.dtype))
 
 
+# Tile caps: 512-wide q tiles with the kv tile as large as fits (cap
+# 2048), so that up to S=2048 the kv grid is one block: k/v stay
+# resident, the one-pass backward needs no dq partial-sum, and the
+# (q, do, lse, delta) reloads amortize across the whole row. VMEM: the
+# f32 score block is bq*bk*4 = 4 MB at 512x2048 (d<=128 keeps operand
+# blocks ~1 MB), inside the ~16 MB budget. The caps are what every
+# ledger run has used (S=512 and S=8192); whether 512x2048 is right at
+# S=2048 packed is not measured (ROADMAP.md Queue 2 workload 3).
+_BLOCK_Q_CAP = 512
+_BLOCK_K_CAP = 2048
+
+
 def _pick_blocks(sq, skv):
-    # v5e-measured defaults (BASELINE.md round-3/4 sweeps): 512-wide q
-    # tiles with the k tile as large as fits (cap 2048) — at seq2048
-    # the single-k-block grid (512x2048) measured 87.6k tok/s vs 74.8k
-    # at 512x512 (+17%): k/v stay resident, the fused backward needs no
-    # dq partial-sum, and (q, do, lse, delta) reloads amortize across
-    # the whole row. VMEM: the f32 score block is bq*bk*4 = 4 MB at
-    # 512x2048 (d<=128 keeps operand blocks ~1 MB), inside the ~16 MB
-    # budget. Override per run with MXNET_TPU_FLASH_BLOCK_Q/K.
-    from ... import envvars
-    bq_cap = envvars.get("MXNET_TPU_FLASH_BLOCK_Q")
-    bk_cap = envvars.get("MXNET_TPU_FLASH_BLOCK_K")
-    bq = min(bq_cap, _pad_len(sq, 8))
-    bk = min(bk_cap, _pad_len(skv, 128))
+    bq = min(_BLOCK_Q_CAP, _pad_len(sq, 8))
+    bk = min(_BLOCK_K_CAP, _pad_len(skv, 128))
     return bq, bk
 
 
@@ -605,8 +606,6 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
 def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, dlse=None, kv_lens=None,
                segment_ids=None):
-    from ... import envvars as _envvars
-
     b, h, sq, d = q.shape
     skv = k.shape[2]
     bq0, bk0 = _pick_blocks(sq, skv)
@@ -656,7 +655,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     # that memory/write cliff outweighs the recompute saving, so long
     # multi-k-block rows (S > 2*block_k cap) take the split path whose
     # dq accumulates in VMEM scratch
-    if nk <= 2 and not _envvars.get("MXNET_TPU_FLASH_SPLIT_BWD"):
+    if nk <= 2:
         return _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
                                 (b, h, sq, skv, d), nq, nk, common,
                                 interpret, k.dtype, v.dtype, q.dtype)
@@ -667,8 +666,10 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
 
 def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                      nq, nk, common, interpret, k_dtype, v_dtype, q_dtype):
-    """Single-pass dq/dk/dv (default; MXNET_TPU_FLASH_SPLIT_BWD=1
-    selects the two-kernel path for A/B and as a fallback)."""
+    """Single-pass dq/dk/dv, taken where the kv grid has at most two
+    blocks: ``bert_base.train_b64x512`` (S=512, nk=1) runs this kernel;
+    ``kimi_linear_48b_a3b.train_8k``'s latent attention (S=8192 at the
+    2048 cap, nk=4) runs ``_flash_bwd_split``'s dq/dkv pair."""
     b, h, sq, skv, d = dims
     bh = b * h
     block_q, block_k = common["block_q"], common["block_k"]

@@ -69,10 +69,14 @@ def _pad_to(n, m):
     return ((n + m - 1) // m) * m
 
 
+# row- and vocab-tile caps
+_BLOCK_N_CAP = 128
+_BLOCK_V_CAP = 2048
+
+
 def _blocks(n, v):
-    from ... import envvars
-    bn = min(envvars.get("MXNET_TPU_XENT_BLOCK_N"), _pad_to(n, 8))
-    bv = min(envvars.get("MXNET_TPU_XENT_BLOCK_V"), _pad_to(v, 128))
+    bn = min(_BLOCK_N_CAP, _pad_to(n, 8))
+    bv = min(_BLOCK_V_CAP, _pad_to(v, 128))
     return bn, bv
 
 

@@ -140,6 +140,30 @@ def test_integer_input_no_grad():
     assert_almost_equal(w.grad, expected)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_embedding_gradient_adds_repeated_rows(dtype):
+    """Ids that repeat heavily: every occurrence's cotangent is ADDED
+    into its table row (np.add.at), in the table's dtype. The
+    cotangents are small integers, so the sums are exact in bfloat16
+    too."""
+    rng = np.random.RandomState(3)
+    rows, dim = 6, 8
+    ids = rng.randint(0, rows - 1, size=(4, 16))        # row 5 never looked up
+    cot = rng.randint(0, 4, size=ids.shape + (dim,)).astype(np.float32)
+    w = nd.array(rng.rand(rows, dim).astype(np.float32)).astype(dtype)
+    w.attach_grad()
+    with autograd.record():
+        out = nd.Embedding(nd.array(ids, dtype="int32"), w,
+                           input_dim=rows, output_dim=dim)
+        loss = (out * nd.array(cot).astype(dtype)).sum()
+    loss.backward()
+    expected = np.zeros((rows, dim), np.float32)
+    np.add.at(expected, ids, cot)
+    assert expected.max() > 16 and not expected[rows - 1].any()
+    assert str(w.grad.dtype) == dtype
+    assert_almost_equal(w.grad.astype("float32"), expected, rtol=0, atol=0)
+
+
 def test_retain_graph():
     x = nd.array([2.0])
     x.attach_grad()

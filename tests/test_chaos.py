@@ -341,12 +341,18 @@ def chaos_drill_env(monkeypatch, tmp_path):
     monkeypatch.setenv("MXNET_TPU_SLO_EVAL_S", "0.1")
     # margin matters: normal stub latency must stay WELL under the
     # objective even instrumented (mxsan) or on a host shared with
-    # five other test workers — only the induced hot-spot (180 ms,
-    # below) may breach it, or fleet-wide slow-burn tickets hold the
-    # incident open past the drill's patience. At 50 ms / 80 ms the
-    # loaded host's own jitter crossed the objective; the scale is
-    # doubled (objectives snap to histogram boundaries: 50 → 100)
-    monkeypatch.setenv("MXNET_TPU_SLO_LATENCY_MS", "100")
+    # five other test workers — only the induced hot-spot (1500 ms,
+    # below) may breach it, or fleet-wide burn alerts hold the incident
+    # open (and the hot seat's weight down) past the drill's patience.
+    # The drill's six closed-loop clients, both routers and all three
+    # engines share ONE interpreter: a request takes ~70 ms at the
+    # median on an idle host, and beside ten busy processes 100-150 ms
+    # at the median, 250-290 at p90 and 650-860 at p99. The objective
+    # is a p99 (a 6x burn is 6% of requests over it), so 100 ms and
+    # then 250 ms still timed out re-converging under six xdist
+    # workers; 1000 ms (objectives snap to histogram boundaries) is
+    # over the loaded p99
+    monkeypatch.setenv("MXNET_TPU_SLO_LATENCY_MS", "1000")
     monkeypatch.setenv("MXNET_TPU_CANARY_INTERVAL_S", "0.25")
     monkeypatch.setenv("MXNET_TPU_CANARY_TIMEOUT_S", "5")
     monkeypatch.setenv("MXNET_TPU_FLIGHT_DIR", str(tmp_path / "flight"))
@@ -361,8 +367,7 @@ def chaos_drill_env(monkeypatch, tmp_path):
 
 
 def test_chaos_drill_end_to_end(chaos_drill_env):
-    """The acceptance drill (stub-model tier-1 shape; the bench leg
-    runs the same harness over real BERT engines): under closed-loop
+    """The acceptance drill (stub-model tier-1 shape): under closed-loop
     load through two active/active routers —
 
     - an induced hot-spot sheds routing weight off the slow seat and
@@ -384,7 +389,7 @@ def test_chaos_drill_end_to_end(chaos_drill_env):
                              max_rows=2, engine_id=engine_id)
 
     report = run_chaos_drill(make_engine, n_engines=3, n_clients=6,
-                             hot_ms=180.0, phase_timeout_s=60.0,
+                             hot_ms=1500.0, phase_timeout_s=60.0,
                              vocab=60, min_len=4, max_len=12)
     assert report["lost"] == 0
     assert report["completed"] == report["attempts"] > 0

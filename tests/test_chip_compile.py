@@ -14,6 +14,7 @@ the same reason (a second file could land on another worker, whose
 fixture would skip it).
 """
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +22,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.ops.pallas import (experts_held, flash_attention, kda_chunked,
-                                  layer_norm_fused, lstm_layer_fused,
-                                  paged_flash_attention, softmax_xent_fused)
+                                  layer_norm_fused, paged_flash_attention,
+                                  softmax_xent_fused)
 
 BF16 = jnp.bfloat16
 
@@ -46,6 +47,17 @@ def one_chip():
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+# the package re-exports the function under the submodule's name
+flash_mod = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+
+
+def flash_caps(monkeypatch, block_q, block_k):
+    """Other tiles than the defaults' (several blocks at a small S):
+    set the module's tile caps for one test."""
+    monkeypatch.setattr(flash_mod, "_BLOCK_Q_CAP", block_q)
+    monkeypatch.setattr(flash_mod, "_BLOCK_K_CAP", block_k)
 
 
 def _compiled_text(fn, one_chip, *shapes):
@@ -84,8 +96,7 @@ _FLASH = {
 def test_flash_attention_fwd_bwd_compiles(one_chip, monkeypatch, case):
     shape, causal, use_lens, use_segs, tiles = _FLASH[case]
     if tiles:
-        monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_Q", str(tiles[0]))
-        monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_K", str(tiles[1]))
+        flash_caps(monkeypatch, *tiles)
     b, _, s, _ = shape
 
     def step(q, k, v, lens, segs):
@@ -128,19 +139,6 @@ def test_paged_flash_attention_compiles(one_chip, sq):
     text = _compiled_text(fn, one_chip, ((32, 12, sq, 64), BF16), pool, pool,
                           ((32, 64), jnp.int32), ((32,), jnp.int32))
     _assert_kernels(text, "mxtpu_paged_flash_fwd")
-
-
-def test_lstm_fwd_bwd_compiles(one_chip):
-    def step(gin, w, h0, c0):
-        def loss(gin, w, h0, c0):
-            out, cseq = lstm_layer_fused(gin, w, h0, c0, False)
-            return _sum(out) + _sum(cseq[-1])
-        return jax.grad(loss, argnums=(0, 1, 2, 3))(gin, w, h0, c0)
-
-    text = _compiled_text(step, one_chip, ((35, 128, 2600), BF16),
-                          ((650, 2600), BF16), ((128, 650), BF16),
-                          ((128, 650), BF16))
-    _assert_kernels(text, "mxtpu_lstm_fwd", "mxtpu_lstm_bwd")
 
 
 def test_kda_walk_fwd_bwd_compiles(one_chip):

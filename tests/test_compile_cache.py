@@ -21,6 +21,7 @@ from mxnet_tpu import compile_cache, nd
 from mxnet_tpu.serving import ServingEngine, ServingRouter
 from mxnet_tpu.telemetry import events
 from mxnet_tpu.telemetry import recorder as flight
+from test_selfheal import _wait
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -298,13 +299,22 @@ def test_watchdog_tolerates_first_visit_compile_but_trips_on_stall(
         tmp_path, monkeypatch):
     """A first-visit 'compile' longer than the stall threshold must
     NOT trip the serving-stall probe (the compile window widens it);
-    a genuine stall on an already-compiled shape still must."""
+    a genuine stall on an already-compiled shape still must.
+
+    Nothing is read after a fixed time: the compile is several stall
+    thresholds long, the quiet stretch after it is counted in watchdog
+    polls, and the stall's event and bundle are each waited for. (Under
+    six xdist workers the bundle used to be listed while the watchdog
+    thread was still writing it.)"""
     monkeypatch.setenv("MXNET_TPU_FLIGHT_DIR", str(tmp_path / "flight"))
     events.configure(str(tmp_path / "wd.jsonl"))
     saved = flight.configure()
-    flight.configure(interval_s=0.05, stall_s=0.3,
+    stall_s = 1.0       # a starved worker thread must not look wedged
+    flight.configure(interval_s=0.05, stall_s=stall_s,
                      min_dump_interval_s=0.0)
     gate = threading.Event()
+    polls = []
+    flight.register_probe("poll_counter", lambda: polls.append(1))
 
     class CompileThenStall:
         """1st call per shape: slow (a compile). Later calls: instant,
@@ -317,22 +327,26 @@ def test_watchdog_tolerates_first_visit_compile_but_trips_on_stall(
                      positions):
             self.calls += 1
             if self.calls == 1:
-                time.sleep(0.9)             # compile >> stall_s
+                time.sleep(3 * stall_s)     # compile >> stall_s
             elif self.calls >= 3:
                 gate.wait(30)               # genuine stall
             return nd.array(ids.asnumpy().astype(np.float32)[..., None])
 
+    def stall_trips():
+        return [t for t in events.read_events(log_path,
+                                              event="watchdog_anomaly")
+                if t.get("kind") == "serving_worker_stall"]
+
     eng = ServingEngine(CompileThenStall(), bucket_lens=(16,),
                         max_rows=1)
-    log_path = None
+    fut = None
     try:
         eng.start()
         eng.infer([1, 2, 3], timeout=30)    # slow first-visit compile
         log_path = events.get_log().path
-        time.sleep(0.4)                     # several watchdog polls
-        trips = events.read_events(log_path, event="watchdog_anomaly")
-        stalls = [t for t in trips
-                  if t.get("kind") == "serving_worker_stall"]
+        seen = len(polls)
+        _wait(lambda: len(polls) >= seen + 5, what="five watchdog polls")
+        stalls = stall_trips()
         assert not stalls, f"compile window tripped the watchdog: {stalls}"
         compiles = events.read_events(log_path, event="compile_end")
         assert compiles and compiles[0]["result"] in ("miss",
@@ -340,28 +354,24 @@ def test_watchdog_tolerates_first_visit_compile_but_trips_on_stall(
 
         eng.infer([4, 5], timeout=30)       # memory_hit, fast
         fut = eng.submit([6, 7, 8])         # 3rd call: wedges
-        deadline = time.monotonic() + 20
-        stalls = []
-        while time.monotonic() < deadline and not stalls:
-            trips = events.read_events(log_path, event="watchdog_anomaly")
-            stalls = [t for t in trips
-                      if t.get("kind") == "serving_worker_stall"]
-            time.sleep(0.05)
-        assert stalls, "genuine stall never tripped the watchdog"
+        _wait(stall_trips, what="the genuine stall to trip the watchdog")
+        # the compile window produced no bundle; the stall did (the
+        # event is emitted before the bundle is renamed into place)
+        root = str(tmp_path / "flight")
+        _wait(lambda: os.path.isdir(root) and any(
+            "serving_worker_stall" in d and not d.endswith(".tmp")
+            for d in os.listdir(root)), what="the stall's flight bundle")
     finally:
         gate.set()
-        try:
-            fut.result(timeout=30)
-        except Exception:
-            pass
+        if fut is not None:
+            try:
+                fut.result(timeout=30)
+            except Exception:
+                pass
         eng.stop()
+        flight.unregister_probe("poll_counter")
         events.configure(None)
         flight.configure(**saved)
-    # the compile window produced no bundle; the stall did
-    root = str(tmp_path / "flight")
-    bundles = [d for d in os.listdir(root)] if os.path.isdir(root) else []
-    assert any("serving_worker_stall" in d for d in bundles
-               if not d.endswith(".tmp"))
 
 
 def test_router_poll_does_not_mark_compiling_engine_down():

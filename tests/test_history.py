@@ -6,9 +6,8 @@ quantile-over-time, straight numbers), the exposition endpoints
 incident freezes the PRECEDING window into the flight bundle), retro
 SLO replay (the live firing decision reproduces from the persisted
 evidence — and fails to reproduce at a healthy instant, proving the
-audit has teeth), the exemplar-bearing tenant merge round-trip, the
-torn-tail ``read_events`` hardening, and the bench_regress sentry
-(flags an injected regression, passes the real trajectory).
+audit has teeth), the exemplar-bearing tenant merge round-trip, and the
+torn-tail ``read_events`` hardening.
 
 CPU-only, thread-light: the store and scraper are driven manually
 with explicit timestamps wherever determinism matters.
@@ -552,70 +551,3 @@ def test_read_events_skips_and_counts_torn_tail(tmp_path):
     # filter still applies; a caller that doesn't ask doesn't pay
     assert [r["n"] for r in
             events_mod.read_events(str(p), event="b")] == [2]
-
-
-# ---------------------------------------------------------------------------
-# bench_regress: the perf-regression sentry
-# ---------------------------------------------------------------------------
-
-def _bench_rec(**metrics):
-    tail = "".join(json.dumps({"metric": k, "value": v}) + "\n"
-                   for k, v in metrics.items())
-    return {"n": 1, "cmd": "x", "rc": 0, "tail": tail, "parsed": None}
-
-
-def test_bench_regress_judge_directions_and_noise():
-    import bench_regress as br
-    assert br.direction("bert_base_train_tokens_per_sec_per_chip") == 1
-    assert br.direction("serving_p99_ms") == -1
-    assert br.direction("suite_budget_skipped") == 0
-
-    recs = [("r1", {}, {"syn_tokens_per_sec": 100.0}),
-            ("r2", {}, {"syn_tokens_per_sec": 102.0}),
-            ("r3", {}, {"syn_tokens_per_sec": 80.0})]
-    rows, regressions = br.judge(recs, floor=0.10)
-    assert [r["metric"] for r in regressions] == ["syn_tokens_per_sec"]
-    assert regressions[0]["status"] == "REGRESSION"
-
-    # a metric the candidate misses is a visibility gap, not a flag
-    recs = [("r1", {}, {"syn_p99_ms": 10.0, "gone_per_sec": 5.0}),
-            ("r2", {}, {"syn_p99_ms": 30.0})]
-    rows, regressions = br.judge(recs, floor=0.10)
-    by = {r["metric"]: r for r in rows}
-    assert by["gone_per_sec"]["status"] == "skipped"
-    assert by["syn_p99_ms"]["status"] == "REGRESSION"   # latency UP
-
-    # historically jittery metric: tolerance widens past the floor
-    recs = [("r%d" % i, {}, {"syn_tokens_per_sec": v})
-            for i, v in enumerate([100.0, 140.0, 100.0, 140.0])]
-    recs.append(("cand", {}, {"syn_tokens_per_sec": 80.0}))
-    rows, regressions = br.judge(recs, floor=0.10)
-    assert not regressions, rows      # 2x median step = 80% tolerance
-
-    # best-of-repeats: the tail's best value per record is scored
-    rec = _bench_rec()
-    rec["tail"] = (json.dumps({"metric": "syn_tokens_per_sec",
-                               "value": 90.0}) + "\n"
-                   + json.dumps({"metric": "syn_tokens_per_sec",
-                                 "value": 110.0}) + "\n")
-    assert br.record_metrics(rec) == {"syn_tokens_per_sec": 110.0}
-
-
-def test_bench_regress_cli_flags_injected_regression(tmp_path, capsys):
-    import bench_regress as br
-    paths = []
-    for i, v in enumerate([100.0, 104.0, 101.0]):
-        p = tmp_path / f"BENCH_r{i + 1:02d}.json"
-        p.write_text(json.dumps(_bench_rec(
-            syn_tokens_per_sec=v, syn_p99_ms=20.0 + i)))
-        paths.append(str(p))
-    assert br.main(paths) == 0
-    assert br.main(["--dir", str(tmp_path)]) == 0
-    assert br.main(paths + ["--inject",
-                            "syn_tokens_per_sec=50.0"]) == 1
-    assert br.main([paths[0]]) == 2             # one record: no diff
-    capsys.readouterr()
-    assert br.main(paths + ["--json"]) == 0
-    out = json.loads(capsys.readouterr().out)
-    assert out["regressions"] == 0
-    assert out["candidate"] == "BENCH_r03.json"

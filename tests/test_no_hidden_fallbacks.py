@@ -1,6 +1,6 @@
 """The device is never hidden: a missing backend, an unclassifiable
-placement, an unknown chip or a CPU under a bench leg is an error, not
-a quiet second choice. All CPU-only and cheap."""
+placement or a CPU under the chip smoke is an error, not a quiet
+second choice. All CPU-only and cheap."""
 import os
 import subprocess
 import sys
@@ -76,40 +76,6 @@ def test_pallas_dispatch_is_explicit_on_a_tpu_backend(monkeypatch):
     monkeypatch.delenv("MXNET_TPU_DISABLE_PALLAS")
     monkeypatch.setenv("MXNET_TPU_PALLAS_INTERPRET", "1")
     assert _util.pallas_ok_for(x) is True
-
-
-def test_bench_peaks_are_keyed_by_kind_and_unknown_kinds_raise(monkeypatch):
-    sys.path.insert(0, ROOT)
-    import bench
-
-    monkeypatch.delenv("MXNET_TPU_PEAK_TFLOPS", raising=False)
-    monkeypatch.delenv("MXNET_TPU_PEAK_HBM_GBPS", raising=False)
-    assert bench._peak_tflops("TPU v5 lite") == 197.0
-    assert bench._peak_hbm_gbps("TPU v5e") == 819.0
-    with pytest.raises(KeyError, match="Mystery 9000"):
-        bench._peak_tflops("Mystery 9000")
-    with pytest.raises(KeyError):
-        bench._peak_hbm_gbps("cpu")
-    with pytest.raises(SystemExit, match="TPU only"):   # this host: cpu
-        bench._device()
-
-
-# Each of these legs used to have a CPU smoke (a slow test running the
-# leg end to end under JAX_PLATFORMS=cpu). A leg now refuses to run
-# off-TPU — before it builds anything — so that is what is pinned.
-@pytest.mark.parametrize("leg", [
-    {"BENCH_MODEL": "bert", "BENCH_PACKED": "1"},
-    {"BENCH_MODEL": "causal_lm"},
-    {"BENCH_MODEL": "serving"},
-    {"BENCH_MODEL": "serving_router"},
-], ids=lambda e: e["BENCH_MODEL"])
-def test_bench_leg_refuses_to_run_off_tpu(leg):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", **leg)
-    r = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")],
-                       env=env, capture_output=True, text=True, timeout=120)
-    assert r.returncode != 0
-    assert "TPU only" in r.stderr and "cpu" in r.stderr
-    assert '"metric"' not in r.stdout
 
 
 def test_chip_smoke_refuses_to_start_off_tpu():

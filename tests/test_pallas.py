@@ -20,6 +20,7 @@ from mxnet_tpu.ops.pallas.flash_attention import (flash_attention,
                                                   flash_attention_with_lse)
 from mxnet_tpu.ops.pallas.layer_norm import layer_norm_fused
 from mxnet_tpu.ops.pallas.softmax_xent import softmax_xent_fused
+from test_chip_compile import _FLASH, flash_caps, flash_mod
 
 
 def _ln_ref(x, g, b, eps=1e-5):
@@ -218,8 +219,7 @@ def test_flash_attention_large_asymmetric_blocks(monkeypatch):
     with bq != bk and causal block-skip — golden vs jnp. (The defaults
     clamp to one 384x384 block at this length, which would not cover
     the multi-block path the 512-cap defaults enable on-chip.)"""
-    monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_Q", "256")
-    monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_K", "128")
+    flash_caps(monkeypatch, 256, 128)
     rng = np.random.RandomState(6)
     B, H, S, D = 1, 2, 384, 64
     q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
@@ -241,17 +241,14 @@ def test_flash_attention_large_asymmetric_blocks(monkeypatch):
 
 
 def test_flash_attention_fused_vs_split_bwd(monkeypatch):
-    """The single-pass backward (default) and the two-kernel path
-    (MXNET_TPU_FLASH_SPLIT_BWD=1) must produce identical gradients on a
-    genuine multi-block grid — nq=2, nk=2 at 128x128 tiles (nk=2 is the
-    LARGEST grid the fused path accepts before the nk>2 dq-partial
-    fallback reroutes to split; S=200 with ragged padding exercises the
-    fused kernel's multi-k dq partial sum and the causal invisible-pair
-    zeroing branch), causal and not."""
-    monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_Q", "128")
-    monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_K", "128")
+    """The single-pass backward and the dq/dkv pair must produce
+    identical gradients for the same q, k, v: S=300 (ragged padding)
+    under kv caps that give nk=1 and nk=2 (both the fused kernel; nk=2
+    is the LARGEST grid it takes, and exercises its multi-k dq partial
+    sum and the causal invisible-pair zeroing branch) and nk=3 (the
+    pair), causal and not."""
     rng = np.random.RandomState(7)
-    B, H, S, D = 1, 2, 200, 64
+    B, H, S, D = 1, 2, 300, 64
     q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
     k = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
     v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
@@ -261,20 +258,52 @@ def test_flash_attention_fused_vs_split_bwd(monkeypatch):
         def f(q, k, v):
             return (flash_attention(q, k, v, None, causal, 0, True) * w).sum()
 
-        monkeypatch.setenv("MXNET_TPU_FLASH_SPLIT_BWD", "0")
-        g_fused = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        monkeypatch.setenv("MXNET_TPU_FLASH_SPLIT_BWD", "1")
-        g_split = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        monkeypatch.delenv("MXNET_TPU_FLASH_SPLIT_BWD")
+        grads = {}
+        for nk, cap in ((1, 384), (2, 256), (3, 128)):
+            flash_caps(monkeypatch, 128, cap)
+            assert flash_mod._pad_len(S, cap) // cap == nk
+            grads[nk] = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda q, k, v: (_attn_ref(q, k, v, causal) * w).sum(),
                       argnums=(0, 1, 2))(q, k, v)
-        for a, c, r in zip(g_fused, g_split, gr):
+        for one, two, split, r in zip(grads[1], grads[2], grads[3], gr):
             # fused vs split: same math, same f32 accumulation order up
             # to the cross-k partial sum — tight tolerance
-            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+            np.testing.assert_allclose(np.asarray(one), np.asarray(split),
                                        rtol=1e-6, atol=1e-6)
-            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+            np.testing.assert_allclose(np.asarray(two), np.asarray(split),
+                                       rtol=1e-6, atol=1e-6)
+            np.testing.assert_allclose(np.asarray(two), np.asarray(r),
                                        rtol=RTOL, atol=ATOL)
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH))
+def test_flash_backward_kernel_follows_the_kv_grid(case, monkeypatch):
+    """Which backward runs is chosen from the count of kv blocks alone,
+    at the shapes the chip compiles (and the two cells run): the
+    one-pass kernel up to two blocks, the dq/dkv pair beyond. Traced on
+    shapes; nothing executes."""
+    shape, causal, use_lens, use_segs, tiles = _FLASH[case]
+    if tiles:
+        flash_caps(monkeypatch, *tiles)
+    b, _, s, _ = shape
+    nk = -(-s // flash_mod._pick_blocks(s, s)[1])
+    if case in ("b64_s512", "mla_s8192_d256_causal"):   # the cells' shapes
+        assert nk == {"b64_s512": 1, "mla_s8192_d256_causal": 4}[case]
+
+    def step(q, k, v, lens, segs):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, None, causal, 0, False,
+            lens if use_lens else None, segs if use_segs else None)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = str(jax.make_jaxpr(step)(
+        qkv, qkv, qkv, jax.ShapeDtypeStruct((b,), jnp.int32),
+        jax.ShapeDtypeStruct((b, s), jnp.int32)))
+    fused = "mxtpu_flash_bwd_fused" in text
+    pair = "mxtpu_flash_bwd_dq" in text and "mxtpu_flash_bwd_dkv" in text
+    assert "mxtpu_flash_fwd" in text
+    assert (fused, pair) == ((True, False) if nk <= 2 else (False, True))
 
 
 @pytest.mark.skipif(not _on_tpu(), reason="memory analysis needs the real chip")
@@ -299,93 +328,58 @@ def test_flash_attention_o_of_s_memory():
         m_ref.temp_size_in_bytes, m_flash.temp_size_in_bytes)
 
 
-def test_lstm_layer_fused_vs_scan(monkeypatch):
-    """Whole-sequence fused LSTM kernel (interpret mode) vs the
-    lax.scan cell: outputs, final states, and every gradient (gin,
-    W_h2h, h0, c0 — including cotangents on the final states) agree."""
-    monkeypatch.setenv("MXNET_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("MXNET_TPU_FUSED_LSTM", "1")
-    from mxnet_tpu.ops.pallas.lstm import lstm_layer_fused
-
-    rng = np.random.RandomState(21)
-    T, N, H = 7, 8, 24
-    gin = jnp.asarray(rng.randn(T, N, 4 * H).astype(np.float32)) * 0.4
-    w = jnp.asarray(rng.randn(H, 4 * H).astype(np.float32)) * 0.3
-    h0 = jnp.asarray(rng.randn(N, H).astype(np.float32)) * 0.5
-    c0 = jnp.asarray(rng.randn(N, H).astype(np.float32)) * 0.5
-
-    def scan_ref(gin, w, h0, c0):
-        def step(carry, gx):
-            h, c = carry
-            z = gx + h @ w
-            i, f, g, o = jnp.split(z, 4, axis=-1)
-            i, f, o = jax.nn.sigmoid(i), jax.nn.sigmoid(f), jax.nn.sigmoid(o)
-            g = jnp.tanh(g)
-            c_new = f * c + i * g
-            h_new = o * jnp.tanh(c_new)
-            return (h_new, c_new), (h_new, c_new)
-        (hl, cl), (out, cseq) = jax.lax.scan(step, (h0, c0), gin)
-        return out, cseq
-
-    out, cseq = lstm_layer_fused(gin, w, h0, c0)
-    ro, rc = scan_ref(gin, w, h0, c0)
-    # RTOL/ATOL are device-aware (real-chip f32 dots round differently
-    # between the interpreted kernel and the scan reference)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ro),
-                               rtol=RTOL, atol=ATOL)
-    np.testing.assert_allclose(np.asarray(cseq), np.asarray(rc),
-                               rtol=RTOL, atol=ATOL)
-
-    # weighted loss touching the full sequence AND both final states so
-    # every cotangent path (dout, dcseq, incl. [-1] entries) is live
-    wo = jnp.asarray(rng.randn(T, N, H).astype(np.float32))
-    wc = jnp.asarray(rng.randn(N, H).astype(np.float32))
-
-    def loss_fused(gin, w, h0, c0):
-        out, cseq = lstm_layer_fused(gin, w, h0, c0)
-        return (out * wo).sum() + (cseq[-1] * wc).sum() + out[-1].sum()
-
-    def loss_ref(gin, w, h0, c0):
-        out, cseq = scan_ref(gin, w, h0, c0)
-        return (out * wo).sum() + (cseq[-1] * wc).sum() + out[-1].sum()
-
-    gf = jax.grad(loss_fused, argnums=(0, 1, 2, 3))(gin, w, h0, c0)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(gin, w, h0, c0)
-    for a, c in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_lstm_fused_bidirectional_matches_scan(monkeypatch):
-    """Bidirectional gluon LSTM: the fused kernel path must agree with
-    the lax.scan path on outputs AND final states (the reverse
-    direction's h_last is the last PROCESSED step, not out[-1] after
-    the flip back to forward-time order)."""
+def test_lstm_bidirectional_final_states_match_a_step_loop():
+    """Bidirectional two-layer gluon LSTM against a plain per-step
+    NumPy loop, on outputs AND final states: each direction's final
+    state is that of its last PROCESSED step (for the reverse direction
+    the step at t=0, taken before its outputs are flipped back to
+    forward-time order), not out[-1]."""
     import mxnet_tpu as mx
     from mxnet_tpu import nd
 
     rng = np.random.RandomState(31)
-    x = rng.randn(5, 4, 12).astype(np.float32)  # (T, N, I), TNC
+    T, N, I, H, L = 5, 4, 12, 8, 2
+    x = rng.randn(T, N, I).astype(np.float32)
+    h0 = (rng.randn(2 * L, N, H) * 0.5).astype(np.float32)
+    c0 = (rng.randn(2 * L, N, H) * 0.5).astype(np.float32)
+    net = mx.gluon.rnn.LSTM(H, num_layers=L, bidirectional=True)
+    net.initialize()
+    net(nd.array(x), net.begin_state(batch_size=N))     # shapes the params
+    weights = {}
+    for name, param in net.collect_params().items():
+        value = (rng.randn(*param.shape) * 0.4).astype(np.float32)
+        param.set_data(nd.array(value))
+        weights[name.split("_", 1)[1]] = value
+    out, (h, c) = net(nd.array(x), [nd.array(h0), nd.array(c0)])
 
-    def run(fused):
-        if fused:
-            monkeypatch.setenv("MXNET_TPU_PALLAS_INTERPRET", "1")
-            monkeypatch.setenv("MXNET_TPU_FUSED_LSTM", "1")
-        else:
-            monkeypatch.delenv("MXNET_TPU_PALLAS_INTERPRET", raising=False)
-            monkeypatch.delenv("MXNET_TPU_FUSED_LSTM", raising=False)
-        mx.random.seed(7)
-        net = mx.gluon.rnn.LSTM(8, num_layers=2, bidirectional=True)
-        net.initialize()
-        out, (h, c) = net(nd.array(x),
-                          net.begin_state(batch_size=4))
-        return out.asnumpy(), h.asnumpy(), c.asnumpy()
+    def sigmoid(z):
+        return 1.0 / (1.0 + np.exp(-z))
 
-    o_s, h_s, c_s = run(False)
-    o_f, h_f, c_f = run(True)
-    np.testing.assert_allclose(o_f, o_s, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(h_f, h_s, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(c_f, c_s, rtol=1e-5, atol=1e-5)
+    def one_direction(seq, tag, h, c):
+        outs = []
+        for x_t in seq:
+            z = x_t @ weights[tag + "_i2h_weight"].T \
+                + weights[tag + "_i2h_bias"] \
+                + h @ weights[tag + "_h2h_weight"].T \
+                + weights[tag + "_h2h_bias"]
+            i, f, g, o = np.split(z, 4, axis=-1)
+            c = sigmoid(f) * c + sigmoid(i) * np.tanh(g)
+            h = sigmoid(o) * np.tanh(c)
+            outs.append(h)
+        return np.stack(outs), h, c
+
+    seq, hs, cs = x, [], []
+    for layer in range(L):
+        fwd, h_f, c_f = one_direction(seq, "l%d" % layer,
+                                      h0[2 * layer], c0[2 * layer])
+        bwd, h_b, c_b = one_direction(seq[::-1], "r%d" % layer,
+                                      h0[2 * layer + 1], c0[2 * layer + 1])
+        seq = np.concatenate([fwd, bwd[::-1]], axis=-1)
+        hs += [h_f, h_b]
+        cs += [c_f, c_b]
+    np.testing.assert_allclose(out.asnumpy(), seq, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(h.asnumpy(), np.stack(hs), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(c.asnumpy(), np.stack(cs), rtol=1e-5, atol=1e-5)
 
 
 def _attn_len_ref(q, k, v, kv_lens, causal=False):
@@ -413,14 +407,16 @@ def test_flash_attention_variable_length(causal, split_bwd, monkeypatch):
     paths, with lengths crossing tile boundaries and the loss masking
     padded positions (the contract under which padded-row grads vanish
     identically)."""
-    if split_bwd:
-        monkeypatch.setenv("MXNET_TPU_FLASH_SPLIT_BWD", "1")
     rng = np.random.RandomState(7)
     B, H, S, D = 3, 2, 40, 16
+    if split_bwd:       # three kv blocks: the dq/dkv pair
+        flash_caps(monkeypatch, 128, 128)
+        S = 300
     q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
     k = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
     v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
-    kv_lens = jnp.asarray([40, 17, 0], jnp.int32)  # incl. an EMPTY example
+    # incl. an EMPTY example
+    kv_lens = jnp.asarray([S, S // 2 - 3, 0], jnp.int32)
 
     o = flash_attention(q, k, v, None, causal, 0, True, kv_lens)
     ref = _attn_len_ref(q, k, v, kv_lens, causal)
@@ -489,10 +485,11 @@ def test_flash_attention_segment_isolation(causal, split_bwd, monkeypatch):
     block-diagonal — forward and all three gradients match the composed
     masked softmax on BOTH backward paths, including causal mode and
     padding slots (id 0) that must emit exact zeros."""
-    if split_bwd:
-        monkeypatch.setenv("MXNET_TPU_FLASH_SPLIT_BWD", "1")
     rng = np.random.RandomState(11)
     B, H, S, D = 2, 2, 40, 16
+    if split_bwd:       # three kv blocks: the dq/dkv pair
+        flash_caps(monkeypatch, 128, 128)
+        S = 300
     q, k, v, seg, lens = _packed_case(rng, B, H, S, D)
 
     o = flash_attention(q, k, v, None, causal, 0, True, lens, seg)
@@ -525,8 +522,7 @@ def test_flash_attention_segment_multiblock(monkeypatch):
     SMEM segment-range whole-block skip and the lane-broadcast equality
     mask must agree with the composed reference across tile boundaries;
     128<block_k exercises the pltpu.repeat id layout."""
-    monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_Q", "64")
-    monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_K", "128")
+    flash_caps(monkeypatch, 64, 128)
     rng = np.random.RandomState(12)
     B, H, S, D = 2, 2, 512, 32
     q, k, v, seg, lens = _packed_case(rng, B, H, S, D)
@@ -546,7 +542,7 @@ def test_flash_attention_segment_multiblock(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    rtol=RTOL, atol=ATOL)
     # the repeat branch (block_k > 128) on the same case
-    monkeypatch.setenv("MXNET_TPU_FLASH_BLOCK_K", "256")
+    flash_caps(monkeypatch, 64, 256)
     o = flash_attention(q, k, v, None, False, 0, True, lens, seg)
     np.testing.assert_allclose(
         np.asarray(o), np.asarray(_attn_seg_ref(q, k, v, seg, lens, False)),

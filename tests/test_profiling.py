@@ -5,8 +5,8 @@ under a LIVE loaded serving engine, the per-bucket cost ledger's
 exactness contract (sum of per-request amortized device time == batch
 forward wall), the /profile and /costs scrape surface, resource
 gauges/watermarks, flight-bundle profile.txt, the disabled-path
-(MXNET_TPU_PROF=0) microbench guard, the loadgen cost cross-check, and
-the xprof trace-id filter helper. Marker-clean tier-1.
+(MXNET_TPU_PROF=0) microbench guard and the loadgen cost cross-check.
+Marker-clean tier-1.
 """
 import json
 import os
@@ -343,26 +343,3 @@ def test_telemetry_dump_profile_and_costs(capsys):
     assert "self%" in out
     assert "costs, engine dump-cost" in out
     assert "bucket" in out and "device s" in out
-
-
-# ---------------------------------------------------------------------------
-# xprof trace-id filter helper (off-device unit)
-# ---------------------------------------------------------------------------
-
-def test_xprof_trace_id_filter_degrades_gracefully():
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    from xprof_roofline import filter_rows_by_trace
-
-    rows = [{"hlo_op_name": "fusion.1",
-             "tf_op_name": "jit(step)/serving/forward#req3f-1c-0"},
-            {"hlo_op_name": "fusion.2", "tf_op_name": "jit(step)/other"},
-            {"hlo_op_name": "copy.3", "tf_op_name": None}]
-    hit, matched = filter_rows_by_trace(rows, "req3f-1c-0")
-    assert matched and [r["hlo_op_name"] for r in hit] == ["fusion.1"]
-    # no match (off-device / annotation not propagated): full table
-    # back with an honest flag, never an empty report
-    out, matched = filter_rows_by_trace(rows, "req-unknown")
-    assert not matched and out == rows
-    # no filter requested: identity
-    out, matched = filter_rows_by_trace(rows, None)
-    assert matched and out is rows

@@ -70,7 +70,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(prog="mxlint", description=__doc__)
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to lint (default: the acceptance "
-                         "scope: mxnet_tpu/ tools/ bench.py)")
+                         "scope: mxnet_tpu/ tools/)")
     ap.add_argument("--root", default=None,
                     help="repo root (default: auto-detected)")
     ap.add_argument("--write-baseline", action="store_true",

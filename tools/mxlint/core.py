@@ -26,8 +26,8 @@ __all__ = ["Finding", "FileContext", "LintPass", "Project",
            "cached_context", "warm_cache", "changed_files",
            "DEFAULT_PATHS", "repo_root"]
 
-#: the acceptance scope: the package, the tools, and the bench driver
-DEFAULT_PATHS = ("mxnet_tpu", "tools", "bench.py")
+#: the acceptance scope: the package and the tools
+DEFAULT_PATHS = ("mxnet_tpu", "tools")
 
 #: directories never scanned (fixtures hold INTENTIONAL violations)
 _SKIP_PARTS = ("__pycache__", "fixtures", ".jax_cache", "dashboards")

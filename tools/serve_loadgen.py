@@ -1,7 +1,9 @@
 """Closed-loop synthetic traffic generator for mxnet_tpu.serving.
 
-Shared by the bench serving leg (bench.py BENCH_MODEL=serving imports
-``run_load``) and usable by hand against any engine::
+``run_load`` and ``run_chaos_drill`` are the harnesses the serving
+tests drive (``tests/test_compile_cache.py``, ``tests/test_chaos.py``,
+``tests/test_tenancy.py``), and the tool is usable by hand against any
+engine::
 
     python tools/serve_loadgen.py --clients 8 --requests 16
 
@@ -1989,6 +1991,17 @@ def chaos_drill(r_keep, r_kill, urls, ctl, autoscaler, hotspot,
             "incident": phase_incident("seat_kill")}
 
         # ---- phase C: router kill -> in-flight handoff -------------------
+        # the death must strand real in-flights: a connection refused
+        # earlier (a loaded host) is sticky, so point the clients back
+        # at the router about to die and see its traffic move first
+        def kill_dispatched():
+            return sum(r.get("dispatched", 0)
+                       for r in r_kill.scoreboard().values())
+        with client._lock:
+            client._preferred = 0
+        d0 = kill_dispatched()
+        _wait_for(lambda: kill_dispatched() >= d0 + n_clients,
+                  phase_timeout_s, f"traffic through {r_kill.router_id}")
         adopt0 = adopt_count()
         ctl.apply({"fault": "kill_router", "target": r_kill.router_id})
         _wait_for(lambda: adopt_count() > adopt0, phase_timeout_s,
@@ -2050,8 +2063,8 @@ def run_chaos_drill(make_engine, n_engines=3, n_clients=6,
     by two peered routers (both exposed over HTTP), a
     :class:`~mxnet_tpu.serving.FleetAutoscaler` spanning both (peers
     share seat state through it), and a chaos controller with
-    everything registered. Used by ``--drill-chaos``, the
-    ``bert_serving_chaos`` bench leg and the tier-1 drill test."""
+    everything registered. Used by ``--drill-chaos`` and the tier-1
+    drill test."""
     import contextlib
 
     from mxnet_tpu.serving import FleetAutoscaler, ServingRouter
