@@ -38,6 +38,8 @@ from ..ndarray.register import Op, invoke
 from .. import autograd as _autograd
 from .. import profiler as _profiler
 from .. import random as _random
+# the names of what a remat'd block keeps by default (`_remat_policy`)
+from ..ops.pallas.flash_attention import REMAT_KEEP as _REMAT_KEEP
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock"]
@@ -315,6 +317,44 @@ class _trace_guard:
 _REMAT_GUARD = threading.local()
 
 
+def _remat_policy(flags):
+    """(``jax.checkpoint`` policy, the names it keeps) of a block's flags,
+    for both remat sites (`_remat_trace`, `_build_cached_op`):
+    ``remat_policy`` where the user gave one (a ``jax.checkpoint_policies``
+    name, "names:a,b" or a callable), else `_REMAT_KEEP`, the values a
+    kernel named as dear to rebuild and small to keep (a flash attention
+    call's output and log-sum-exp: rebuilding them is a second forward call
+    of the kernel in the backward). A block in which nothing carries one of
+    those names keeps nothing but its inputs."""
+    policy = flags.get("remat_policy")
+    names = ()
+    if policy is None:
+        names = _REMAT_KEEP
+    elif isinstance(policy, str):
+        if policy.startswith("names:"):
+            # "names:conv_out[,other]" — keep only values tagged with
+            # jax.ad_checkpoint.checkpoint_name (Convolution tags its
+            # output 'conv_out'): backward recomputes just the cheap
+            # elementwise chain between kept anchors
+            names = tuple(policy[len("names:"):].split(","))
+        else:
+            policy = getattr(jax.checkpoint_policies, policy)
+    if names:
+        policy = jax.checkpoint_policies.save_only_these_names(*names)
+    return policy, names
+
+
+def _tally_kept(heard, names, times=1):
+    """Add what a remat site keeps (``heard``: `profiler.named_values` of
+    its trace; ``times``: groups of rows) to the open CachedOp build's
+    tally, if one is open."""
+    kept = getattr(_REMAT_GUARD, "kept", None)
+    if kept is not None:
+        sizes = [size for name, size in heard if name in names]
+        kept[0] += times * len(sizes)
+        kept[1] += times * sum(sizes)
+
+
 # ----------------------------------------------------------------------
 # deferred aux updates (BatchNorm running stats inside a trace)
 # ----------------------------------------------------------------------
@@ -386,12 +426,21 @@ class HybridBlock(Block):
         :meth:`_remat_trace`). ``remat``/``remat_policy`` default to
         None = KEEP the block's existing setting, so a later parent
         ``net.hybridize()`` does not erase per-child marks; pass
-        ``remat=False`` to clear explicitly. ``remat_policy`` selects
-        what the forward saves (a ``jax.checkpoint_policies`` name, or
-        "names:conv_out" to save conv outputs and recompute only the
-        elementwise chain). ``remat_rows=N`` (with ``remat`` on a marked
-        child) takes the batch N rows at a time through the checkpointed
-        block, one group after the other (a ``lax.map``), so the backward
+        ``remat=False`` to clear explicitly. What the forward keeps,
+        beside the block's inputs: by default only the values a kernel
+        named dear to rebuild and small to keep — a flash attention
+        call's output and log-sum-exp, so the kernel's forward runs once
+        a step and not again in the backward (an attention layer keeps
+        one more activation the size of its input; a block with no such
+        call keeps nothing; `profiler.counters()` ``remat_kept`` /
+        ``remat_kept_bytes`` say what was kept). ``remat_policy``
+        overrides that (a ``jax.checkpoint_policies`` name:
+        "nothing_saveable" keeps nothing at all; or "names:conv_out" to
+        keep conv outputs and recompute only the elementwise chain), for
+        whole-net and per-block remat alike. ``remat_rows=N`` (with
+        ``remat`` on a marked child) takes the batch N rows at a time
+        through the checkpointed block, one group after the other (a
+        ``lax.map``), so the backward
         rebuilds N rows' activations at once, not the batch's; the
         block's inputs and outputs must all lead with the batch axis, and
         a tally kept with ``defer_aux_update(..., increment=True)`` adds
@@ -473,9 +522,11 @@ class HybridBlock(Block):
         checkpoint outputs and re-enter the outer trace's aux sink; a
         subkey of the active trace key is passed in explicitly so the
         backward recompute replays identical randomness (dropout masks
-        match between forward and rebuild). ``remat_policy`` (a
-        ``jax.checkpoint_policies`` name or callable) selects what the
-        forward may save; default saves nothing but the inputs."""
+        match between forward and rebuild). What the forward keeps is
+        `_remat_policy`'s: the user's ``remat_policy``, else the inputs
+        and whatever a kernel inside named with one of `_REMAT_KEEP`
+        (tallied, where a CachedOp build is listening, for
+        `profiler.counters()` ``remat_kept``)."""
         ctx = x.ctx
         try:
             params = list(self.collect_params().values())
@@ -503,7 +554,8 @@ class HybridBlock(Block):
             try:
                 for p, d in zip(params, p_datas):
                     p._data = {c: _wrap(d, c) for c in p._data}
-                out = block._forward_eager(*call_args)
+                with _profiler.named_values() as box["named"]:
+                    out = block._forward_eager(*call_args)
             finally:
                 _REMAT_GUARD.active = prev_remat
                 for p, d in saved:
@@ -516,21 +568,12 @@ class HybridBlock(Block):
             aux = tuple(_raw(n) for _, n, _ in sink)
             return tuple(f._data for f in flat), aux
 
-        policy = self._flags.get("remat_policy")
-        if isinstance(policy, str):
-            if policy.startswith("names:"):
-                # "names:conv_out[,other]" — save only values tagged with
-                # jax.ad_checkpoint.checkpoint_name (Convolution tags its
-                # output 'conv_out'): backward recomputes just the cheap
-                # elementwise chain between saved anchors
-                policy = jax.checkpoint_policies.save_only_these_names(
-                    *policy[len("names:"):].split(","))
-            else:
-                policy = getattr(jax.checkpoint_policies, policy)
+        policy, keep = _remat_policy(self._flags)
         ckpt = jax.checkpoint(pure, policy=policy)
         key = _random._next_key()
         rows = self._flags.get("remat_rows")
         batch = in_datas[0].shape[0] if in_datas and in_datas[0].ndim else 0
+        n = 1
         if rows and batch > rows and batch % rows == 0 \
                 and all(d.ndim and d.shape[0] == batch for d in in_datas):
             # ``rows`` rows at a time, one after the other (a lax.map: the
@@ -545,6 +588,7 @@ class HybridBlock(Block):
                               for a, (_, inc) in zip(auxs, box["aux_params"]))
         else:
             out_datas, aux_datas = ckpt(key, in_datas, p_datas)
+        _tally_kept(box["named"], keep, times=n)
         for (p, inc), new in zip(box["aux_params"], aux_datas):
             defer_aux_update(p, _wrap(new, ctx), increment=inc)
         flat = [_wrap(d, ctx) for d in out_datas]
@@ -583,9 +627,21 @@ class HybridBlock(Block):
             if entry is None:
                 # the trace; the compile is this call's `invoke` below
                 _profiler.count("cachedop_builds")
-                with _profiler.span("mxtpu/cachedop/build", block=self.name):
-                    entry = self._build_cached_op(args, inputs, params, ctx,
-                                                  training)
+                with _profiler.span("mxtpu/cachedop/build",
+                                    block=self.name) as build:
+                    # what this trace's remat sites keep for a backward
+                    # (`_tally_kept`): nothing where nobody records one
+                    kept = [0, 0]
+                    _REMAT_GUARD.kept = \
+                        kept if _autograd.is_recording() else None
+                    try:
+                        entry = self._build_cached_op(args, inputs, params,
+                                                      ctx, training)
+                    finally:
+                        _REMAT_GUARD.kept = None
+                    build.set(remat_kept=kept[0], remat_kept_bytes=kept[1])
+                _profiler.count("remat_kept", kept[0])
+                _profiler.count("remat_kept_bytes", kept[1])
                 self._cached_graph[key] = entry
             op, structure, aux_params, n_flat_out = entry
 
@@ -612,6 +668,8 @@ class HybridBlock(Block):
         arg_template = list(args)
 
         aux_params_order: list = []
+        remat = self._flags.get("remat")
+        policy, keep = _remat_policy(self._flags)
 
         def traced(rng_key, *arrays):
             in_arrays = arrays[:n_in]
@@ -628,13 +686,13 @@ class HybridBlock(Block):
             prev_train = _autograd.set_training(training)
             prev_rec = _autograd.set_recording(False)
             prev_remat = getattr(_REMAT_GUARD, "active", False)
-            if block._flags.get("remat"):
+            if remat:
                 # whole-block remat is applied at the jit level below —
                 # keep forward() from re-wrapping this same block (and
                 # any descendant) in a nested trace-time checkpoint
                 _REMAT_GUARD.active = True
             try:
-                with _trace_guard():
+                with _trace_guard(), _profiler.named_values() as named:
                     for p, arr in zip(params, p_arrays):
                         wrappers = {c: _wrap(arr, c) for c in p._data}
                         p._data = wrappers
@@ -654,10 +712,12 @@ class HybridBlock(Block):
                 aux_params_order.append(p)
                 aux_arrays.append(new)
             traced._structure = structure
+            if remat:
+                _tally_kept(named, keep)
             return tuple(x._data if isinstance(x, NDArray) else x
                          for x in flat) + tuple(aux_arrays)
 
-        fn = jax.checkpoint(traced) if self._flags.get("remat") else traced
+        fn = jax.checkpoint(traced, policy=policy) if remat else traced
         jitted = jax.jit(fn)
         # learn the output structure abstractly — no device execution
         # (jax.eval_shape runs the python once with avals; the real

@@ -68,9 +68,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import profiler as _profiler
 from ._util import resolve_interpret, x32
 
 _NEG_INF = -1e30
@@ -825,6 +827,44 @@ def _int_ct(x):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
+# The two values of a forward call that the backward reads and cannot
+# derive from q, k, v short of running the forward again: the output
+# (one activation, the size of q) and the rows' log-sum-exp (1/head_dim
+# of that, float32). They carry these `checkpoint_name`s, and a
+# rematerialised block keeps values so named (gluon/block.py reads this
+# tuple into its default checkpoint policy) instead of rebuilding them
+# with a second forward call in its backward. Outside a checkpoint a
+# name is the identity and lowers to nothing.
+REMAT_KEEP = ("flash_out", "flash_lse")
+
+
+def _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret, kv_lens,
+               segment_ids):
+    """The forward that the primals and the VJPs' forward rules share:
+    (out, lse) under their `REMAT_KEEP` names."""
+    if sm_scale is None:
+        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    o, lse = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
+                        resolve_interpret(interpret), kv_lens=kv_lens,
+                        segment_ids=segment_ids)
+    named = []
+    for name, x in zip(REMAT_KEEP, (o, lse)):
+        _profiler.note_named(name, x)   # trace time: `remat_kept`'s tally
+        named.append(checkpoint_name(x, name))
+    return tuple(named)
+
+
+def _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, res, do, dlse=None):
+    q, k, v, o, lse, kv_lens, segment_ids = res
+    if sm_scale is None:
+        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, sm_scale, bool(causal),
+                            int(q_offset), resolve_interpret(interpret),
+                            dlse=dlse, kv_lens=kv_lens,
+                            segment_ids=segment_ids)
+    return dq, dk, dv, _int_ct(kv_lens), _int_ct(segment_ids)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
                              q_offset=0, interpret=None, kv_lens=None,
@@ -839,33 +879,20 @@ def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
     ``segment_ids`` (B, S) int32 restricts attention to same-segment
     pairs (sequence packing; see the module docstring).
     """
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    return _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
-                      resolve_interpret(interpret), kv_lens=kv_lens,
-                      segment_ids=segment_ids)
+    return _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
+                      kv_lens, segment_ids)
 
 
 def _flash_lse_vjp_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                        kv_lens=None, segment_ids=None):
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    o, lse = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
-                        resolve_interpret(interpret), kv_lens=kv_lens,
-                        segment_ids=segment_ids)
+    o, lse = _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
+                        kv_lens, segment_ids)
+    # the primal output IS the named value: one kept array serves both
     return (o, lse), (q, k, v, o, lse, kv_lens, segment_ids)
 
 
 def _flash_lse_vjp_bwd(sm_scale, causal, q_offset, interpret, res, cts):
-    q, k, v, o, lse, kv_lens, segment_ids = res
-    do, dlse = cts
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, sm_scale, bool(causal),
-                            int(q_offset), resolve_interpret(interpret),
-                            dlse=dlse, kv_lens=kv_lens,
-                            segment_ids=segment_ids)
-    return dq, dk, dv, _int_ct(kv_lens), _int_ct(segment_ids)
+    return _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, res, *cts)
 
 
 flash_attention_with_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
@@ -881,32 +908,15 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, q_offset=0,
     block-diagonal over packed sequences (see module docstring). q, k
     and v share one head width; ``mx.nd.flash_attention`` pads a wider
     q.k (192 against v's 128) up to it and passes the true ``sm_scale``."""
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    o, _ = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
-                      resolve_interpret(interpret), kv_lens=kv_lens,
-                      segment_ids=segment_ids)
-    return o
+    return _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
+                      kv_lens, segment_ids)[0]
 
 
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                    kv_lens=None, segment_ids=None):
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    o, lse = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
-                        resolve_interpret(interpret), kv_lens=kv_lens,
-                        segment_ids=segment_ids)
+    o, lse = _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
+                        kv_lens, segment_ids)
     return o, (q, k, v, o, lse, kv_lens, segment_ids)
-
-
-def _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, res, do):
-    q, k, v, o, lse, kv_lens, segment_ids = res
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, sm_scale, bool(causal),
-                            int(q_offset), resolve_interpret(interpret),
-                            kv_lens=kv_lens, segment_ids=segment_ids)
-    return dq, dk, dv, _int_ct(kv_lens), _int_ct(segment_ids)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

@@ -48,12 +48,14 @@ _EVENTS: list = []
 _AGGREGATE: dict = {}
 _LOCK = threading.Lock()
 # Always-on dispatch counts (`count` / `counters()`): `register.invoke`
-# bumps "invokes", `HybridBlock._call_cached_op` "cachedop_builds",
-# `Optimizer.update_multi` "fused" and "looped" (and "invokes", once, for
-# its compiled program). Not locked: a span reads the difference on its
-# own thread, which is exact while no other thread dispatches (a training
-# loop).
-_COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0}
+# bumps "invokes", `HybridBlock._call_cached_op` "cachedop_builds" (and,
+# from what that trace's rematerialised blocks keep, "remat_kept" and
+# "remat_kept_bytes"), `Optimizer.update_multi` "fused" and "looped" (and
+# "invokes", once, for its compiled program). Not locked: a span reads
+# the difference on its own thread, which is exact while no other thread
+# dispatches (a training loop).
+_COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
+           "remat_kept": 0, "remat_kept_bytes": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -138,7 +140,14 @@ def counters(device=True):
     ``fused`` / ``looped`` (parameters `Optimizer.update_multi` put
     through its one compiled program / through the per-key loop: a
     ``looped`` that grows by the model's size each step names an
-    optimizer that dispatches eagerly, parameter by parameter).
+    optimizer that dispatches eagerly, parameter by parameter), and
+    ``remat_kept`` / ``remat_kept_bytes`` (values, and their bytes, that
+    blocks marked ``hybridize(remat=True)`` keep from their forward for
+    their backward because a kernel named them dear to rebuild — a flash
+    attention call's output and log-sum-exp; a group of rows under
+    ``remat_rows`` counts each time. Tallied when a block is traced under
+    ``autograd.record``, so flat across steps like ``cachedop_builds``;
+    0 with remat'd attention layers means their forward runs twice a step).
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and
@@ -155,6 +164,35 @@ def counters(device=True):
             else:
                 out[key] = out.get(key, 0.0) + value
     return out
+
+
+# What a kernel named with `jax.ad_checkpoint.checkpoint_name` while a
+# rematerialised block was being traced: [(name, bytes)]. Trace time
+# only; nothing listens, and `note_named` returns at once, in a step.
+_NAMED = threading.local()
+
+
+def note_named(name, value):
+    """A kernel's word, while it is traced, that ``value`` carries the
+    checkpoint name ``name`` (`ops/pallas/flash_attention.py`)."""
+    heard = getattr(_NAMED, "heard", None)
+    if heard is not None:
+        heard.append((name, value.size * value.dtype.itemsize))
+
+
+class named_values:
+    """``with named_values() as heard:`` — the (name, bytes) of every
+    value named inside (`HybridBlock`'s remat sites, around the trace of
+    the block they checkpoint)."""
+
+    def __enter__(self):
+        self._outer = getattr(_NAMED, "heard", None)
+        _NAMED.heard = heard = []
+        return heard
+
+    def __exit__(self, *exc):
+        _NAMED.heard = self._outer
+        return False
 
 
 _DEVICE_COUNTERS = weakref.WeakSet()

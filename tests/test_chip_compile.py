@@ -111,6 +111,44 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, monkeypatch, case):
     _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd")
 
 
+@pytest.mark.parametrize("policy, rebuilds", [
+    (None, False), ("nothing_saveable", True)])
+def test_remat_keeps_flash_residuals_in_the_compiled_step(
+        one_chip, policy, rebuilds):
+    """Two checkpointed attention layers at the `bert_base` cell's shape,
+    under the policy a block marked ``remat=True`` gets
+    (`gluon/block.py` `_remat_policy`): the compiled program runs the
+    forward kernel once a layer where the names are kept and again in the
+    backward where nothing is (this one program lets XLA share the last
+    layer's rebuild with its forward; the cell's backward is a program of
+    its own), and the backward kernel once a layer either way."""
+    from mxnet_tpu.gluon.block import _remat_policy
+
+    ckpt_policy, _ = _remat_policy({"remat_policy": policy})
+
+    def layer(w, x):
+        q, k, v = (t.reshape(64, 512, 12, 64).transpose(0, 2, 1, 3)
+                   for t in jnp.split(x @ w, 3, axis=-1))
+        o = flash_attention(q, k, v, None, False, 0, False)
+        return x + o.transpose(0, 2, 1, 3).reshape(x.shape)
+
+    def step(w1, w2, x):
+        def loss(w1, w2):
+            h = x
+            for w in (w1, w2):
+                h = jax.checkpoint(layer, policy=ckpt_policy)(w, h)
+            return _sum(h)
+        return jax.grad(loss, argnums=(0, 1))(w1, w2)
+
+    text = _compiled_text(step, one_chip, ((768, 2304), BF16),
+                          ((768, 2304), BF16), ((64, 512, 768), BF16))
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    forward_calls = sum("mxtpu_flash_fwd" in c for c in calls)
+    assert forward_calls > 2 if rebuilds else forward_calls == 2
+    assert sum("mxtpu_flash_bwd_fused" in c for c in calls) == 2
+
+
 def test_layer_norm_fwd_bwd_compiles(one_chip):
     def step(x, g, b):
         return jax.grad(lambda x, g, b: _sum(

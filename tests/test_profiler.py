@@ -313,6 +313,67 @@ def test_fused_and_looped_count_the_update_path_with_tracing_off(
     assert c1["looped"] - c0["looped"] == 3 * looped
 
 
+def _remat_encoder_step(monkeypatch, layers=2, batch=2, seq=128, units=32,
+                        heads=2):
+    """record -> backward over an encoder whose layers are marked
+    ``hybridize(active=False, remat=True)`` as the benchmark's cells mark
+    them (flash kernel in interpret mode); (step, bytes a layer keeps)."""
+    from mxnet_tpu.gluon.nn.transformer import TransformerEncoder
+
+    monkeypatch.setenv("MXNET_TPU_PALLAS_INTERPRET", "1")
+    enc = TransformerEncoder(layers, units, 2 * units, heads)
+    enc.initialize()
+    enc.remat_per_layer()
+    enc.hybridize()
+    x = nd.ones((batch, seq, units))
+
+    def step(train=True):
+        if not train:
+            return enc(x)
+        with autograd.record():
+            y = enc(x).sum()
+        y.backward()
+        return y
+
+    # the attention output, float32, and a float32 log-sum-exp a head a row
+    return step, batch * seq * units * 4 + batch * heads * seq * 4
+
+
+def test_remat_kept_counts_what_the_blocks_of_a_build_keep(monkeypatch):
+    """The always-on `remat_kept` / `remat_kept_bytes`: two named values a
+    remat'd attention layer (the flash call's output and log-sum-exp), the
+    bytes from their shapes, tallied when the CachedOp is traced under
+    ``record`` and flat from then on; a build nobody differentiates keeps
+    nothing."""
+    assert not profiler.active()
+    step, layer_bytes = _remat_encoder_step(monkeypatch)
+    kept = lambda: (profiler.counters(device=False)["remat_kept"],
+                    profiler.counters(device=False)["remat_kept_bytes"])
+    k0 = kept()
+    step(train=False).wait_to_read()              # a forward-only build
+    assert kept() == k0
+    step().wait_to_read()
+    assert kept() == (k0[0] + 2 * 2, k0[1] + 2 * layer_bytes)
+    builds = profiler.counters()["cachedop_builds"]
+    for _ in range(2):
+        step().wait_to_read()
+    assert kept() == (k0[0] + 4, k0[1] + 2 * layer_bytes)
+    assert profiler.counters()["cachedop_builds"] == builds
+
+
+def test_the_build_span_says_what_remat_keeps(tmp_path, monkeypatch):
+    """`mxtpu/cachedop/build` carries the same two numbers as attributes,
+    0 and 0 for a block that keeps nothing."""
+    step, layer_bytes = _remat_encoder_step(monkeypatch)
+    plain = _small_loop()
+    spans = _trace(tmp_path, lambda: (step().wait_to_read(),
+                                      step().wait_to_read(),
+                                      plain().wait_to_read()))
+    builds = [s[3] for s in spans if s[0] == "mxtpu/cachedop/build"]
+    assert [(b["remat_kept"], b["remat_kept_bytes"]) for b in builds] \
+        == [(4, 2 * layer_bytes), (0, 0)]
+
+
 def test_span_off_paths_stay_cheap():
     """Off, a span site costs a function call and a test, and `invoke`
     one counted call and an empty `with` more than it did (observed 0.07
