@@ -441,10 +441,18 @@ class HybridBlock(Block):
         ``remat`` on a marked child) takes the batch N rows at a time
         through the checkpointed block, one group after the other (a
         ``lax.map``), so the backward
-        rebuilds N rows' activations at once, not the batch's; the
-        block's inputs and outputs must all lead with the batch axis, and
-        a tally kept with ``defer_aux_update(..., increment=True)`` adds
-        up over the groups."""
+        rebuilds N rows' activations at once, not the batch's; a
+        tally kept with ``defer_aux_update(..., increment=True)`` adds
+        up over the groups. A block may take SEVERAL arrays and return
+        several (a decoder layer that reads another layer's keys and
+        values, or hands its own on: each is an input or an output of
+        the checkpoint, kept, and a value read by two blocks gets both
+        cotangents). Under ``remat_rows`` EVERY array input and output
+        must lead with the batch axis (so (B, heads, S, d), not (heads,
+        B, S, d)); where one input does not, or N does not divide the
+        batch, the rows are not split: the block runs once over the
+        whole batch, correct and with the whole batch's rebuilt
+        activations live."""
         prev = self._flags
         if remat is None:
             remat = prev.get("remat", False)
@@ -574,6 +582,9 @@ class HybridBlock(Block):
         rows = self._flags.get("remat_rows")
         batch = in_datas[0].shape[0] if in_datas and in_datas[0].ndim else 0
         n = 1
+        # the rows are split only where every array input leads with the
+        # batch axis and ``rows`` divides it; else (the fallback the
+        # docstring of `hybridize` names) the whole batch at once
         if rows and batch > rows and batch % rows == 0 \
                 and all(d.ndim and d.shape[0] == batch for d in in_datas):
             # ``rows`` rows at a time, one after the other (a lax.map: the
@@ -634,12 +645,17 @@ class HybridBlock(Block):
                     kept = [0, 0]
                     _REMAT_GUARD.kept = \
                         kept if _autograd.is_recording() else None
+                    tiles = _profiler.counters(device=False)
                     try:
                         entry = self._build_cached_op(args, inputs, params,
                                                       ctx, training)
                     finally:
                         _REMAT_GUARD.kept = None
                     build.set(remat_kept=kept[0], remat_kept_bytes=kept[1])
+                    # the flash kernels' tile tallies of this trace
+                    now = _profiler.counters(device=False)
+                    build.set(**{k: now[k] - tiles[k] for k in
+                                 ("flash_tiles", "flash_tiles_live")})
                 _profiler.count("remat_kept", kept[0])
                 _profiler.count("remat_kept_bytes", kept[1])
                 self._cached_graph[key] = entry

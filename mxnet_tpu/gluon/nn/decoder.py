@@ -2,7 +2,12 @@
 depthwise causal short convolution, and three mixers / layers that the
 Kimi Linear family (arXiv:2510.26692) is made of — a KDA (gated delta-rule
 linear attention) mixer, an MLA (latent attention, no positional encoding)
-mixer, and an expert layer that is told which experts it holds.
+mixer, and an expert layer that is told which experts it holds — and the
+three that SambaY (Phi-4-mini-flash, arXiv:2507.06607) is made of: a Mamba
+selective-scan mixer, differential attention (arXiv:2410.05258; self,
+windowed or full, or cross to another layer's keys and values) with grouped
+key/value heads, and a Gated Memory Unit that reuses another layer's scan
+output.
 
 No upstream-gluon analog. Layout (batch, seq, units); pre-norm residual
 wiring is the model's (gluon/model_zoo/kimi_linear.py). Every block is a
@@ -11,12 +16,17 @@ hybridized root traces one program and per-layer remat applies.
 """
 from __future__ import annotations
 
+import math
+
+import numpy as np
+
 from .basic_layers import Dense
 from .transformer import _merge_heads, _split_heads
 from ..block import HybridBlock, defer_aux_update
+from ... import initializer as _init
 
 __all__ = ["RMSNorm", "GatedMLP", "CausalConv1D", "KDAMixer", "MLAMixer",
-           "HeldExperts"]
+           "HeldExperts", "MambaMixer", "DiffAttention", "GatedMemoryUnit"]
 
 
 def _linear(units, in_units, dtype, init, prefix):
@@ -59,15 +69,18 @@ class CausalConv1D(HybridBlock):
     an optional SiLU: token t sees tokens t-K+1 .. t of its own channel."""
 
     def __init__(self, channels, kernel_size=4, activation="silu",
-                 weight_initializer=None, prefix=None, params=None):
+                 weight_initializer=None, use_bias=False, prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         self._activation = activation
         with self.name_scope():
             self.weight = self.params.get("weight", shape=(channels, kernel_size),
                                           init=weight_initializer)
+            self.bias = self.params.get("bias", shape=(channels,),
+                                        init="zeros") if use_bias else None
 
-    def hybrid_forward(self, F, x, weight):
-        return F.causal_conv1d(x, weight, activation=self._activation)
+    def hybrid_forward(self, F, x, weight, bias=None):
+        return F.causal_conv1d(x, weight, bias, activation=self._activation)
 
 
 class KDAMixer(HybridBlock):
@@ -246,3 +259,156 @@ class HeldExperts(HybridBlock):
         if self.shared is not None:
             out = out + self.shared(x)
         return out
+
+
+class _MambaALog(_init.Initializer):
+    """A_log[c, n] = log(n + 1): Mamba's S4D-real start."""
+
+    def __call__(self, desc, arr):
+        self._set(arr, np.broadcast_to(
+            np.log(np.arange(1, arr.shape[1] + 1, dtype=np.float64)), arr.shape))
+
+
+class _MambaDtBias(_init.Initializer):
+    """A step bias whose softplus is log-uniform in [1e-3, 1e-1] (Mamba's
+    dt_min / dt_max): the inverse softplus of the draw."""
+
+    def __call__(self, desc, arr):
+        dt = np.exp(_init._np_rng().uniform(math.log(1e-3), math.log(1e-1),
+                                            arr.shape))
+        self._set(arr, dt + np.log(-np.expm1(-dt)))
+
+
+class MambaMixer(HybridBlock):
+    """Mamba's mixer (arXiv:2312.00752): [u, z] = W_in x; u = SiLU(conv(u) +
+    b) (depthwise, causal); [d, B, C] = W_x u; delta = W_dt d + b_dt; s =
+    ``F.selective_scan`` (softplus(delta), A = -exp(A_log), the skip D u
+    included); output W_out (s * SiLU(z)). Returns ``(output, s)``: ``s`` (B,
+    S, d_inner), the scan's output before the gate, is what a Gated Memory
+    Unit further up reads."""
+
+    def __init__(self, units, d_inner, d_state=16, d_conv=4, dt_rank=None,
+                 dtype="float32", weight_initializer=None, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        dt_rank = dt_rank or -(-units // 16)
+        self._inner, self._state, self._rank = d_inner, d_state, dt_rank
+        init = weight_initializer
+        with self.name_scope():
+            self.in_proj = _linear(2 * d_inner, units, dtype, init, "in_")
+            self.conv = CausalConv1D(d_inner, d_conv, weight_initializer=init,
+                                     use_bias=True, prefix="conv_")
+            self.x_proj = _linear(dt_rank + 2 * d_state, d_inner, dtype, init,
+                                  "x_")
+            self.dt_proj = Dense(d_inner, flatten=False, dtype=dtype,
+                                 weight_initializer=init,
+                                 bias_initializer=_MambaDtBias(),
+                                 in_units=dt_rank, prefix="dt_")
+            self.out_proj = _linear(units, d_inner, dtype, init, "out_")
+            self.a_log = self.params.get("a_log", shape=(d_inner, d_state),
+                                         init=_MambaALog())
+            self.d = self.params.get("d", shape=(d_inner,), init="ones")
+
+    def hybrid_forward(self, F, x, a_log, d):
+        uz = self.in_proj(x)
+        u = self.conv(F.slice_axis(uz, axis=-1, begin=0, end=self._inner))
+        z = F.slice_axis(uz, axis=-1, begin=self._inner, end=None)
+        dbc = self.x_proj(u)
+        r, n = self._rank, self._state
+        delta = self.dt_proj(F.slice_axis(dbc, axis=-1, begin=0, end=r))
+        b = F.slice_axis(dbc, axis=-1, begin=r, end=r + n)
+        c = F.slice_axis(dbc, axis=-1, begin=r + n, end=None)
+        s = F.selective_scan(u, delta, a_log, b, c, d)
+        return self.out_proj(F.silu_mul(z, s)), s
+
+
+class GatedMemoryUnit(HybridBlock):
+    """SambaY's Gated Memory Unit: W_out (m * SiLU(W_in x)), where ``m`` is
+    another layer's memory at the same positions (the source Mamba layer's
+    scan output). No scan and no convolution of its own."""
+
+    def __init__(self, units, d_inner, dtype="float32", weight_initializer=None,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.in_proj = _linear(d_inner, units, dtype, weight_initializer, "in_")
+            self.out_proj = _linear(units, d_inner, dtype, weight_initializer,
+                                    "out_")
+
+    def hybrid_forward(self, F, x, m):
+        return self.out_proj(F.silu_mul(self.in_proj(x), m))
+
+
+class DiffAttention(HybridBlock):
+    """Differential attention (arXiv:2410.05258) with grouped key/value
+    heads and no positional encoding. ``num_heads`` query heads of
+    ``head_dim`` form num_heads / 2 pairs (q1_p, q2_p), ``num_kv_heads`` key
+    heads num_kv_heads / 2 pairs (k1_g, k2_g), and the values num_kv_heads /
+    2 paired values V_g of 2 * head_dim; pair p reads group g = p // (pairs
+    a group). o_p = (1 - lambda_init) * RMSNorm((A1_p - lambda A2_p) V_g)
+    with A^i = softmax(q^i k^i^T / sqrt(head_dim) + mask), lambda =
+    exp(lambda_q1 . lambda_k1) - exp(lambda_q2 . lambda_k2) + lambda_init;
+    then W_o. Two ``F.flash_attention`` calls (A1 V and A2 V), each with
+    half the query heads reading half as many key/value heads again.
+
+    Columns: the projection's q columns are [q1 of every pair, q2 of every
+    pair], k's [k1 of every group, k2 of every group], v's the groups' paired
+    values one after the other.
+
+    ``window``: causal sliding window (a token sees itself and the window -
+    1 before it); None: full causal. ``cross=True``: only q is projected
+    here; ``k`` (B, num_kv_heads, S, head_dim) and ``v`` (B, num_kv_heads /
+    2, S, 2 * head_dim) are a source layer's, handed in. A self layer returns
+    ``(output, k, v)`` so that it can be that source."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim, lambda_init,
+                 window=None, cross=False, epsilon=1e-5, dtype="float32",
+                 weight_initializer=None, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        if num_heads % 2 or num_kv_heads % 2 or num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query and {num_kv_heads} key/value "
+                             "heads do not pair and group")
+        self._h, self._hk, self._d = num_heads, num_kv_heads, head_dim
+        self._window, self._cross = window, cross
+        self._lambda_init, self._eps = float(lambda_init), epsilon
+        self._trace_scope = "mxtpu_swa" if window is not None else "mxtpu_yoco"
+        init = weight_initializer
+        wide = num_heads * head_dim if cross \
+            else (num_heads + 2 * num_kv_heads) * head_dim
+        with self.name_scope():
+            self.in_proj = Dense(wide, flatten=False, dtype=dtype,
+                                 weight_initializer=init, in_units=units,
+                                 prefix="q_" if cross else "qkv_")
+            self.o_proj = Dense(units, flatten=False, dtype=dtype,
+                                weight_initializer=init,
+                                in_units=num_heads * head_dim, prefix="o_")
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+                setattr(self, name, self.params.get(
+                    name, shape=(head_dim,), init=_init.Normal(0.1)))
+            self.subln_gamma = self.params.get(
+                "subln_gamma", shape=(2 * head_dim,), init="ones")
+
+    def hybrid_forward(self, F, x, k=None, v=None, lambda_q1=None,
+                       lambda_k1=None, lambda_q2=None, lambda_k2=None,
+                       subln_gamma=None):
+        h, hk, d = self._h, self._hk, self._d
+        proj = self.in_proj(x)
+        if self._cross:
+            q = _split_heads(F, proj, h)
+        else:
+            q = _split_heads(F, F.slice_axis(proj, axis=-1, begin=0, end=h * d), h)
+            k = _split_heads(F, F.slice_axis(proj, axis=-1, begin=h * d,
+                                             end=(h + hk) * d), hk)
+            v = _split_heads(F, F.slice_axis(proj, axis=-1, begin=(h + hk) * d,
+                                             end=None), hk // 2)
+        halves = []
+        for i in range(2):
+            halves.append(F.flash_attention(
+                F.slice_axis(q, axis=1, begin=i * h // 2, end=(i + 1) * h // 2),
+                F.slice_axis(k, axis=1, begin=i * hk // 2, end=(i + 1) * hk // 2),
+                v, causal=True, window=self._window, name_scope=self._trace_scope))
+        out = F.diff_attention_combine(
+            halves[0], halves[1], lambda_q1, lambda_k1, lambda_q2, lambda_k2,
+            subln_gamma, lambda_init=self._lambda_init, eps=self._eps)
+        out = self.o_proj(_merge_heads(F, out))
+        return out if self._cross else (out, k, v)

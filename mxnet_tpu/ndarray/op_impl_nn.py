@@ -452,7 +452,8 @@ def causal_mask_scores(scores):
 # ----------------------------------------------------------------------
 @register_op("flash_attention")
 def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
-                       causal=False, sm_scale=None):
+                       causal=False, sm_scale=None, window=None,
+                       name_scope=None):
     """softmax(Q K^T * scale) V over (B, H, S, D) inputs.
 
     Pallas flash kernel on TPU (O(S) memory); jnp fallback elsewhere.
@@ -471,8 +472,27 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
     multiple of 128 of the wider one and cuts the output back to v's: the
     pad adds nothing to q.k and its output columns are dropped. The jnp
     fallback needs no pad.
+
+    ``window=W`` (with ``causal``; static): a token sees itself and the
+    W - 1 keys before it. The kernel walks only the tiles the band crosses.
+    ``key`` and ``value`` may have FEWER HEADS than ``query`` ((B, Hk, S, D)
+    with Hk dividing H): query head h reads key/value head h // (H / Hk)
+    through the kernel's index maps (nothing is repeated in HBM), and their
+    gradients come back with Hk heads. They may also be another layer's
+    (cross-attention to keys and values a source layer projected): the op
+    only asks that the lengths agree with the mask wanted.
+    ``name_scope``: a ``jax.named_scope`` for the call, forward and
+    backward, so that a device trace can tell one kind of attention layer
+    from another (``mxtpu_swa``, ``mxtpu_yoco``).
     """
+    if name_scope is not None:
+        with jax.named_scope(name_scope):
+            return flash_attention_op(query, key, value, valid_len,
+                                      segment_ids, causal, sm_scale, window)
     from ..ops import pallas as _pallas
+
+    if window is not None and not causal:
+        raise ValueError("flash_attention: window needs causal=True")
 
     if valid_len is not None:
         valid_len = valid_len.astype(jnp.int32).reshape(-1)
@@ -495,8 +515,12 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
             sm_scale = scale
         out = _pallas.flash_attention(query, key, value, sm_scale,
                                       bool(causal), q_off, None, valid_len,
-                                      segment_ids)
+                                      segment_ids,
+                                      None if window is None else int(window))
         return out[..., :dv]
+    group = query.shape[1] // key.shape[1]
+    if group > 1:
+        key, value = (jnp.repeat(t, group, axis=1) for t in (key, value))
     s = jnp.einsum("bhqd,bhkd->bhqk",
                    query.astype(jnp.float32),
                    key.astype(jnp.float32)) * scale
@@ -510,6 +534,9 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
         mask = sm if mask is None else jnp.logical_and(mask, sm)
     if causal:
         cm = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            cm = jnp.logical_and(cm, jnp.triu(jnp.ones((sq, sk), bool),
+                                              k=sk - sq - int(window) + 1))
         mask = cm if mask is None else jnp.logical_and(mask, cm)
     if mask is not None:
         p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
@@ -947,21 +974,71 @@ def swiglu(data):
 
 @register_op("causal_conv1d")
 @_lean
-def causal_conv1d(data, weight, activation=None):
+def causal_conv1d(data, weight, bias=None, activation=None):
     """Depthwise causal convolution over time: data (B, S, C), weight
     (C, K); y_t = sum_i weight[:, i] * x_{t-(K-1)+i} (the last tap is the
-    current token), zeros before the row's start. ``activation='silu'``
-    applies SiLU to the result. A sum of K shifted products in float32."""
+    current token), zeros before the row's start, + ``bias`` (C,) where
+    given. ``activation='silu'`` applies SiLU to the result. A sum of K
+    shifted products in float32."""
     k = weight.shape[1]
     s = data.shape[1]
     x = jnp.pad(data.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     w = weight.astype(jnp.float32)
     y = sum(x[:, i:i + s] * w[:, i] for i in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     if activation == "silu":
         y = jax.nn.silu(y)
     elif activation is not None:
         raise ValueError(f"causal_conv1d: unknown activation {activation!r}")
     return y.astype(data.dtype)
+
+
+@register_op("silu_mul")
+@_lean
+def silu_mul(gate, data):
+    """SiLU(gate) * data, in float32; returns data's type."""
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * data.astype(jnp.float32)).astype(data.dtype)
+
+
+@register_op("selective_scan")
+def selective_scan_op(data, delta, a_log, b, c, skip):
+    """Mamba's selective scan (ops/pallas/ssm.py): data u and delta (B, L, C),
+    a_log (C, N), b and c (B, L, N), skip (C,). Per channel and state, in
+    float32, h_t = exp(D_t A) h_{t-1} + D_t b_t u_t with D_t = softplus(delta_t)
+    and A = -exp(a_log), h_0 = 0; returns y_t = sum_n c_t[n] h_t[:, n] +
+    skip * u_t, (B, L, C) in data's type. On the chip a Pallas kernel pair
+    with a hand-written backward (``mxtpu_ssm_fwd`` / ``mxtpu_ssm_bwd``: the
+    state stays in VMEM, the backward rebuilds it a time block at a time);
+    elsewhere a ``lax.scan`` over the tokens, differentiated by jax. The
+    call runs under ``jax.named_scope("mxtpu_ssm")``."""
+    from ..ops import pallas as _pallas
+    from ..ops.pallas import ssm as _ssm
+
+    use_kernel = (_pallas.pallas_ok_for(data)
+                  and data.dtype in (jnp.float32, jnp.bfloat16))
+    with jax.named_scope("mxtpu_ssm"):
+        return _ssm.selective_scan(data, delta, a_log, b, c, skip,
+                                   use_kernel=use_kernel)
+
+
+@register_op("diff_attention_combine")
+@_lean
+def diff_attention_combine(first, second, lambda_q1, lambda_k1, lambda_q2,
+                           lambda_k2, gamma, lambda_init=0.8, eps=1e-5):
+    """Differential attention's combination (arXiv:2410.05258), float32:
+    with lambda = exp(lambda_q1 . lambda_k1) - exp(lambda_q2 . lambda_k2) +
+    lambda_init, (1 - lambda_init) * RMSNorm(first - lambda * second) over
+    the last axis with gain ``gamma``. ``first`` and ``second`` (B, H, S, 2d)
+    are the two softmax maps' products with the paired values."""
+    f32 = jnp.float32
+    lam = (jnp.exp(jnp.sum(lambda_q1.astype(f32) * lambda_k1.astype(f32)))
+           - jnp.exp(jnp.sum(lambda_q2.astype(f32) * lambda_k2.astype(f32)))
+           + lambda_init)
+    x = first.astype(f32) - lam * second.astype(f32)
+    x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (x * gamma.astype(f32) * (1.0 - lambda_init)).astype(first.dtype)
 
 
 @register_op("kda_gate")

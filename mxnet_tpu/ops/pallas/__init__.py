@@ -17,6 +17,9 @@ we only drop to Pallas where XLA's own fusion genuinely loses:
 - ``kda`` — the gated delta rule (KDA linear attention) in chunks: the
   state's walk over the chunks with the state in VMEM, forward and a
   hand-written backward (``kda_chunked``).
+- ``ssm`` — Mamba's selective scan: the state of a block of channels in
+  VMEM, a hand-written backward that rebuilds it a time block at a time
+  (``selective_scan``).
 - ``moe`` — the experts a chip holds: dispatch tables at static shapes
   and a grouped matmul over the held experts' rows (``grouped_matmul``,
   ``experts_held``).
@@ -38,6 +41,7 @@ from .flash_attention import (paged_attention_reference,  # noqa: E402
 from .softmax_xent import softmax_xent_fused  # noqa: E402
 from .kda import kda_chunked  # noqa: E402
 from .moe import experts_held, grouped_matmul  # noqa: E402
+from .ssm import selective_scan  # noqa: E402
 
 __all__ = [
     "pallas_enabled",
@@ -50,6 +54,7 @@ __all__ = [
     "paged_attention_reference",
     "softmax_xent_fused",
     "kda_chunked",
+    "selective_scan",
     "grouped_matmul",
     "experts_held",
 ]

@@ -59,6 +59,30 @@ exact zeros through the l==0 guard; packed outputs and gradients then
 match each sequence run unpacked, bit-for-bit in block-free cases and
 within fp tolerance otherwise. Packing requires Sq == Skv (self
 attention; the KV-cache decode path has no packed analog here).
+
+A causal WINDOW (``window=W``, static): token t sees keys j with
+0 <= t - j < W, itself and the W - 1 before it (sliding-window layers).
+The kv tile is then at most as wide as the window, and tiles outside
+the band cost nothing: ``_block_visible`` skips them, and the forward
+and the split backward do not even walk them. Their grids' inner axis
+is narrowed to the most tiles a band crosses (``_band_steps``): step jj
+of q tile i visits kv tile ``_kv_tile(i, jj)``, the band's first tile
++ jj (``mxtpu_flash_fwd``, ``mxtpu_flash_bwd_dq``); step ii of kv tile
+j visits q tile ``_q_tile(j, ii)`` (``mxtpu_flash_bwd_dkv``). The fused
+backward (at most two kv tiles: rows no longer than two windows) walks
+its whole (kv, q) grid and skips by ``_block_visible`` alone.
+
+GROUPED key/value heads: k and v may have fewer heads than q (Hk
+divides H); query head h reads key/value head h // (H / Hk) through
+the index maps, k and v are not repeated in HBM. Forward and dq walk
+the B*H query rows; the dkv and fused backwards walk the B*Hk
+key/value rows and, inside each, the group's query heads one after the
+other, so that dk and dv are summed over the group in VMEM.
+
+Trace-time tallies (`profiler.counters()`): every forward or backward
+call adds the tiles of its (q tiles x kv tiles) rectangle to
+``flash_tiles`` and those its static masks (causal, window) leave live
+to ``flash_tiles_live``.
 """
 from __future__ import annotations
 
@@ -130,7 +154,7 @@ def _seg_range(qrng_ref, krng_ref, i, j, n_heads):
 
 
 def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-               kvl=None, smask=None):
+               kvl=None, smask=None, window=None):
     """Validity mask for the (i, j) score block, or None when every
     position is statically visible (no kv padding, not causal, no
     per-example length, no segments) — the common dense shape skips the
@@ -155,19 +179,25 @@ def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
         row = i * block_q + q_offset + lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         cm = col <= row
+        if window is not None:   # itself and the window - 1 before it
+            cm = jnp.logical_and(cm, row - col < window)
         mask = cm if mask is None else jnp.logical_and(mask, cm)
     return mask
 
 
 def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                   segrng=None):
+                   segrng=None, window=None):
     """Whether the (i, j) tile has ANY live score: causal skip, the
-    per-example length skip (tiles starting at/after kvl are dead —
-    the variable-length fast path's whole-tile saving), and the packed
-    segment-range skip (disjoint id ranges cannot share a segment, so
-    cross-sequence tiles cost no MXU work)."""
+    window skip (the tile's last key is older than the first row's
+    window), the per-example length skip (tiles starting at/after kvl
+    are dead — the variable-length fast path's whole-tile saving), and
+    the packed segment-range skip (disjoint id ranges cannot share a
+    segment, so cross-sequence tiles cost no MXU work)."""
     q_last = (i + 1) * block_q - 1 + q_offset
     vis = jnp.logical_or(not causal, j * block_k <= q_last)
+    if window is not None:
+        vis = jnp.logical_and(
+            vis, (j + 1) * block_k - 1 >= i * block_q + q_offset - (window - 1))
     if kvl is not None:
         vis = jnp.logical_and(vis, j * block_k < kvl)
     if segrng is not None:
@@ -177,29 +207,77 @@ def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
     return vis
 
 
+def _kv_tile(i, jj, window, q_offset, block_q, block_k):
+    """The kv tile that step ``jj`` of q tile ``i``'s narrowed walk visits:
+    the first tile of the rows' band, + jj."""
+    first = jnp.maximum(i * block_q + (q_offset - (window - 1)), 0) // block_k
+    return first + jj
+
+
+def _q_tile(j, ii, q_offset, block_q, block_k):
+    """The q tile that step ``ii`` of kv tile ``j``'s narrowed walk visits:
+    the first tile whose rows see the tile's keys (causal), + ii."""
+    return jnp.maximum(j * block_k - q_offset, 0) // block_q + ii
+
+
+def _band_steps(window, q_offset, block_q, block_k, nq, nk):
+    """(kv tiles a q tile's band crosses at most, q tiles a kv tile's):
+    the lengths of the narrowed inner axes, counted at trace time."""
+    i, j = np.arange(nq), np.arange(nk)
+    first_kv = np.maximum(i * block_q + q_offset - (window - 1), 0) // block_k
+    last_kv = np.minimum((i * block_q + block_q - 1 + q_offset) // block_k,
+                         nk - 1)
+    first_q = np.maximum(j * block_k - q_offset, 0) // block_q
+    last_q = np.minimum(
+        (j * block_k + block_k - 1 + window - 1 - q_offset) // block_q, nq - 1)
+    return (max(1, int((last_kv - first_kv + 1).max())),
+            max(1, int((last_q - first_q + 1).max())))
+
+
+def _tally_tiles(rows, causal, window, q_offset, block_q, block_k, nq, nk):
+    """Trace-time: ``flash_tiles`` += the tiles of this call's score
+    rectangles, ``flash_tiles_live`` += those the static masks leave live."""
+    i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    live = np.ones((nq, nk), bool)
+    if causal:
+        live &= j * block_k <= (i + 1) * block_q - 1 + q_offset
+    if window is not None:
+        live &= (j + 1) * block_k - 1 >= i * block_q + q_offset - (window - 1)
+    _profiler.count("flash_tiles", rows * nq * nk)
+    _profiler.count("flash_tiles_live", rows * int(live.sum()))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
                 sm_scale, causal, q_offset, kv_len, block_q, block_k,
-                precision, dynamic_kv, dynamic_seg, n_heads):
+                precision, dynamic_kv, dynamic_seg, n_heads,
+                window=None, narrow=None):
     if dynamic_seg:
         (qseg_ref, kseg_ref, qrng_ref, krng_ref,
          o_ref, lse_ref, acc_sc, m_sc, l_sc) = rest
     else:
         qseg_ref = kseg_ref = qrng_ref = krng_ref = None
         o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
-    i, j = pl.program_id(1), pl.program_id(2)
+    i, jj = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
+    # ``narrow`` (a window's walk): step jj visits the band's jj-th tile
+    j = _kv_tile(i, jj, window, q_offset, block_q, block_k) \
+        if narrow else jj
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _():
         acc_sc[:] = jnp.zeros_like(acc_sc)
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    # skip: causal invisibility, a tile past the example's kv length,
-    # or a packed tile whose segment-id ranges are disjoint
+    # skip: causal invisibility, a tile outside the window's band or past
+    # the example's kv length, or a packed tile whose segment-id ranges
+    # are disjoint
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                             _seg_range(qrng_ref, krng_ref, i, j, n_heads))
+                             _seg_range(qrng_ref, krng_ref, i, j, n_heads),
+                             window)
+    if narrow:
+        visible = jnp.logical_and(visible, j < narrow[1])
 
     @pl.when(visible)
     def _():
@@ -214,7 +292,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
         smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
             if dynamic_seg else None
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask)
+                          kvl, smask, window)
         if mask is not None:
             s = jnp.where(mask, s, np.float32(_NEG_INF))
 
@@ -234,7 +312,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
             preferred_element_type=jnp.float32, precision=precision)
         m_sc[:] = m_cur
 
-    @pl.when(j == nk - 1)
+    @pl.when(jj == nk - 1)
     def _():
         l = l_sc[:]
         l_safe = jnp.where(l == np.float32(0.0), np.float32(1.0), l)
@@ -247,22 +325,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    kvl_ref, *rest,
                    sm_scale, causal, q_offset, kv_len, block_q, block_k,
-                   precision, dynamic_kv, dynamic_seg, n_heads):
+                   precision, dynamic_kv, dynamic_seg, n_heads,
+                   window=None, narrow=None, group=1, steps=None):
     if dynamic_seg:
         qseg_ref, kseg_ref, qrng_ref, krng_ref, dq_ref, dq_sc = rest
     else:
         qseg_ref = kseg_ref = qrng_ref = krng_ref = None
         dq_ref, dq_sc = rest
-    i, j = pl.program_id(1), pl.program_id(2)
+    i, jj = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
+    j = _kv_tile(i, jj, window, q_offset, block_q, block_k) \
+        if narrow else jj
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                             _seg_range(qrng_ref, krng_ref, i, j, n_heads))
+                             _seg_range(qrng_ref, krng_ref, i, j, n_heads),
+                             window)
+    if narrow:
+        visible = jnp.logical_and(visible, j < narrow[1])
 
     @pl.when(visible)
     def _():
@@ -279,7 +363,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
             if dynamic_seg else None
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask)
+                          kvl, smask, window)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
         dp = jax.lax.dot_general(
@@ -290,35 +374,51 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
-    @pl.when(j == nk - 1)
+    @pl.when(jj == nk - 1)
     def _():
         # dq is wrt the ORIGINAL q: rescale once on the small (bq, d)
         # block (q was pre-scaled; ds here is wrt unscaled scores)
         dq_ref[0] = (dq_sc[:] * np.float32(sm_scale)).astype(dq_ref.dtype)
 
 
+def _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, steps):
+    """The q tile of inner step ``t`` of the (kv rows, kv tiles, q steps)
+    grids: with grouped heads the axis runs the group's query heads one
+    after the other, ``steps`` tiles each; narrowed, a head's step ii
+    visits the ii-th q tile that sees the kv tile."""
+    ii = t % steps if group > 1 else t
+    return _q_tile(j, ii, q_offset, block_q, block_k) if narrow else ii
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     kvl_ref, *rest,
                     sm_scale, causal, q_offset, kv_len, block_q, block_k,
-                    precision, dynamic_kv, dynamic_seg, n_heads):
-    # grid: (BH, nk, nq) — q is the inner (sequential) axis
+                    precision, dynamic_kv, dynamic_seg, n_heads,
+                    window=None, narrow=None, group=1, steps=None):
+    # grid: (B*Hk, nk, group * q steps) — q is the inner (sequential) axis
     if dynamic_seg:
         (qseg_ref, kseg_ref, qrng_ref, krng_ref,
          dk_ref, dv_ref, dk_sc, dv_sc) = rest
     else:
         qseg_ref = kseg_ref = qrng_ref = krng_ref = None
         dk_ref, dv_ref, dk_sc, dv_sc = rest
-    j, i = pl.program_id(1), pl.program_id(2)
+    j, t = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
-    kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
+    # any query head of the row's batch entry: the lengths are per example
+    kvl = kvl_ref[pl.program_id(0) * group if group > 1
+                  else pl.program_id(0)] if dynamic_kv else None
+    i = _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, steps)
 
-    @pl.when(i == 0)
+    @pl.when(t == 0)
     def _():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                             _seg_range(qrng_ref, krng_ref, i, j, n_heads))
+                             _seg_range(qrng_ref, krng_ref, i, j, n_heads),
+                             window)
+    if narrow:
+        visible = jnp.logical_and(visible, i < narrow[0])
 
     @pl.when(visible)
     def _():
@@ -335,7 +435,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
             if dynamic_seg else None
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask)
+                          kvl, smask, window)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
 
@@ -350,7 +450,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
-    @pl.when(i == nq - 1)
+    @pl.when(t == nq - 1)
     def _():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
@@ -359,7 +459,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       kvl_ref, *rest,
                       sm_scale, causal, q_offset, kv_len, block_q, block_k,
-                      precision, dynamic_kv, dynamic_seg, n_heads):
+                      precision, dynamic_kv, dynamic_seg, n_heads,
+                      window=None, narrow=None, group=1, steps=None):
     """One-pass backward: dq, dk, dv from a SINGLE traversal of the
     (q block, k block) grid — the score matrix s and dp are computed
     once per pair instead of once in a dq kernel and again in a dkv
@@ -378,17 +479,21 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         qseg_ref = kseg_ref = qrng_ref = krng_ref = None
         dq_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
-    j, i = pl.program_id(1), pl.program_id(2)
+    j, t = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
-    kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
+    # any query head of the row's batch entry: the lengths are per example
+    kvl = kvl_ref[pl.program_id(0) * group if group > 1
+                  else pl.program_id(0)] if dynamic_kv else None
+    i = _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, steps)
 
-    @pl.when(i == 0)
+    @pl.when(t == 0)
     def _():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                             _seg_range(qrng_ref, krng_ref, i, j, n_heads))
+                             _seg_range(qrng_ref, krng_ref, i, j, n_heads),
+                             window)
 
     @pl.when(visible)
     def _():
@@ -405,7 +510,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
             if dynamic_seg else None
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask)
+                          kvl, smask, window)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
 
@@ -431,7 +536,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # uninitialized)
         dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
 
-    @pl.when(i == nq - 1)
+    @pl.when(t == nq - 1)
     def _():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
@@ -462,10 +567,58 @@ _BLOCK_Q_CAP = 512
 _BLOCK_K_CAP = 2048
 
 
-def _pick_blocks(sq, skv):
+def _pick_blocks(sq, skv, window=None):
     bq = min(_BLOCK_Q_CAP, _pad_len(sq, 8))
     bk = min(_BLOCK_K_CAP, _pad_len(skv, 128))
+    if window is not None:
+        # a kv tile wider than the window is mostly masked: at most the
+        # window, so that a band crosses two tiles and the rest is skipped
+        bk = min(bk, _pad_len(window, 128))
     return bq, bk
+
+
+def _check_heads(q, k, v, window, causal, segment_ids):
+    """The group (query heads a key/value head) of a call, checked."""
+    h, hk = q.shape[1], k.shape[1]
+    if v.shape[1] != hk or h % hk:
+        raise ValueError(
+            f"key/value heads ({hk}, {v.shape[1]}) must agree and divide "
+            f"the query heads ({h})")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("window needs causal=True and window >= 1")
+    if segment_ids is not None and (window is not None or h != hk):
+        raise ValueError("segment_ids (packing) goes with neither a window "
+                         "nor grouped key/value heads")
+    return h // hk
+
+
+def _q_map(group, steps, narrow, q_offset, block_q, block_k):
+    """The q-side index map of the (kv rows, kv tiles, q steps) grids."""
+    if group == 1 and not narrow:
+        return lambda b_, j, i: (b_, i, 0)
+    g32, s32 = np.int32(group), np.int32(steps)
+
+    def qmap(b_, j, t):
+        i = _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, s32)
+        if narrow:
+            i = jnp.minimum(i, np.int32(narrow[0] - 1))
+        return (b_ * g32 + t // s32 if group > 1 else b_, i, 0)
+    return qmap
+
+
+def _kv_map(group, window, narrow, q_offset, block_q, block_k):
+    """The kv-side index map of the (q rows, q tiles, kv steps) grids."""
+    if group == 1 and not narrow:
+        return lambda b_, i, j: (b_, j, 0)
+    g32 = np.int32(group)
+
+    def kmap(b_, i, jj):
+        j = jj
+        if narrow:
+            j = jnp.minimum(_kv_tile(i, jj, window, q_offset, block_q, block_k),
+                            np.int32(narrow[1] - 1))
+        return (b_ // g32 if group > 1 else b_, j, 0)
+    return kmap
 
 
 def _expand_kv_lens(kv_lens, b, h):
@@ -529,14 +682,16 @@ def _seg_specs(block_q, block_k, n_heads, transposed_grid):
 @x32
 def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, kv_lens=None,
-               segment_ids=None):
+               segment_ids=None, window=None):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     if segment_ids is not None and sq != skv:
         raise ValueError(
             f"segment_ids (packing) requires self-attention shapes, got "
             f"sq={sq} != skv={skv}")
-    bq0, bk0 = _pick_blocks(sq, skv)
+    group = _check_heads(q, k, v, window, causal, segment_ids)
+    hk = h // group
+    bq0, bk0 = _pick_blocks(sq, skv, window)
     block_q = block_q or bq0
     block_k = block_k or bk0
     sq_p, skv_p = _pad_len(sq, block_q), _pad_len(skv, block_k)
@@ -544,8 +699,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
     # pre-scale q so the kernels never run the (block_q, block_k)
     # elementwise *sm_scale (dq is rescaled on its small output block)
     qf = (q * sm_scale).astype(q.dtype).reshape(b * h, sq, d)
-    kf = k.reshape(b * h, skv, d)
-    vf = v.reshape(b * h, skv, d)
+    kf = k.reshape(b * hk, skv, d)
+    vf = v.reshape(b * hk, skv, d)
     if sq_p != sq:
         qf = _pad0(qf, ((0, 0), (0, sq_p - sq), (0, 0)))
     if skv_p != skv:
@@ -558,18 +713,22 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
     kvlf = _expand_kv_lens(kv_lens, b, h) if dynamic_kv \
         else jnp.full((bh,), skv, jnp.int32)
     nq, nk = sq_p // block_q, skv_p // block_k
+    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk)
+    # a window's walk: the inner axis holds the band's tiles, not the row's
+    narrow = (nq, nk) if window is not None else None
+    kv_steps = _band_steps(window, q_offset, block_q, block_k, nq, nk)[0] \
+        if narrow else nk
     kern = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         q_offset=q_offset, kv_len=skv, block_q=block_q, block_k=block_k,
         precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
-        dynamic_seg=dynamic_seg, n_heads=h)
+        dynamic_seg=dynamic_seg, n_heads=h, window=window, narrow=narrow)
+    kv_map = _kv_map(group, window, narrow, q_offset, block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, kf, vf, kvlf]
@@ -579,7 +738,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                                         sq_p, skv_p, block_q, block_k))
     o, lse = pl.pallas_call(
         kern,
-        grid=(bh, nq, nk),
+        grid=(bh, nq, kv_steps),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
@@ -607,10 +766,12 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
 @x32
 def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, dlse=None, kv_lens=None,
-               segment_ids=None):
+               segment_ids=None, window=None):
     b, h, sq, d = q.shape
     skv = k.shape[2]
-    bq0, bk0 = _pick_blocks(sq, skv)
+    hk = k.shape[1]        # the forward checked the heads
+    group = h // hk
+    bq0, bk0 = _pick_blocks(sq, skv, window)
     block_q = block_q or bq0
     block_k = block_k or bk0
     sq_p, skv_p = _pad_len(sq, block_q), _pad_len(skv, block_k)
@@ -631,8 +792,8 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     # pre-scaled q (matches forward): s = q'k^T directly; dk = ds^T q'
     # IS the original-k gradient, dq rescales by sm_scale at the write
     qf = (q * sm_scale).astype(q.dtype).reshape(bh, sq, d)
-    kf = k.reshape(bh, skv, d)
-    vf = v.reshape(bh, skv, d)
+    kf = k.reshape(b * hk, skv, d)
+    vf = v.reshape(b * hk, skv, d)
     dof = do.reshape(bh, sq, d)
     lsef = lse.reshape(bh, sq, 1)
     if sq_p != sq:
@@ -648,10 +809,12 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
         kf, vf = _pad0(kf, pad), _pad0(vf, pad)
 
     nq, nk = sq_p // block_q, skv_p // block_k
+    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk)
     common = dict(sm_scale=sm_scale, causal=causal, q_offset=q_offset,
                   kv_len=skv, block_q=block_q, block_k=block_k,
                   precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
-                  dynamic_seg=seg_ops is not None, n_heads=h)
+                  dynamic_seg=seg_ops is not None, n_heads=h,
+                  window=window, group=group)
 
     # the fused pass writes nk f32 dq-partial copies to HBM; past nk=2
     # that memory/write cliff outweighs the recompute saving, so long
@@ -660,14 +823,15 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     if nk <= 2:
         return _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
                                 (b, h, sq, skv, d), nq, nk, common,
-                                interpret, k.dtype, v.dtype, q.dtype)
+                                interpret, k.dtype, v.dtype, q.dtype, group)
     return _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
                             (b, h, sq, skv, d), nq, nk, common,
-                            interpret, k.dtype, v.dtype, q.dtype)
+                            interpret, k.dtype, v.dtype, q.dtype, group)
 
 
 def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
-                     nq, nk, common, interpret, k_dtype, v_dtype, q_dtype):
+                     nq, nk, common, interpret, k_dtype, v_dtype, q_dtype,
+                     group=1):
     """Single-pass dq/dk/dv, taken where the kv grid has at most two
     blocks: ``bert_base.train_b64x512`` (S=512, nk=1) runs this kernel;
     ``kimi_linear_48b_a3b.train_8k``'s latent attention (S=8192 at the
@@ -676,20 +840,26 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     bh = b * h
     block_q, block_k = common["block_q"], common["block_k"]
     sq_p, skv_p = nq * block_q, nk * block_k
+    common = dict(common, steps=nq)
+    # the q side: with grouped heads the inner axis runs the group's query
+    # heads one after the other, nq tiles each
+    q_map = _q_map(group, nq, None, 0, block_q, block_k)
+    if group == 1:
+        dq_map = lambda b_, j, i: (b_, j, i, 0)  # noqa: E731
+    else:
+        def dq_map(b_, j, t):
+            row, i, _ = q_map(b_, j, t)
+            return (row, j, i, 0)
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, kf, vf, dof, lsef, delta, kvlf]
@@ -698,11 +868,10 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
         operands += seg_ops
     dq_part, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, **common),
-        grid=(bh, nk, nq),
+        grid=(bh // group, nk, group * nq),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, j, i: (b_, j, i, 0),
+            pl.BlockSpec((1, 1, block_q, d), dq_map,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                          memory_space=pltpu.VMEM),
@@ -713,8 +882,8 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
             # f32 partials: the cross-k-block sum happens outside the
             # kernel in f32, then casts once to the caller dtype
             jax.ShapeDtypeStruct((bh, nk, sq_p, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, skv_p, d), k_dtype),
-            jax.ShapeDtypeStruct((bh, skv_p, d), v_dtype),
+            jax.ShapeDtypeStruct((bh // group, skv_p, d), k_dtype),
+            jax.ShapeDtypeStruct((bh // group, skv_p, d), v_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -728,25 +897,32 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     dq = dq_part.sum(axis=1).astype(q_dtype) if nk > 1 \
         else dq_part[:, 0].astype(q_dtype)
     dq = dq[:, :sq].reshape(b, h, sq, d)
-    dk = dk[:, :skv].reshape(b, h, skv, d)
-    dv = dv[:, :skv].reshape(b, h, skv, d)
+    dk = dk[:, :skv].reshape(b, h // group, skv, d)
+    dv = dv[:, :skv].reshape(b, h // group, skv, d)
     return dq, dk, dv
 
 
 def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
-                     nq, nk, common, interpret, k_dtype, v_dtype, q_dtype):
+                     nq, nk, common, interpret, k_dtype, v_dtype, q_dtype,
+                     group=1):
     b, h, sq, skv, d = dims
     bh = b * h
     block_q, block_k = common["block_q"], common["block_k"]
     sq_p, skv_p = nq * block_q, nk * block_k
+    window, q_offset = common.get("window"), common["q_offset"]
+    # a window's walk: both inner axes hold a band's tiles, not a row's
+    narrow = (nq, nk) if window is not None else None
+    kv_steps, q_steps = _band_steps(window, q_offset, block_q, block_k,
+                                    nq, nk) if narrow else (nk, nq)
+    common = dict(common, narrow=narrow, steps=q_steps)
+    kv_map = _kv_map(group, window, narrow, q_offset, block_q, block_k)
+    q_map = _q_map(group, q_steps, narrow, q_offset, block_q, block_k)
 
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0),
@@ -761,7 +937,7 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
         operands += seg_ops
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
-        grid=(bh, nq, nk),
+        grid=(bh, nq, kv_steps),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                                memory_space=pltpu.VMEM),
@@ -773,25 +949,21 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     )(*operands)
 
     dkv_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     if seg_ops is not None:
         dkv_specs += _seg_specs(block_q, block_k, h, transposed_grid=True)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
-        grid=(bh, nk, nq),
+        grid=(bh // group, nk, group * q_steps),
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
@@ -800,8 +972,8 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, skv_p, d), k_dtype),
-            jax.ShapeDtypeStruct((bh, skv_p, d), v_dtype),
+            jax.ShapeDtypeStruct((bh // group, skv_p, d), k_dtype),
+            jax.ShapeDtypeStruct((bh // group, skv_p, d), v_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -813,8 +985,8 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     )(*operands)
 
     dq = dq[:, :sq].reshape(b, h, sq, d)
-    dk = dk[:, :skv].reshape(b, h, skv, d)
-    dv = dv[:, :skv].reshape(b, h, skv, d)
+    dk = dk[:, :skv].reshape(b, h // group, skv, d)
+    dv = dv[:, :skv].reshape(b, h // group, skv, d)
     return dq, dk, dv
 
 
@@ -839,14 +1011,14 @@ REMAT_KEEP = ("flash_out", "flash_lse")
 
 
 def _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret, kv_lens,
-               segment_ids):
+               segment_ids, window=None):
     """The forward that the primals and the VJPs' forward rules share:
     (out, lse) under their `REMAT_KEEP` names."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     o, lse = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
                         resolve_interpret(interpret), kv_lens=kv_lens,
-                        segment_ids=segment_ids)
+                        segment_ids=segment_ids, window=window)
     named = []
     for name, x in zip(REMAT_KEEP, (o, lse)):
         _profiler.note_named(name, x)   # trace time: `remat_kept`'s tally
@@ -854,21 +1026,22 @@ def _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret, kv_lens,
     return tuple(named)
 
 
-def _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, res, do, dlse=None):
+def _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, window, res, do,
+                   dlse=None):
     q, k, v, o, lse, kv_lens, segment_ids = res
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, sm_scale, bool(causal),
                             int(q_offset), resolve_interpret(interpret),
                             dlse=dlse, kv_lens=kv_lens,
-                            segment_ids=segment_ids)
+                            segment_ids=segment_ids, window=window)
     return dq, dk, dv, _int_ct(kv_lens), _int_ct(segment_ids)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9))
 def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
                              q_offset=0, interpret=None, kv_lens=None,
-                             segment_ids=None):
+                             segment_ids=None, window=None):
     """Flash attention returning (out, lse) — DIFFERENTIABLE in both
     outputs (the lse cotangent folds into the backward's delta term).
 
@@ -877,45 +1050,54 @@ def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
     the log-sum-exp combiner and lets gradients flow through both.
     ``kv_lens`` (B,) int32 masks keys at/after each example's length.
     ``segment_ids`` (B, S) int32 restricts attention to same-segment
-    pairs (sequence packing; see the module docstring).
+    pairs (sequence packing; see the module docstring). ``window`` and
+    grouped key/value heads as in :func:`flash_attention`.
     """
     return _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                      kv_lens, segment_ids)
+                      kv_lens, segment_ids, window)
 
 
 def _flash_lse_vjp_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
-                       kv_lens=None, segment_ids=None):
+                       kv_lens=None, segment_ids=None, window=None):
     o, lse = _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                        kv_lens, segment_ids)
+                        kv_lens, segment_ids, window)
     # the primal output IS the named value: one kept array serves both
     return (o, lse), (q, k, v, o, lse, kv_lens, segment_ids)
 
 
-def _flash_lse_vjp_bwd(sm_scale, causal, q_offset, interpret, res, cts):
-    return _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, res, *cts)
+def _flash_lse_vjp_bwd(sm_scale, causal, q_offset, interpret, window, res,
+                       cts):
+    return _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, window, res,
+                          *cts)
 
 
 flash_attention_with_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9))
 def flash_attention(q, k, v, sm_scale=None, causal=False, q_offset=0,
-                    interpret=None, kv_lens=None, segment_ids=None):
+                    interpret=None, kv_lens=None, segment_ids=None,
+                    window=None):
     """softmax(q k^T * scale [+causal/length/segment mask]) v,
     blockwise in VMEM. ``kv_lens`` (B,) int32 masks keys at/after each
     example's valid length (variable-length batches, e.g. BERT
     padding); ``segment_ids`` (B, S) int32 makes attention
     block-diagonal over packed sequences (see module docstring). q, k
     and v share one head width; ``mx.nd.flash_attention`` pads a wider
-    q.k (192 against v's 128) up to it and passes the true ``sm_scale``."""
+    q.k (192 against v's 128) up to it and passes the true ``sm_scale``.
+    ``window`` (static, with ``causal``): a token sees itself and the
+    ``window - 1`` keys before it; tiles outside the band are not walked.
+    k and v may have fewer heads than q (q (B, H, S, D), k/v (B, Hk, S, D),
+    Hk divides H): query head h reads key/value head h // (H / Hk), and
+    dk, dv come back with Hk heads, summed over each group."""
     return _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                      kv_lens, segment_ids)[0]
+                      kv_lens, segment_ids, window)[0]
 
 
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
-                   kv_lens=None, segment_ids=None):
+                   kv_lens=None, segment_ids=None, window=None):
     o, lse = _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                        kv_lens, segment_ids)
+                        kv_lens, segment_ids, window)
     return o, (q, k, v, o, lse, kv_lens, segment_ids)
 
 

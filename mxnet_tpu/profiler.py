@@ -51,11 +51,13 @@ _LOCK = threading.Lock()
 # bumps "invokes", `HybridBlock._call_cached_op` "cachedop_builds" (and,
 # from what that trace's rematerialised blocks keep, "remat_kept" and
 # "remat_kept_bytes"), `Optimizer.update_multi` "fused" and "looped" (and
-# "invokes", once, for its compiled program). Not locked: a span reads
-# the difference on its own thread, which is exact while no other thread
-# dispatches (a training loop).
+# "invokes", once, for its compiled program), the flash attention
+# wrappers, while traced, "flash_tiles" and "flash_tiles_live". Not locked:
+# a span reads the difference on its own thread, which is exact while no
+# other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
-           "remat_kept": 0, "remat_kept_bytes": 0}
+           "remat_kept": 0, "remat_kept_bytes": 0,
+           "flash_tiles": 0, "flash_tiles_live": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -148,6 +150,13 @@ def counters(device=True):
     ``remat_rows`` counts each time. Tallied when a block is traced under
     ``autograd.record``, so flat across steps like ``cachedop_builds``;
     0 with remat'd attention layers means their forward runs twice a step).
+    ``flash_tiles`` / ``flash_tiles_live``: the (q tile, kv tile) pairs of
+    the score rectangles of every flash attention kernel call traced so far
+    (forward and backward calls alike), and those of them that the call's
+    static masks (causal, window) leave live, which are the ones that do
+    matmul work; the ratio says how much of S^2 a model's attention layers
+    skip, and one near 1 under a window says the window is masked inside
+    tiles, not skipped. Trace-time tallies, flat across steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and

@@ -213,3 +213,38 @@ def test_grouped_expert_matmul_fwd_bwd_compiles(one_chip):
                           ((held, 2 * width, hidden), BF16),
                           ((held, hidden, width), BF16))
     _assert_kernels(text, "mxtpu_moe_gmm", "mxtpu_moe_tgmm")
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window_512", "full"])
+def test_flash_window_and_grouped_heads_compile(one_chip, window):
+    """One row of differential attention's half at 8,192 tokens: 20 query
+    heads read 10 key/value heads, 128 wide (q.k padded from 64), with the
+    512-token window (narrowed walks) and without."""
+    def step(q, k, v):
+        return jax.grad(lambda q, k, v: _sum(flash_attention(
+            q, k, v, 0.125, True, 0, False, None, None, window)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(step, one_chip, ((1, 20, 8192, 128), BF16),
+                          ((1, 10, 8192, 128), BF16), ((1, 10, 8192, 128), BF16))
+    _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_dq",
+                    "mxtpu_flash_bwd_dkv")
+
+
+def test_selective_scan_fwd_bwd_compiles(one_chip):
+    """One row of Phi-4-mini-flash's Mamba layers: 8,192 tokens, 5,120
+    channels, 16 states."""
+    from mxnet_tpu.ops.pallas import selective_scan
+
+    rows, length, channels, states = 1, 8192, 5120, 16
+
+    def step(u, delta, a_log, b, c, skip):
+        return jax.grad(lambda *a: _sum(selective_scan(
+            *a, use_kernel=True, interpret=False)), argnums=tuple(range(6)))(
+            u, delta, a_log, b, c, skip)
+
+    text = _compiled_text(step, one_chip, ((rows, length, channels), BF16),
+                          ((rows, length, channels), BF16),
+                          ((channels, states), BF16), ((rows, length, states), BF16),
+                          ((rows, length, states), BF16), ((channels,), BF16))
+    _assert_kernels(text, "mxtpu_ssm_fwd", "mxtpu_ssm_bwd")
