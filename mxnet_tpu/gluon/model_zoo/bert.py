@@ -136,6 +136,7 @@ class BERTMLMHead(HybridBlock):
     def __init__(self, vocab_size, units, layer_norm_eps=1e-12,
                  dtype="float32", prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        self._units = units
         with self.name_scope():
             self.transform = Dense(units, flatten=False, dtype=dtype,
                                    prefix="transform_")
@@ -145,7 +146,13 @@ class BERTMLMHead(HybridBlock):
                                  prefix="decoder_")
 
     def hybrid_forward(self, F, seq):
-        return self.decoder(self.ln(self.act(self.transform(seq))))
+        # the vocabulary projection is a 2-D product: behind one XLA
+        # writes the logits in the layout F.softmax_cross_entropy reads
+        # (a caller's reshape to 2-D folds with the one back to 3-D)
+        h = self.ln(self.act(self.transform(seq)))
+        logits = self.decoder(F.reshape(h, shape=(-1, self._units)))
+        return F.reshape_like(logits, seq, lhs_begin=0, lhs_end=1,
+                              rhs_begin=0, rhs_end=-1)
 
 
 class BERTNSPHead(HybridBlock):

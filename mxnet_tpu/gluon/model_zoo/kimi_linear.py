@@ -42,16 +42,16 @@ class _LMHead(HybridBlock):
     def __init__(self, vocab, units, dtype, weight_initializer, prefix=None,
                  params=None):
         super().__init__(prefix=prefix, params=params)
-        self._vocab = vocab
+        self._units = units
         with self.name_scope():
             self.proj = _linear(vocab, units, dtype, weight_initializer, "head_")
 
     def hybrid_forward(self, F, x, labels=None):
-        logits = self.proj(x)
         if labels is None:
-            return logits
+            return self.proj(x)
+        # a 2-D product, as F.softmax_cross_entropy wants its producer
         return F.softmax_cross_entropy(
-            F.reshape(logits, shape=(-1, self._vocab)),
+            self.proj(F.reshape(x, shape=(-1, self._units))),
             F.reshape(labels, shape=(-1,)))
 
 

@@ -70,18 +70,19 @@ class _Head(HybridBlock):
     def __init__(self, vocab, units, dtype, weight_initializer, prefix=None,
                  params=None):
         super().__init__(prefix=prefix, params=params)
-        self._vocab = vocab
+        self._vocab, self._units = vocab, units
         self.weight = self.params.get("weight", shape=(vocab, units),
                                       dtype=dtype, init=weight_initializer)
 
     def hybrid_forward(self, F, x, labels=None, weight=None):
+        if labels is not None:
+            # a 2-D product, as F.softmax_cross_entropy wants its producer
+            x = F.reshape(x, shape=(-1, self._units))
         logits = F.FullyConnected(x, weight, None, no_bias=True,
                                   num_hidden=self._vocab, flatten=False)
         if labels is None:
             return logits
-        return F.softmax_cross_entropy(
-            F.reshape(logits, shape=(-1, self._vocab)),
-            F.reshape(labels, shape=(-1,)))
+        return F.softmax_cross_entropy(logits, F.reshape(labels, shape=(-1,)))
 
 
 class Phi4FlashLayer(HybridBlock):

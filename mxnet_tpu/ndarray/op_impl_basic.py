@@ -369,8 +369,14 @@ def reshape(x, shape=None, reverse=False):
 
 
 @register_op("reshape_like")
-def reshape_like(x, other):
-    return jnp.reshape(x, other.shape)
+def reshape_like(x, other, lhs_begin=None, lhs_end=None, rhs_begin=None,
+                 rhs_end=None):
+    """``x``'s axes [lhs_begin, lhs_end) take the shape of ``other``'s
+    [rhs_begin, rhs_end); None is an end of the shape, negatives count
+    from the back (matrix_op.cc ReshapeLikeParam)."""
+    lhs = range(x.ndim)[lhs_begin:lhs_end]
+    return jnp.reshape(x, x.shape[:lhs.start] + other.shape[rhs_begin:rhs_end]
+                       + x.shape[lhs.stop:])
 
 
 @register_op("shape_array", differentiable=False)

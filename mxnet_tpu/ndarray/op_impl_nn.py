@@ -331,6 +331,12 @@ def softmax_output(data, label, grad_scale=1.0, ignore_label=-1.0,
 
 @register_op("softmax_cross_entropy")
 def softmax_cross_entropy(data, label):
+    """Summed -log softmax(data)[label]; data (N, V), label (N,).
+
+    Produce ``data`` with a 2-D product (``FullyConnected`` of a 2-D
+    input): the kernel reads the logits vocabulary-major and XLA writes
+    them so only behind a 2-D matmul. A 3-D product reshaped to (N, V)
+    costs a copy of the logits (``ops/pallas/softmax_xent.py``)."""
     from ..ops import pallas as _pallas
 
     if (_pallas.pallas_ok_for(data)

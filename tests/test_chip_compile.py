@@ -15,6 +15,8 @@ fixture would skip it).
 """
 import functools
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,14 +162,134 @@ def test_layer_norm_fwd_bwd_compiles(one_chip):
     _assert_kernels(text, "mxtpu_layer_norm_fwd", "mxtpu_layer_norm_bwd")
 
 
-def test_softmax_xent_fwd_bwd_compiles(one_chip):
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_softmax_xent_fwd_bwd_compiles(one_chip, dtype):
+    """float32 logits reach the kernel too (`contrib/amp/lists.py` keeps
+    the op in float32): a tile sized for bfloat16 alone overran the
+    backward's scoped VMEM there."""
     def step(logits, labels):
         return jax.grad(lambda x: _sum(
             softmax_xent_fused(x, labels, False)))(logits)
 
-    text = _compiled_text(step, one_chip, ((8192, 30522), BF16),
+    text = _compiled_text(step, one_chip, ((8192, 30522), dtype),
                           ((8192,), jnp.int32))
     _assert_kernels(text, "mxtpu_softmax_xent_fwd", "mxtpu_softmax_xent_bwd")
+
+
+def _bert_head():
+    """`benchmark/chip/models/bert_base.py`'s head and loss: the head's 3-D
+    logits reshaped to 2-D by the caller."""
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon.model_zoo.bert import BERTMLMHead
+
+    class MLM(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.head = BERTMLMHead(30522, 768, prefix="head_")
+
+        def hybrid_forward(self, F, seq, labels):
+            return F.softmax_cross_entropy(
+                F.reshape(self.head(seq), shape=(-1, 30522)),
+                F.reshape(labels, shape=(-1,)))
+
+    return MLM()
+
+
+def _kimi_head():
+    from mxnet_tpu.gluon.model_zoo.kimi_linear import _LMHead
+    return _LMHead(20480, 2304, "bfloat16", None)
+
+
+def _phi_head():
+    from mxnet_tpu.gluon.model_zoo.phi4_flash import _Head
+    return _Head(25008, 2560, "bfloat16", None)
+
+
+_HEADS = {
+    # cell: (the block, its input, the vocabulary, two programs a step?)
+    "bert_base": (_bert_head, (64, 512, 768), 30522, True),
+    "kimi_linear_48b_a3b": (_kimi_head, (2, 2048, 2304), 20480, False),
+    "phi4_mini_flash": (_phi_head, (2, 2048, 2560), 25008, False),
+}
+
+
+def _turned_logits(text, size):
+    """The `copy` and `transpose` instructions of a compiled program whose
+    result has ``size`` elements."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) == size:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(_HEADS))
+def test_vocabulary_head_compiles_without_a_copy_of_the_logits(
+        one_chip, monkeypatch, cell):
+    """The three cells' head BLOCKS with their loss, forward and backward at
+    the cell's shape: no instruction turns the logits (2 GB in `bert_base`,
+    three times a step before PR 32). The kernel reads them vocabulary-major,
+    which XLA writes only behind a 2-D product: a head that multiplies 3-D,
+    or a kernel that reads them token-major, fails here.
+
+    Kimi's and Phi's stretch runs under `jax.checkpoint` in one program, as
+    under `remat_per_layer`. BERT's is staged as `ndarray/register.invoke`
+    stages a recorded CachedOp: `jax.vjp` over the jitted block, so a
+    forward program that hands its residuals to a backward program; a
+    residual's layout between the two is the device's choice, not the
+    kernel's."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.block import functionalize
+    from mxnet_tpu.ops.pallas import _util
+
+    make, x_shape, vocab, two_programs = _HEADS[cell]
+    block = make()
+    block.initialize(init=mx.initializer.Zero())
+    if two_programs:  # its Dense layers learn their widths from a call
+        block(mx.nd.zeros((1, 8, x_shape[-1])), mx.nd.zeros((1, 8)))
+    block.cast("bfloat16")
+    fn, params = functionalize(block, training=True)
+    # this process's arrays are the CPU's: steer the ops to the kernels
+    monkeypatch.setattr(_util, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(_util, "_platforms", lambda data: {"tpu"})
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = ({k: aval(v.shape, v.dtype) for k, v in params.items()},
+            aval(x_shape, BF16), aval(x_shape[:2], jnp.int32))
+    key = jax.random.PRNGKey(0)
+
+    def head(p, x, labels):
+        return _sum(fn(p, key, x, labels))
+
+    if two_programs:
+        kept = {}
+
+        def forward(p, x, labels):
+            loss, pullback = jax.vjp(jax.jit(head), p, x, labels)
+            residuals, kept["tree"] = jax.tree_util.tree_flatten(pullback)
+            return loss, residuals
+
+        def backward(residuals, g):
+            return jax.tree_util.tree_unflatten(kept["tree"], residuals)(g)
+
+        first = jax.jit(forward).lower(*args)
+        residuals = [aval(r.shape, r.dtype) for r in first.out_info[1]]
+        texts = [first.compile().as_text(),
+                 jax.jit(backward).lower(residuals, aval((), jnp.float32))
+                 .compile().as_text()]
+    else:
+        step = jax.grad(jax.checkpoint(head), argnums=(0, 1))
+        texts = [jax.jit(step).lower(*args).compile().as_text()]
+
+    _assert_kernels("".join(texts), "mxtpu_softmax_xent_fwd",
+                    "mxtpu_softmax_xent_bwd")
+    for text in texts:
+        assert not _turned_logits(text, vocab * x_shape[0] * x_shape[1])
 
 
 @pytest.mark.parametrize("sq", [1, 64], ids=["decode", "chunked_prefill"])

@@ -290,6 +290,21 @@ def test_shape_ops():
     assert_almost_equal(nd.cumsum(a, axis=1).asnumpy(), np.cumsum(x, 1))
 
 
+@pytest.mark.parametrize("lhs, rhs, ranges, want", [
+    ((6, 5), (2, 3, 7), dict(lhs_begin=0, lhs_end=1, rhs_begin=0, rhs_end=-1),
+     (2, 3, 5)),
+    ((6, 5), (6, 9), dict(lhs_begin=0, lhs_end=1, rhs_begin=0, rhs_end=-1),
+     (6, 5)),
+    ((2, 12, 5), (3, 4), dict(lhs_begin=1, lhs_end=2), (2, 3, 4, 5)),
+    ((30,), (7, 5, 6), dict(rhs_begin=-2), (5, 6)),
+])
+def test_reshape_like_over_ranges_of_axes(lhs, rhs, ranges, want):
+    x = _any(lhs)
+    out = nd.reshape_like(nd.array(x), nd.zeros(rhs), **ranges)
+    assert out.shape == want
+    assert_almost_equal(out.asnumpy(), x.reshape(want))
+
+
 def test_pad_depth_space_diag():
     x = _any((2, 4, 3, 3))
     want = np.pad(x, ((0, 0), (0, 0), (1, 1), (1, 1)), constant_values=2.0)

@@ -4,6 +4,8 @@ Mirrors the reference's cross-backend golden harness
 (tests/python/gpu/test_operator_gpu.py check_consistency): the fused
 kernel path is compared against the plain jnp/XLA lowering.
 """
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,8 @@ from mxnet_tpu.ops.pallas.flash_attention import (flash_attention,
 from mxnet_tpu.ops.pallas.layer_norm import layer_norm_fused
 from mxnet_tpu.ops.pallas.softmax_xent import softmax_xent_fused
 from test_chip_compile import _FLASH, flash_caps, flash_mod
+
+xent_mod = importlib.import_module("mxnet_tpu.ops.pallas.softmax_xent")
 
 
 def _ln_ref(x, g, b, eps=1e-5):
@@ -140,21 +144,56 @@ def test_flash_attention_lse():
                                rtol=RTOL, atol=ATOL)
 
 
-@pytest.mark.parametrize("n,v", [(50, 1000), (64, 128), (33, 513)])
-def test_softmax_xent_fused(n, v):
+_XENT = [
+    # n, v, dtype, (block_v, block_n) of 2-byte logits or None for the
+    # module's; float32 blocks are half as tall (the same bytes a tile)
+    (50, 1000, "float32", None),
+    (64, 128, "float32", None),
+    (33, 513, "float32", None),
+    # one whole block of a vocabulary that is no multiple of 8
+    (200, 1018, "float32", None),
+    # the module's tiles, a partial last block on both axes
+    (640, 2560, "bfloat16", None),
+    (640, 2560, "float32", None),
+    # several blocks: a vocabulary that is no multiple of 8, one that is a
+    # multiple of 16 and not of 128, an aligned one; tokens that are no
+    # multiple of the token block
+    (300, 1018, "float32", (256, 128)),
+    (300, 1018, "bfloat16", (256, 128)),
+    (300, 1072, "float32", (256, 128)),
+    (256, 1072, "bfloat16", (512, 128)),
+    (384, 2048, "bfloat16", (512, 128)),
+]
+
+
+@pytest.mark.parametrize("n,v,dtype,tiles", _XENT)
+def test_softmax_xent_fused(monkeypatch, n, v, dtype, tiles):
+    """Loss and dlogits against `jax.nn.log_softmax`'s, in float32 on the
+    same (rounded) logits."""
+    if tiles:
+        monkeypatch.setattr(xent_mod, "_BLOCK_V", tiles[0])
+        monkeypatch.setattr(xent_mod, "_BLOCK_N", tiles[1])
     rng = np.random.RandomState(3)
-    logits = jnp.asarray(rng.randn(n, v).astype(np.float32))
+    logits = jnp.asarray(rng.randn(n, v).astype(np.float32) * 3).astype(dtype)
     labels = jnp.asarray(rng.randint(0, v, n).astype(np.int32))
+    w = jnp.asarray(rng.randn(n).astype(np.float32))
+
+    def ref(x):
+        return -jax.nn.log_softmax(x.astype(jnp.float32))[jnp.arange(n), labels]
+
     loss = softmax_xent_fused(logits, labels, True)
-    ref = -jax.nn.log_softmax(logits)[jnp.arange(n), labels]
-    np.testing.assert_allclose(np.asarray(loss), np.asarray(ref),
+    assert loss.dtype == jnp.float32 and loss.shape == (n,)
+    np.testing.assert_allclose(np.asarray(loss), np.asarray(ref(logits)),
                                rtol=RTOL, atol=ATOL)
 
-    w = jnp.asarray(rng.randn(n).astype(np.float32))
-    gx = jax.grad(lambda l: (softmax_xent_fused(l, labels, True) * w).sum())(logits)
-    gr = jax.grad(lambda l: ((-jax.nn.log_softmax(l)[jnp.arange(n), labels]) * w).sum())(logits)
-    np.testing.assert_allclose(np.asarray(gx), np.asarray(gr),
-                               rtol=RTOL, atol=ATOL)
+    gx = jax.grad(lambda x: (softmax_xent_fused(x, labels, True) * w).sum())(logits)
+    gr = jax.grad(lambda x: (ref(x) * w).sum())(logits)
+    assert gx.dtype == logits.dtype and gx.shape == (n, v)
+    # a bfloat16 gradient is the float32 one rounded once
+    rtol = 2 ** -7 if dtype == "bfloat16" else RTOL
+    np.testing.assert_allclose(np.asarray(gx, np.float32),
+                               np.asarray(gr, np.float32),
+                               rtol=rtol, atol=ATOL)
 
 
 def test_op_dispatch_interpret(monkeypatch):

@@ -1,4 +1,6 @@
 """Transformer layers + BERT model family tests (BASELINE config #3)."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -125,6 +127,31 @@ def test_bert_mlm_nsp_training_step():
         trainer.step(4)
         losses.append(float(loss.asnumpy()))
     assert losses[-1] < losses[0], losses
+
+
+@pytest.mark.parametrize("shape", [(3, 8, 32), (24, 32)],
+                         ids=["sequence", "gathered_positions"])
+@pytest.mark.parametrize("hybridize", [False, True])
+def test_mlm_head_keeps_its_inputs_leading_axes(shape, hybridize):
+    """The vocabulary projection runs on rows (a 2-D product); the logits
+    come back in the input's leading axes, equal to the plain composition."""
+    rng = np.random.RandomState(5)
+    head = BERTMLMHead(50, 32)
+    head.initialize(init=mx.initializer.Normal(0.1))
+    if hybridize:
+        head.hybridize()
+    x = rng.randn(*shape).astype(np.float32)
+    out = head(mx.nd.array(x)).asnumpy()
+    assert out.shape == shape[:-1] + (50,)
+
+    p = {k.split("_", 1)[1]: v.data().asnumpy()
+         for k, v in head.collect_params().items()}
+    h = x @ p["transform_weight"].T + p["transform_bias"]
+    h = 0.5 * h * (1 + np.vectorize(math.erf)(h / np.sqrt(2)))
+    h = (h - h.mean(-1, keepdims=True)) / np.sqrt(h.var(-1, keepdims=True) + 1e-12)
+    h = h * p["ln_gamma"] + p["ln_beta"]
+    want = h @ p["decoder_weight"].T + p["decoder_bias"]
+    np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
 
 
 def test_bert_padding_mask_isolates_padding():
