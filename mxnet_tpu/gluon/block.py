@@ -449,10 +449,13 @@ class HybridBlock(Block):
         the checkpoint, kept, and a value read by two blocks gets both
         cotangents). Under ``remat_rows`` EVERY array input and output
         must lead with the batch axis (so (B, heads, S, d), not (heads,
-        B, S, d)); where one input does not, or N does not divide the
-        batch, the rows are not split: the block runs once over the
-        whole batch, correct and with the whole batch's rebuilt
-        activations live."""
+        B, S, d)), but for an output WITHOUT axes: a scalar second output
+        (a layer's own loss term, summed over its rows) is summed over
+        the groups, so the block returns the batch's sum whether or not
+        the rows were split. Where one input does not lead with the batch
+        axis, or N does not divide the batch, the rows are not split: the
+        block runs once over the whole batch, correct and with the whole
+        batch's rebuilt activations live."""
         prev = self._flags
         if remat is None:
             remat = prev.get("remat", False)
@@ -594,7 +597,11 @@ class HybridBlock(Block):
             outs, auxs = jax.lax.map(
                 lambda kx: ckpt(kx[0], list(kx[1]), p_datas),
                 (jax.random.split(key, n), groups))
-            out_datas = tuple(o.reshape((batch,) + o.shape[2:]) for o in outs)
+            # an output without axes (a layer's scalar loss term) is the sum
+            # over the groups; the others lie side by side
+            out_datas = tuple(o.sum(0).astype(o.dtype) if o.ndim == 1
+                              else o.reshape((batch,) + o.shape[2:])
+                              for o in outs)
             aux_datas = tuple(a.sum(0).astype(a.dtype) if inc else a[-1]
                               for a, (_, inc) in zip(auxs, box["aux_params"]))
         else:
@@ -655,7 +662,8 @@ class HybridBlock(Block):
                     # the flash kernels' tile tallies of this trace
                     now = _profiler.counters(device=False)
                     build.set(**{k: now[k] - tiles[k] for k in
-                                 ("flash_tiles", "flash_tiles_live")})
+                                 ("flash_tiles", "flash_tiles_live",
+                                  "dsa_layers")})
                 _profiler.count("remat_kept", kept[0])
                 _profiler.count("remat_kept_bytes", kept[1])
                 self._cached_graph[key] = entry

@@ -55,6 +55,22 @@ class _LMHead(HybridBlock):
             F.reshape(labels, shape=(-1,)))
 
 
+def chunked_token_loss(F, head, x, labels, chunks):
+    """The summed token loss of ``head(x, labels)`` over ``chunks`` stretches
+    of the sequence, one after the other (with the head marked for remat the
+    logits of one stretch are live, not the batch's)."""
+    seq = x.shape[1]
+    if seq % chunks:
+        raise ValueError(f"loss_chunks {chunks} does not divide the length {seq}")
+    loss = None
+    for i in range(chunks):
+        lo, hi = i * seq // chunks, (i + 1) * seq // chunks
+        part = head(F.slice_axis(x, axis=1, begin=lo, end=hi),
+                    F.slice_axis(labels, axis=1, begin=lo, end=hi))
+        loss = part if loss is None else loss + part
+    return loss
+
+
 class KimiLinearLayer(HybridBlock):
     """One pre-norm residual layer; ``index`` is the published 1-based one."""
 
@@ -133,17 +149,7 @@ class KimiLinearModel(HybridBlock):
         x = self.final_norm(x)
         if labels is None:
             return self.lm_head(x)
-        n = self._loss_chunks
-        seq = ids.shape[1]
-        if seq % n:
-            raise ValueError(f"loss_chunks {n} does not divide the length {seq}")
-        loss = None
-        for i in range(n):
-            lo, hi = i * seq // n, (i + 1) * seq // n
-            part = self.lm_head(F.slice_axis(x, axis=1, begin=lo, end=hi),
-                                F.slice_axis(labels, axis=1, begin=lo, end=hi))
-            loss = part if loss is None else loss + part
-        return loss
+        return chunked_token_loss(F, self.lm_head, x, labels, self._loss_chunks)
 
 
 def kimi_linear(config, **kwargs):

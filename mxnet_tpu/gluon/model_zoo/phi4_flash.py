@@ -36,6 +36,7 @@ from ..block import HybridBlock
 from ..nn import Embedding, LayerNorm
 from ..nn.decoder import DiffAttention, GatedMemoryUnit, GatedMLP, MambaMixer
 from ..nn.transformer import remat_per_layer
+from .kimi_linear import chunked_token_loss
 
 __all__ = ["Phi4FlashModel", "Phi4FlashLayer", "phi4_flash", "layer_kind"]
 
@@ -194,17 +195,7 @@ class Phi4FlashModel(HybridBlock):
         x = self.final_norm(x)
         if labels is None:
             return self.lm_head(x)
-        n = self._loss_chunks
-        seq = ids.shape[1]
-        if seq % n:
-            raise ValueError(f"loss_chunks {n} does not divide the length {seq}")
-        loss = None
-        for i in range(n):
-            lo, hi = i * seq // n, (i + 1) * seq // n
-            part = self.lm_head(F.slice_axis(x, axis=1, begin=lo, end=hi),
-                                F.slice_axis(labels, axis=1, begin=lo, end=hi))
-            loss = part if loss is None else loss + part
-        return loss
+        return chunked_token_loss(F, self.lm_head, x, labels, self._loss_chunks)
 
 
 def phi4_flash(config, **kwargs):

@@ -10,7 +10,7 @@ from .transformer import (
 )
 from .decoder import (
     RMSNorm, GatedMLP, CausalConv1D, KDAMixer, MLAMixer, HeldExperts,
-    MambaMixer, DiffAttention, GatedMemoryUnit,
+    MambaMixer, DiffAttention, GatedMemoryUnit, SparseGQAttention,
 )
 from .conv_layers import (
     Conv1D, Conv2D, Conv3D, Conv1DTranspose, Conv2DTranspose, Conv3DTranspose,

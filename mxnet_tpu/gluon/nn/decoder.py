@@ -7,7 +7,9 @@ three that SambaY (Phi-4-mini-flash, arXiv:2507.06607) is made of: a Mamba
 selective-scan mixer, differential attention (arXiv:2410.05258; self,
 windowed or full, or cross to another layer's keys and values) with grouped
 key/value heads, and a Gated Memory Unit that reuses another layer's scan
-output.
+output; and grouped-query attention with rotary positions over the keys a
+learned indexer selects (DeepSeek-V3.2-Exp's sparse attention, as
+Keye-VL-2.0's language model has it).
 
 No upstream-gluon analog. Layout (batch, seq, units); pre-norm residual
 wiring is the model's (gluon/model_zoo/kimi_linear.py). Every block is a
@@ -26,12 +28,24 @@ from ..block import HybridBlock, defer_aux_update
 from ... import initializer as _init
 
 __all__ = ["RMSNorm", "GatedMLP", "CausalConv1D", "KDAMixer", "MLAMixer",
-           "HeldExperts", "MambaMixer", "DiffAttention", "GatedMemoryUnit"]
+           "HeldExperts", "MambaMixer", "DiffAttention", "GatedMemoryUnit",
+           "SparseGQAttention"]
 
 
 def _linear(units, in_units, dtype, init, prefix):
     return Dense(units, flatten=False, use_bias=False, dtype=dtype,
                  weight_initializer=init, in_units=in_units, prefix=prefix)
+
+
+def _cast_keeping(block, dtype, kept):
+    """``block.cast(dtype)`` that leaves the parameters ``kept`` as they are
+    (float32 statistics and device tallies)."""
+    for child in block._children.values():
+        child.cast(dtype)
+    for _, param in block.params.items():
+        if not any(param is k for k in kept):
+            param.cast(dtype)
+    block._cached_graph = {}
 
 
 class RMSNorm(HybridBlock):
@@ -175,9 +189,13 @@ class HeldExperts(HybridBlock):
     """A routed expert layer as ONE chip of an expert-parallel deployment
     computes it: it is told which experts it holds.
 
-    The router is as wide as the model (``num_experts``, sigmoid scores in
-    float32, top ``top_k`` of score + bias, chosen scores normalised and
-    scaled). ``experts_held = (lo, hi)`` names the contiguous range of
+    The router is as wide as the model (``num_experts``, scores in float32,
+    top ``top_k``, chosen scores normalised and scaled). Two scores:
+    ``score="sigmoid"`` (each expert's own sigmoid; the top ``top_k`` of
+    score + a selection bias, ``router_running_bias``: the DeepSeek-V3
+    family's) and ``score="softmax"`` (a softmax over all the experts, the
+    top ``top_k`` of it, no bias and no such parameter: the Qwen3-MoE
+    family's). ``experts_held = (lo, hi)`` names the contiguous range of
     experts whose weights live here. The layer computes the chosen terms
     whose expert is held, adds the shared expert, and returns that PARTIAL
     sum on purpose: what the other chips' experts add is their work, and
@@ -185,8 +203,9 @@ class HeldExperts(HybridBlock):
     ``experts_held=None`` every expert is held and the sum is whole.
 
     Dropless at static shapes (``F.moe_experts_held``): a sorted row buffer
-    of as many rows as tokens, and a dense masked branch inside the same
-    program for a step that needs more. ``running_slots``
+    of as many rows as tokens, or twice the balanced load where that is more,
+    and a dense masked branch inside the same program for a step that needs
+    more. ``running_slots``
     (hi - lo + 1,) counts on the device, without a host read, the slots each
     held expert was sent and, last, the slots the branch taken left out (a
     check of the routing tables: 0);
@@ -196,23 +215,25 @@ class HeldExperts(HybridBlock):
 
     def __init__(self, units, hidden_size, num_experts, top_k, experts_held=None,
                  num_shared_experts=1, routed_scaling_factor=1.0,
-                 renormalize=True, dtype="float32",
+                 renormalize=True, score="sigmoid", dtype="float32",
                  weight_initializer=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        if score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score {score!r} is neither sigmoid nor softmax")
         lo, hi = experts_held if experts_held is not None else (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
             raise ValueError(f"experts_held {experts_held!r} is no range of "
                              f"{num_experts} experts")
         self._lo, self._held = lo, hi - lo
         self._kw = dict(top_k=top_k, routed_scaling_factor=routed_scaling_factor,
-                        renormalize=renormalize, first_held=lo)
+                        renormalize=renormalize, first_held=lo, score=score)
         init = weight_initializer
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(num_experts, units), dtype=dtype, init=init)
             self.router_running_bias = self.params.get(
                 "router_running_bias", shape=(num_experts,), init="zeros",
-                grad_req="null")
+                grad_req="null") if score == "sigmoid" else None
             self.running_slots = self.params.get(
                 "running_slots", shape=(self._held + 1,), init="zeros",
                 grad_req="null")
@@ -229,13 +250,7 @@ class HeldExperts(HybridBlock):
         profiler.register_device_counters(self)
 
     def cast(self, dtype):
-        kept = (self.router_running_bias, self.running_slots)
-        for child in self._children.values():
-            child.cast(dtype)
-        for _, param in self.params.items():
-            if param not in kept:
-                param.cast(dtype)
-        self._cached_graph = {}
+        _cast_keeping(self, dtype, (self.router_running_bias, self.running_slots))
 
     def device_counters(self):
         """{counter: number} from ``running_slots`` (a host read: for the
@@ -248,12 +263,14 @@ class HeldExperts(HybridBlock):
                 "moe_dropped": float(tally[-1]),
                 "moe_slots/" + self.name: [float(v) for v in tally[:-1]]}
 
-    def hybrid_forward(self, F, x, router_weight, router_running_bias,
-                       running_slots, experts_gate_up_weight, experts_down_weight):
+    def hybrid_forward(self, F, x, router_weight, running_slots,
+                       experts_gate_up_weight, experts_down_weight,
+                       router_running_bias=None):
         flat = F.reshape(x, shape=(-3, 0))                     # (B*S, D)
+        bias = () if router_running_bias is None else (router_running_bias,)
         routed, seen = F.moe_experts_held(
-            flat, router_weight, router_running_bias, experts_gate_up_weight,
-            experts_down_weight, **self._kw)
+            flat, router_weight, experts_gate_up_weight, experts_down_weight,
+            *bias, **self._kw)
         defer_aux_update(self.running_slots, seen, increment=True)
         out = F.reshape_like(routed, x)
         if self.shared is not None:
@@ -412,3 +429,110 @@ class DiffAttention(HybridBlock):
             subln_gamma, lambda_init=self._lambda_init, eps=self._eps)
         out = self.o_proj(_merge_heads(F, out))
         return out if self._cross else (out, k, v)
+
+
+class SparseGQAttention(HybridBlock):
+    """Grouped-query attention with rotary positions over the keys a learned
+    indexer selects (DeepSeek-V3.2-Exp's sparse attention, sparse stage).
+
+    Main attention: q = W_q x (``num_heads`` of ``head_dim``), k = W_k x, v =
+    W_v x (``num_kv_heads``; query head h reads key/value head h // group); q
+    and k RMS-normalised a head (one gain of ``head_dim`` each), then rotated
+    (``F.rope``: rotate-half, ``rope_theta``, three position streams over
+    ``mrope_section``). Indexer, on ``stop_gradient(x)``: q_i = W_qi x
+    (``index_heads`` of ``index_dim``), k_i = LayerNorm(W_ki x) (one key head
+    for all), w = W_w x, q_i and k_i rotated over all their columns (the
+    sections halved with the width); I[t, s] = (heads * dim)^-1/2 sum_j w[t,
+    j] ReLU(q_i[t, j] . k_i[s]) in float32. Query t keeps its min(``top_k``,
+    t + 1) best causal keys (``F.dsa_topk_mask``); the main attention runs
+    over the kept pairs (``F.dsa_attention``: the flash kernel with the
+    mask as an operand), then W_o.
+
+    ``positions`` (B, 3, S). Returns ``(output, index_loss)``: the scalar
+    sum over the kept pairs of p_bar (log p_bar - log softmax_kept(I)), p_bar
+    the detached head mean of the main attention's probabilities. The two
+    losses do not meet: the indexer reads a detached input, so the model's
+    loss gives its parameters nothing, and its own loss reaches no other.
+    ``running_pairs`` (2,) counts on the device [pairs kept, causal pairs]
+    (``profiler.counters()``: ``dsa_pairs_selected``, ``dsa_pairs_causal``)."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim, index_heads,
+                 index_dim, top_k, rope_theta=10000.0, mrope_section=None,
+                 epsilon=1e-6, dtype="float32", weight_initializer=None,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_kv_heads} key/value heads do not divide "
+                             f"{num_heads} query heads")
+        sections = tuple(mrope_section or (head_dim // 2, 0, 0))
+        shrink = head_dim // index_dim
+        if sum(sections) != head_dim // 2 or head_dim % index_dim \
+                or any(n % shrink for n in sections):
+            raise ValueError(f"mrope_section {sections} does not split "
+                             f"{head_dim // 2} frequencies, or not {index_dim // 2}")
+        self._h, self._hk, self._hi = num_heads, num_kv_heads, index_heads
+        self._top_k, self._eps = top_k, epsilon
+        self._rope = dict(theta=rope_theta, sections=sections)
+        self._index_rope = dict(theta=rope_theta,
+                                sections=tuple(n // shrink for n in sections))
+        init = weight_initializer
+        with self.name_scope():
+            self.q_proj = _linear(num_heads * head_dim, units, dtype, init, "q_")
+            self.k_proj = _linear(num_kv_heads * head_dim, units, dtype, init, "k_")
+            self.v_proj = _linear(num_kv_heads * head_dim, units, dtype, init, "v_")
+            self.o_proj = _linear(units, num_heads * head_dim, dtype, init, "o_")
+            self.iq_proj = _linear(index_heads * index_dim, units, dtype, init,
+                                   "index_q_")
+            self.ik_proj = _linear(index_dim, units, dtype, init, "index_k_")
+            self.iw_proj = _linear(index_heads, units, dtype, init, "index_w_")
+            self.q_norm_gamma = self.params.get("q_norm_gamma", shape=(head_dim,),
+                                                init="ones")
+            self.k_norm_gamma = self.params.get("k_norm_gamma", shape=(head_dim,),
+                                                init="ones")
+            self.index_k_norm_gamma = self.params.get(
+                "index_k_norm_gamma", shape=(index_dim,), init="ones")
+            self.index_k_norm_beta = self.params.get(
+                "index_k_norm_beta", shape=(index_dim,), init="zeros")
+            self.running_pairs = self.params.get(
+                "running_pairs", shape=(2,), init="zeros", grad_req="null")
+        from ... import profiler
+        profiler.register_device_counters(self)
+
+    def cast(self, dtype):
+        _cast_keeping(self, dtype, (self.running_pairs,))
+
+    def device_counters(self):
+        """{counter: number} from ``running_pairs`` (a host read: for the
+        operator's poll, never inside a step)."""
+        if self.running_pairs._data is None:
+            return {}
+        tally = sum(a.asnumpy().astype("float64")
+                    for a in self.running_pairs.list_data())
+        return {"dsa_pairs_selected": float(tally[0]),
+                "dsa_pairs_causal": float(tally[1])}
+
+    def hybrid_forward(self, F, x, positions, q_norm_gamma, k_norm_gamma,
+                       index_k_norm_gamma, index_k_norm_beta, running_pairs):
+        from ... import profiler
+        profiler.count("dsa_layers")      # trace time: flat across steps
+        q = F.RMSNorm(_split_heads(F, self.q_proj(x), self._h), q_norm_gamma,
+                      eps=self._eps)
+        k = F.RMSNorm(_split_heads(F, self.k_proj(x), self._hk), k_norm_gamma,
+                      eps=self._eps)
+        v = _split_heads(F, self.v_proj(x), self._hk)
+        q = F.rope(q, positions, **self._rope)
+        k = F.rope(k, positions, **self._rope)
+        # the indexer: nothing of the model's loss reaches it, and nothing of
+        # its loss the model
+        xi = F.stop_gradient(x)
+        qi = F.rope(_split_heads(F, self.iq_proj(xi), self._hi), positions,
+                    **self._index_rope)
+        ki = F.rope(F.LayerNorm(self.ik_proj(xi), index_k_norm_gamma,
+                                index_k_norm_beta, eps=self._eps),
+                    positions, **self._index_rope)
+        scores = F.dsa_index_scores(qi, ki, self.iw_proj(xi))
+        mask, tally = F.dsa_topk_mask(scores, top_k=self._top_k)
+        defer_aux_update(self.running_pairs, tally, increment=True)
+        out, p_bar = F.dsa_attention(q, k, v, mask)
+        loss = F.dsa_index_loss(scores, mask, p_bar)
+        return self.o_proj(_merge_heads(F, out)), loss

@@ -459,7 +459,7 @@ def causal_mask_scores(scores):
 @register_op("flash_attention")
 def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
                        causal=False, sm_scale=None, window=None,
-                       name_scope=None):
+                       name_scope=None, pair_mask=None):
     """softmax(Q K^T * scale) V over (B, H, S, D) inputs.
 
     Pallas flash kernel on TPU (O(S) memory); jnp fallback elsewhere.
@@ -490,15 +490,26 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
     ``name_scope``: a ``jax.named_scope`` for the call, forward and
     backward, so that a device trace can tell one kind of attention layer
     from another (``mxtpu_swa``, ``mxtpu_yoco``).
+    ``pair_mask`` (B, Sq, Skv) int8, an ARRAY (data, not a static argument;
+    no gradient): query t sees key s only where it is nonzero, besides what
+    ``causal`` allows; one mask for all heads. The kernel reads it a tile at
+    a time and skips the tiles it leaves empty; it goes with neither
+    ``valid_len``, ``segment_ids`` nor ``window``.
     """
     if name_scope is not None:
         with jax.named_scope(name_scope):
             return flash_attention_op(query, key, value, valid_len,
-                                      segment_ids, causal, sm_scale, window)
+                                      segment_ids, causal, sm_scale, window,
+                                      None, pair_mask)
     from ..ops import pallas as _pallas
 
     if window is not None and not causal:
         raise ValueError("flash_attention: window needs causal=True")
+    if pair_mask is not None:
+        if valid_len is not None or segment_ids is not None or window is not None:
+            raise ValueError("flash_attention: pair_mask goes with neither "
+                             "valid_len, segment_ids nor window")
+        pair_mask = pair_mask.astype(jnp.int8)
 
     if valid_len is not None:
         valid_len = valid_len.astype(jnp.int32).reshape(-1)
@@ -522,7 +533,8 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
         out = _pallas.flash_attention(query, key, value, sm_scale,
                                       bool(causal), q_off, None, valid_len,
                                       segment_ids,
-                                      None if window is None else int(window))
+                                      None if window is None else int(window),
+                                      pair_mask)
         return out[..., :dv]
     group = query.shape[1] // key.shape[1]
     if group > 1:
@@ -544,6 +556,9 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
             cm = jnp.logical_and(cm, jnp.triu(jnp.ones((sq, sk), bool),
                                               k=sk - sq - int(window) + 1))
         mask = cm if mask is None else jnp.logical_and(mask, cm)
+    if pair_mask is not None:
+        pm = pair_mask[:, None] != 0
+        mask = pm if mask is None else jnp.logical_and(mask, pm)
     if mask is not None:
         p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
         # fully-masked rows: emit zeros, matching the Pallas kernel's
@@ -1088,15 +1103,17 @@ def kda_chunked_op(query, key, value, log_decay, beta, chunk_size=64,
 
 
 @register_op("moe_experts_held")
-def moe_experts_held(data, router_weight, score_bias, gate_up, down,
+def moe_experts_held(data, router_weight, gate_up, down, score_bias=None,
                      top_k=8, routed_scaling_factor=1.0, renormalize=True,
-                     first_held=0):
+                     first_held=0, score="sigmoid"):
     """The part of a routed expert layer that the experts held here give
     (ops/pallas/moe.py): data (T, D); router_weight (E, D) over ALL experts,
-    sigmoid scores in float32, the top ``top_k`` of score + score_bias;
+    ``score`` (``sigmoid`` of each, or a ``softmax`` over them) in float32,
+    the top ``top_k`` of score + score_bias (None: no bias);
     gate_up (E_held, 2F, D) and down (E_held, D, F) of the experts
     [first_held, first_held + E_held). Dropless at static shapes: a row
-    buffer of T rows (+ a tile an expert), and the
+    buffer of T rows, or of twice the balanced load T * top_k * E_held / E
+    where that is more (+ a tile an expert), and the
     dense branch of one ``lax.cond`` for a step that needs more. Returns
     (partial sum (T, D), [slots per held expert..., unplaced slots] float32
     (E_held + 1,): what the layer adds to its running count)."""
@@ -1104,11 +1121,139 @@ def moe_experts_held(data, router_weight, score_bias, gate_up, down,
     from ..ops.pallas import moe as _moe
 
     ids, weights = _moe.route(data, router_weight, score_bias, int(top_k),
-                              float(routed_scaling_factor), bool(renormalize))
+                              float(routed_scaling_factor), bool(renormalize),
+                              score)
     use_kernel = (_pallas.pallas_ok_for(data)
                   and data.dtype in (jnp.float32, jnp.bfloat16))
+    tokens, held = data.shape[0], gate_up.shape[0]
+    balanced = tokens * int(top_k) * held // router_weight.shape[0]
     y, counts, unplaced = _moe.experts_held(
         data, ids, weights, gate_up, down, int(first_held),
-        use_kernel=use_kernel)
+        use_kernel=use_kernel, capacity_rows=max(tokens, 2 * balanced))
     seen = jnp.concatenate([counts, unplaced[None]]).astype(jnp.float32)
     return y, lax.stop_gradient(seen)
+
+
+@register_op("rope")
+@_lean
+def rope(data, positions, theta=10000.0, sections=None):
+    """Rotary positions, rotate-half, in float32: data (B, H, S, d) or (B, S,
+    d); the pair (x[i], x[i + d/2]) of frequency i in [0, d/2) turns by
+    theta^(-2i/d) * p(t). ``positions`` (B, S), or (B, n, S) for n position
+    streams with ``sections`` (n counts that add up to d/2): frequency i
+    reads the stream whose section holds i, sections side by side in order
+    (the multimodal form; equal streams give plain rotary positions)."""
+    half = data.shape[-1] // 2
+    freq = jnp.asarray(float(theta) ** (-np.arange(half) / half), jnp.float32)
+    pos = positions.astype(jnp.float32)
+    if pos.ndim == 3:
+        if sections is None or sum(sections) != half \
+                or len(sections) != pos.shape[1]:
+            raise ValueError(f"rope: sections {sections!r} do not split the "
+                             f"{half} frequencies over {pos.shape[1]} streams")
+        stream = np.repeat(np.arange(len(sections)), sections)
+        pos = jnp.moveaxis(pos[:, stream, :], 1, 2)            # (B, S, d/2)
+    else:
+        pos = pos[..., None]
+    angle = pos * freq
+    if data.ndim == 4:
+        angle = angle[:, None]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x = data.astype(jnp.float32)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(data.dtype)
+
+
+# ----------------------------------------------------------------------
+# Sparse attention with a learned indexer (ops/pallas/dsa.py): the indexer's
+# scores, the selection, the main attention over the selection with the head
+# mean of its probabilities, and the indexer's loss. Each runs under the name
+# scope a device trace finds it by, forward and backward.
+# ----------------------------------------------------------------------
+@register_op("dsa_index_scores")
+def dsa_index_scores(query, key, weights):
+    """The indexer's scores I[t, s] = (H_i d_i)^-1/2 sum_j weights[t, j]
+    ReLU(query[t, j] . key[s]) in float32: query (B, H_i, S, d_i), key (B, S,
+    d_i) one head for all, weights (B, S, H_i); (B, S, S), -inf above the
+    diagonal. On the chip the Pallas kernels ``mxtpu_dsa_index_fwd`` and, for
+    the backward, ``mxtpu_dsa_index_bwd_dq`` / ``_dk`` (the heads' products
+    never leave VMEM); everywhere else query-row-blocked XLA. Scope
+    ``mxtpu_dsa_index``."""
+    from ..ops import pallas as _pallas
+    from ..ops.pallas import dsa as _dsa
+
+    use_kernel = (_pallas.pallas_ok_for(query)
+                  and query.dtype in (jnp.float32, jnp.bfloat16))
+    with jax.named_scope("mxtpu_dsa_index"):
+        return _dsa.index_scores(query, key, weights, use_kernel=use_kernel)
+
+
+@register_op("dsa_topk_mask")
+def dsa_topk_mask(scores, top_k=2048):
+    """Each query's min(top_k, t + 1) best causal keys by ``scores`` (B, S,
+    S), the lower index first among equals: an int8 (B, S, S) mask, and
+    [pairs kept, causal pairs] float32 (2,) for the layer's running tally.
+    No gradient. Scope ``mxtpu_dsa_topk``."""
+    from ..ops.pallas import dsa as _dsa
+
+    b, s, _ = scores.shape
+    with jax.named_scope("mxtpu_dsa_topk"):
+        mask = _dsa.topk_mask(scores, int(top_k))
+        tally = jnp.stack([jnp.sum(mask, dtype=jnp.int32).astype(jnp.float32),
+                           jnp.float32(b * (s * (s + 1) // 2))])
+    return mask, tally
+
+
+@register_op("dsa_attention")
+def dsa_attention(query, key, value, pair_mask):
+    """Causal attention over the pairs ``pair_mask`` (B, S, S) int8 keeps,
+    grouped key/value heads as ``flash_attention``: the output (B, H, S, d)
+    and p_bar (B, S, S) float32, the head mean of the probabilities on the
+    kept pairs (no gradient through it). On the chip the flash kernel with
+    the mask as an operand; p_bar is query-row-blocked XLA on every backend;
+    scope ``mxtpu_dsa_attn``."""
+    from ..ops import pallas as _pallas
+    from ..ops.pallas import dsa as _dsa
+
+    scale = 1.0 / (query.shape[-1] ** 0.5)
+    pair_mask = pair_mask.astype(jnp.int8)
+    use_kernel = (_pallas.pallas_ok_for(query)
+                  and query.dtype in (jnp.float32, jnp.bfloat16))
+    with jax.named_scope("mxtpu_dsa_attn"):
+        if use_kernel:
+            out, lse = _pallas.flash_attention_with_lse(
+                query, key, value, scale, True, 0, None, None, None, None,
+                pair_mask)
+        else:
+            out, lse = _masked_attention_with_lse(query, key, value, pair_mask,
+                                                  scale)
+        p_bar = _dsa.head_mean_probs(query, key, lse, pair_mask,
+                                     sm_scale=scale)
+    return out, p_bar
+
+
+def _masked_attention_with_lse(query, key, value, pair_mask, scale):
+    """The jnp twin of the masked flash call: (out, lse (B, H, S))."""
+    group = query.shape[1] // key.shape[1]
+    key, value = (jnp.repeat(t, group, axis=1) for t in (key, value))
+    s = jnp.einsum("bhqd,bhkd->bhqk", (query * scale).astype(query.dtype), key,
+                   preferred_element_type=jnp.float32)
+    seen = jnp.logical_and(pair_mask[:, None] != 0,
+                           jnp.tril(jnp.ones(s.shape[-2:], bool)))
+    s = jnp.where(seen, s, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.where(seen, jnp.exp(s - lse[..., None]), 0.0)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, value.astype(jnp.float32))
+    return out.astype(query.dtype), lse
+
+
+@register_op("dsa_index_loss")
+def dsa_index_loss(scores, pair_mask, p_bar):
+    """The indexer's loss, sum over the kept pairs of p_bar (log p_bar - log
+    softmax_kept(scores)): a float32 scalar (no axes) whose gradient to
+    ``scores`` is softmax_kept(scores) - p_bar. Scope ``mxtpu_dsa_index``."""
+    from ..ops.pallas import dsa as _dsa
+
+    with jax.named_scope("mxtpu_dsa_index"):
+        return _dsa.index_loss(scores, pair_mask, p_bar)

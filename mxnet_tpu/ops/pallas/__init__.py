@@ -23,6 +23,11 @@ we only drop to Pallas where XLA's own fusion genuinely loses:
 - ``moe`` — the experts a chip holds: dispatch tables at static shapes
   and a grouped matmul over the held experts' rows (``grouped_matmul``,
   ``experts_held``).
+- ``dsa`` — sparse attention's learned indexer beside the flash kernel
+  (which takes its selection as a pair mask): the heads' scores and the
+  head mean of the attention's probabilities as pair tiles resident in
+  VMEM over the heads; the selection and the indexer's loss in XLA
+  (imported as a module: ``ops.pallas.dsa``).
 
 Dispatch contract: every kernel here has a pure-jnp twin used when the
 backend is not TPU (tests run on the CPU mesh) or when

@@ -79,6 +79,24 @@ the B*H query rows; the dkv and fused backwards walk the B*Hk
 key/value rows and, inside each, the group's query heads one after the
 other, so that dk and dv are summed over the group in VMEM.
 
+A PAIR MASK THAT IS DATA (``pair_mask``, (B, Sq, Skv) int8, an operand and
+not a static argument): query t sees key s only where the mask is nonzero,
+besides what ``causal`` allows; one mask serves all the heads of a batch
+entry (sparse attention whose keys a learned indexer selects: the mask is
+computed from the data, a step at a time). Two operands: the mask itself,
+read a (block_q, block_k) tile at a time through the same grids (1 MB at
+512 x 2048, beside a tile's 0.5 GFLOP), and its TILE SUMMARY, (B, nq, nk)
+int32 in SMEM, nonzero where a tile has any live pair, from which
+``_block_visible`` skips a dead tile whole. Every arm honours both: the
+forward and the dq kernel walk (q rows, q tiles, kv tiles) and read tile
+(i, j) of batch entry row // H; the dkv and fused kernels walk (kv rows, kv
+tiles, the group's query heads x q tiles) and read tile (i, j) of batch
+entry row // Hk, the same tile for each of the group's heads. Inside a live
+tile the mask joins the causal one (``_pair_mask``'s ``smask``). It goes with
+neither a window, segments nor ``kv_lens``; with the operand absent the
+kernels trace what they traced before it existed
+(``tests/test_keye_vl2_blocks.py``).
+
 Trace-time tallies (`profiler.counters()`): every forward or backward
 call adds the tiles of its (q tiles x kv tiles) rectangle to
 ``flash_tiles`` and those its static masks (causal, window) leave live
@@ -153,6 +171,34 @@ def _seg_range(qrng_ref, krng_ref, i, j, n_heads):
             krng_ref[0, b, j], krng_ref[1, b, j])
 
 
+def _split_rest(rest, dynamic_seg, dynamic_mask):
+    """A kernel's trailing refs: (the four segment refs or Nones, the pair
+    mask's tile and tile summary or Nones, the outputs and scratch)."""
+    seg, pm = (None,) * 4, (None,) * 2
+    if dynamic_seg:
+        seg, rest = rest[:4], rest[4:]
+    if dynamic_mask:
+        pm, rest = rest[:2], rest[2:]
+    return seg, pm, rest
+
+
+def _tile_live(live_ref, i, j, rows):
+    """The pair mask's summary of tile (i, j) (an SMEM scalar, nonzero where
+    any pair of the tile is live); ``rows``: grid rows a batch entry. None
+    refs mean no pair mask."""
+    if live_ref is None:
+        return None
+    return live_ref[pl.program_id(0) // np.int32(rows), i, j]
+
+
+def _tile_mask(pm_ref, smask):
+    """The (block_q, block_k) boolean mask of a tile: the pair mask's where
+    there is one (it never goes with segments), else ``smask``."""
+    if pm_ref is None:
+        return smask
+    return pm_ref[0].astype(jnp.int32) != 0
+
+
 def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
                kvl=None, smask=None, window=None):
     """Validity mask for the (i, j) score block, or None when every
@@ -186,13 +232,14 @@ def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
 
 
 def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                   segrng=None, window=None):
+                   segrng=None, window=None, live=None):
     """Whether the (i, j) tile has ANY live score: causal skip, the
     window skip (the tile's last key is older than the first row's
     window), the per-example length skip (tiles starting at/after kvl
     are dead — the variable-length fast path's whole-tile saving), and
     the packed segment-range skip (disjoint id ranges cannot share a
-    segment, so cross-sequence tiles cost no MXU work)."""
+    segment, so cross-sequence tiles cost no MXU work), and the pair mask's
+    tile summary (``live``: 0 where the mask leaves no pair of the tile)."""
     q_last = (i + 1) * block_q - 1 + q_offset
     vis = jnp.logical_or(not causal, j * block_k <= q_last)
     if window is not None:
@@ -204,6 +251,8 @@ def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
         qmin, qmax, kmin, kmax = segrng
         vis = jnp.logical_and(vis, jnp.logical_and(qmin <= kmax,
                                                    kmin <= qmax))
+    if live is not None:
+        vis = jnp.logical_and(vis, live != 0)
     return vis
 
 
@@ -250,13 +299,10 @@ def _tally_tiles(rows, causal, window, q_offset, block_q, block_k, nq, nk):
 def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
                 sm_scale, causal, q_offset, kv_len, block_q, block_k,
                 precision, dynamic_kv, dynamic_seg, n_heads,
-                window=None, narrow=None):
-    if dynamic_seg:
-        (qseg_ref, kseg_ref, qrng_ref, krng_ref,
-         o_ref, lse_ref, acc_sc, m_sc, l_sc) = rest
-    else:
-        qseg_ref = kseg_ref = qrng_ref = krng_ref = None
-        o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
+                window=None, narrow=None, dynamic_mask=False):
+    ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
+     (o_ref, lse_ref, acc_sc, m_sc, l_sc)) = _split_rest(
+         rest, dynamic_seg, dynamic_mask)
     i, jj = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
@@ -271,11 +317,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
         l_sc[:] = jnp.zeros_like(l_sc)
 
     # skip: causal invisibility, a tile outside the window's band or past
-    # the example's kv length, or a packed tile whose segment-id ranges
-    # are disjoint
+    # the example's kv length, a packed tile whose segment-id ranges are
+    # disjoint, or one in which the pair mask leaves nothing
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
-                             window)
+                             window, _tile_live(live_ref, i, j, n_heads))
     if narrow:
         visible = jnp.logical_and(visible, j < narrow[1])
 
@@ -289,8 +335,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
-        smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
-            if dynamic_seg else None
+        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
+                           if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
                           kvl, smask, window)
         if mask is not None:
@@ -326,12 +372,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    kvl_ref, *rest,
                    sm_scale, causal, q_offset, kv_len, block_q, block_k,
                    precision, dynamic_kv, dynamic_seg, n_heads,
-                   window=None, narrow=None, group=1, steps=None):
-    if dynamic_seg:
-        qseg_ref, kseg_ref, qrng_ref, krng_ref, dq_ref, dq_sc = rest
-    else:
-        qseg_ref = kseg_ref = qrng_ref = krng_ref = None
-        dq_ref, dq_sc = rest
+                   window=None, narrow=None, group=1, steps=None,
+                   dynamic_mask=False):
+    ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
+     (dq_ref, dq_sc)) = _split_rest(rest, dynamic_seg, dynamic_mask)
     i, jj = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
@@ -344,7 +388,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
-                             window)
+                             window, _tile_live(live_ref, i, j, n_heads))
     if narrow:
         visible = jnp.logical_and(visible, j < narrow[1])
 
@@ -360,8 +404,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
-        smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
-            if dynamic_seg else None
+        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
+                           if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
                           kvl, smask, window)
         p = jnp.exp(s - lse) if mask is None \
@@ -394,14 +438,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     kvl_ref, *rest,
                     sm_scale, causal, q_offset, kv_len, block_q, block_k,
                     precision, dynamic_kv, dynamic_seg, n_heads,
-                    window=None, narrow=None, group=1, steps=None):
+                    window=None, narrow=None, group=1, steps=None,
+                    dynamic_mask=False):
     # grid: (B*Hk, nk, group * q steps) — q is the inner (sequential) axis
-    if dynamic_seg:
-        (qseg_ref, kseg_ref, qrng_ref, krng_ref,
-         dk_ref, dv_ref, dk_sc, dv_sc) = rest
-    else:
-        qseg_ref = kseg_ref = qrng_ref = krng_ref = None
-        dk_ref, dv_ref, dk_sc, dv_sc = rest
+    ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
+     (dk_ref, dv_ref, dk_sc, dv_sc)) = _split_rest(
+         rest, dynamic_seg, dynamic_mask)
     j, t = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
     # any query head of the row's batch entry: the lengths are per example
@@ -416,7 +458,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
-                             window)
+                             window,
+                             _tile_live(live_ref, i, j, n_heads // group))
     if narrow:
         visible = jnp.logical_and(visible, i < narrow[0])
 
@@ -432,8 +475,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
-        smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
-            if dynamic_seg else None
+        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
+                           if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
                           kvl, smask, window)
         p = jnp.exp(s - lse) if mask is None \
@@ -460,7 +503,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       kvl_ref, *rest,
                       sm_scale, causal, q_offset, kv_len, block_q, block_k,
                       precision, dynamic_kv, dynamic_seg, n_heads,
-                      window=None, narrow=None, group=1, steps=None):
+                      window=None, narrow=None, group=1, steps=None,
+                      dynamic_mask=False):
     """One-pass backward: dq, dk, dv from a SINGLE traversal of the
     (q block, k block) grid — the score matrix s and dp are computed
     once per pair instead of once in a dq kernel and again in a dkv
@@ -473,12 +517,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     prefetch pipeline) and the per-k-block partials are summed by XLA
     outside the kernel.
     """
-    if dynamic_seg:
-        (qseg_ref, kseg_ref, qrng_ref, krng_ref,
-         dq_ref, dk_ref, dv_ref, dk_sc, dv_sc) = rest
-    else:
-        qseg_ref = kseg_ref = qrng_ref = krng_ref = None
-        dq_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
+    ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
+     (dq_ref, dk_ref, dv_ref, dk_sc, dv_sc)) = _split_rest(
+         rest, dynamic_seg, dynamic_mask)
     j, t = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
     # any query head of the row's batch entry: the lengths are per example
@@ -493,7 +534,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
-                             window)
+                             window,
+                             _tile_live(live_ref, i, j, n_heads // group))
 
     @pl.when(visible)
     def _():
@@ -507,8 +549,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
-        smask = _segment_mask(qseg_ref, kseg_ref, block_k) \
-            if dynamic_seg else None
+        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
+                           if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
                           kvl, smask, window)
         p = jnp.exp(s - lse) if mask is None \
@@ -577,7 +619,8 @@ def _pick_blocks(sq, skv, window=None):
     return bq, bk
 
 
-def _check_heads(q, k, v, window, causal, segment_ids):
+def _check_heads(q, k, v, window, causal, segment_ids, pair_mask=None,
+                 kv_lens=None):
     """The group (query heads a key/value head) of a call, checked."""
     h, hk = q.shape[1], k.shape[1]
     if v.shape[1] != hk or h % hk:
@@ -589,6 +632,14 @@ def _check_heads(q, k, v, window, causal, segment_ids):
     if segment_ids is not None and (window is not None or h != hk):
         raise ValueError("segment_ids (packing) goes with neither a window "
                          "nor grouped key/value heads")
+    if pair_mask is not None:
+        if window is not None or segment_ids is not None or kv_lens is not None:
+            raise ValueError("pair_mask goes with neither a window, "
+                             "segment_ids nor kv_lens")
+        want = (q.shape[0], q.shape[2], k.shape[2])
+        if pair_mask.shape != want or pair_mask.dtype != jnp.int8:
+            raise ValueError(f"pair_mask must be int8 {want} (batch, queries, "
+                             f"keys), got {pair_mask.dtype} {pair_mask.shape}")
     return h // hk
 
 
@@ -679,17 +730,46 @@ def _seg_specs(block_q, block_k, n_heads, transposed_grid):
     ]
 
 
+def _prep_pair_mask(pair_mask, sq_p, skv_p, block_q, block_k):
+    """The pair mask's two operands: the (B, sq_p, skv_p) int8 mask (tile
+    padding dead) and its (B, nq, nk) int32 tile summary for SMEM, nonzero
+    where a tile has a live pair."""
+    b, sq, skv = pair_mask.shape
+    if (sq_p, skv_p) != (sq, skv):
+        pair_mask = _pad0(pair_mask, ((0, 0), (0, sq_p - sq), (0, skv_p - skv)))
+    tiles = pair_mask.reshape(b, sq_p // block_q, block_q,
+                              skv_p // block_k, block_k)
+    return [pair_mask, jnp.max(tiles, axis=(2, 4)).astype(jnp.int32)]
+
+
+def _mask_specs(block_q, block_k, rows, transposed_grid, group=1, steps=None):
+    """BlockSpecs of the pair mask's operands; ``rows``: grid rows a batch
+    entry. The (kv rows, kv tiles, q steps) grids (``transposed_grid``) run a
+    group's query heads one after the other, ``steps`` q tiles each."""
+    r32 = np.int32(rows)
+    if not transposed_grid:
+        tile = lambda b_, i, j: (b_ // r32, i, j)  # noqa: E731
+    elif group == 1:
+        tile = lambda b_, j, i: (b_ // r32, i, j)  # noqa: E731
+    else:
+        s32 = np.int32(steps)
+        tile = lambda b_, j, t: (b_ // r32, t % s32, j)  # noqa: E731
+    return [pl.BlockSpec((1, block_q, block_k), tile, memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM)]
+
+
 @x32
 def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, kv_lens=None,
-               segment_ids=None, window=None):
+               segment_ids=None, window=None, pair_mask=None):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     if segment_ids is not None and sq != skv:
         raise ValueError(
             f"segment_ids (packing) requires self-attention shapes, got "
             f"sq={sq} != skv={skv}")
-    group = _check_heads(q, k, v, window, causal, segment_ids)
+    group = _check_heads(q, k, v, window, causal, segment_ids, pair_mask,
+                         kv_lens)
     hk = h // group
     bq0, bk0 = _pick_blocks(sq, skv, window)
     block_q = block_q or bq0
@@ -722,7 +802,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         q_offset=q_offset, kv_len=skv, block_q=block_q, block_k=block_k,
         precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
-        dynamic_seg=dynamic_seg, n_heads=h, window=window, narrow=narrow)
+        dynamic_seg=dynamic_seg, n_heads=h, window=window, narrow=narrow,
+        dynamic_mask=pair_mask is not None)
     kv_map = _kv_map(group, window, narrow, q_offset, block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
@@ -736,6 +817,9 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
         in_specs += _seg_specs(block_q, block_k, h, transposed_grid=False)
         operands += list(_prep_segments(segment_ids, b, sq, skv,
                                         sq_p, skv_p, block_q, block_k))
+    if pair_mask is not None:
+        in_specs += _mask_specs(block_q, block_k, h, transposed_grid=False)
+        operands += _prep_pair_mask(pair_mask, sq_p, skv_p, block_q, block_k)
     o, lse = pl.pallas_call(
         kern,
         grid=(bh, nq, kv_steps),
@@ -766,7 +850,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
 @x32
 def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, dlse=None, kv_lens=None,
-               segment_ids=None, window=None):
+               segment_ids=None, window=None, pair_mask=None):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     hk = k.shape[1]        # the forward checked the heads
@@ -782,6 +866,8 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     seg_ops = None if segment_ids is None else list(
         _prep_segments(segment_ids, b, sq, skv, sq_p, skv_p,
                        block_q, block_k))
+    mask_ops = None if pair_mask is None else _prep_pair_mask(
+        pair_mask, sq_p, skv_p, block_q, block_k)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(bh, sq, 1)
@@ -814,7 +900,8 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
                   kv_len=skv, block_q=block_q, block_k=block_k,
                   precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
                   dynamic_seg=seg_ops is not None, n_heads=h,
-                  window=window, group=group)
+                  window=window, group=group,
+                  dynamic_mask=mask_ops is not None)
 
     # the fused pass writes nk f32 dq-partial copies to HBM; past nk=2
     # that memory/write cliff outweighs the recompute saving, so long
@@ -823,15 +910,17 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     if nk <= 2:
         return _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
                                 (b, h, sq, skv, d), nq, nk, common,
-                                interpret, k.dtype, v.dtype, q.dtype, group)
+                                interpret, k.dtype, v.dtype, q.dtype, group,
+                                mask_ops)
     return _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
                             (b, h, sq, skv, d), nq, nk, common,
-                            interpret, k.dtype, v.dtype, q.dtype, group)
+                            interpret, k.dtype, v.dtype, q.dtype, group,
+                            mask_ops)
 
 
 def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                      nq, nk, common, interpret, k_dtype, v_dtype, q_dtype,
-                     group=1):
+                     group=1, mask_ops=None):
     """Single-pass dq/dk/dv, taken where the kv grid has at most two
     blocks: ``bert_base.train_b64x512`` (S=512, nk=1) runs this kernel;
     ``kimi_linear_48b_a3b.train_8k``'s latent attention (S=8192 at the
@@ -866,6 +955,9 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     if seg_ops is not None:
         in_specs += _seg_specs(block_q, block_k, h, transposed_grid=True)
         operands += seg_ops
+    if mask_ops is not None:
+        in_specs += _mask_specs(block_q, block_k, h // group, True, group, nq)
+        operands += mask_ops
     dq_part, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, **common),
         grid=(bh // group, nk, group * nq),
@@ -904,7 +996,7 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
 
 def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                      nq, nk, common, interpret, k_dtype, v_dtype, q_dtype,
-                     group=1):
+                     group=1, mask_ops=None):
     b, h, sq, skv, d = dims
     bh = b * h
     block_q, block_k = common["block_q"], common["block_k"]
@@ -935,6 +1027,9 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     if seg_ops is not None:
         dq_specs += _seg_specs(block_q, block_k, h, transposed_grid=False)
         operands += seg_ops
+    if mask_ops is not None:
+        dq_specs += _mask_specs(block_q, block_k, h, transposed_grid=False)
+        operands += mask_ops
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(bh, nq, kv_steps),
@@ -961,6 +1056,9 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     ]
     if seg_ops is not None:
         dkv_specs += _seg_specs(block_q, block_k, h, transposed_grid=True)
+    if mask_ops is not None:
+        dkv_specs += _mask_specs(block_q, block_k, h // group, True, group,
+                                 q_steps)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         grid=(bh // group, nk, group * q_steps),
@@ -1011,14 +1109,15 @@ REMAT_KEEP = ("flash_out", "flash_lse")
 
 
 def _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret, kv_lens,
-               segment_ids, window=None):
+               segment_ids, window=None, pair_mask=None):
     """The forward that the primals and the VJPs' forward rules share:
     (out, lse) under their `REMAT_KEEP` names."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     o, lse = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
                         resolve_interpret(interpret), kv_lens=kv_lens,
-                        segment_ids=segment_ids, window=window)
+                        segment_ids=segment_ids, window=window,
+                        pair_mask=pair_mask)
     named = []
     for name, x in zip(REMAT_KEEP, (o, lse)):
         _profiler.note_named(name, x)   # trace time: `remat_kept`'s tally
@@ -1028,20 +1127,22 @@ def _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret, kv_lens,
 
 def _flash_vjp_bwd(sm_scale, causal, q_offset, interpret, window, res, do,
                    dlse=None):
-    q, k, v, o, lse, kv_lens, segment_ids = res
+    q, k, v, o, lse, kv_lens, segment_ids, pair_mask = res
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, sm_scale, bool(causal),
                             int(q_offset), resolve_interpret(interpret),
                             dlse=dlse, kv_lens=kv_lens,
-                            segment_ids=segment_ids, window=window)
-    return dq, dk, dv, _int_ct(kv_lens), _int_ct(segment_ids)
+                            segment_ids=segment_ids, window=window,
+                            pair_mask=pair_mask)
+    return (dq, dk, dv, _int_ct(kv_lens), _int_ct(segment_ids),
+            _int_ct(pair_mask))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9))
 def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
                              q_offset=0, interpret=None, kv_lens=None,
-                             segment_ids=None, window=None):
+                             segment_ids=None, window=None, pair_mask=None):
     """Flash attention returning (out, lse) — DIFFERENTIABLE in both
     outputs (the lse cotangent folds into the backward's delta term).
 
@@ -1050,19 +1151,20 @@ def flash_attention_with_lse(q, k, v, sm_scale=None, causal=False,
     the log-sum-exp combiner and lets gradients flow through both.
     ``kv_lens`` (B,) int32 masks keys at/after each example's length.
     ``segment_ids`` (B, S) int32 restricts attention to same-segment
-    pairs (sequence packing; see the module docstring). ``window`` and
-    grouped key/value heads as in :func:`flash_attention`.
+    pairs (sequence packing; see the module docstring). ``window``,
+    ``pair_mask`` and grouped key/value heads as in :func:`flash_attention`.
     """
     return _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                      kv_lens, segment_ids, window)
+                      kv_lens, segment_ids, window, pair_mask)
 
 
 def _flash_lse_vjp_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
-                       kv_lens=None, segment_ids=None, window=None):
+                       kv_lens=None, segment_ids=None, window=None,
+                       pair_mask=None):
     o, lse = _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                        kv_lens, segment_ids, window)
+                        kv_lens, segment_ids, window, pair_mask)
     # the primal output IS the named value: one kept array serves both
-    return (o, lse), (q, k, v, o, lse, kv_lens, segment_ids)
+    return (o, lse), (q, k, v, o, lse, kv_lens, segment_ids, pair_mask)
 
 
 def _flash_lse_vjp_bwd(sm_scale, causal, q_offset, interpret, window, res,
@@ -1077,7 +1179,7 @@ flash_attention_with_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9))
 def flash_attention(q, k, v, sm_scale=None, causal=False, q_offset=0,
                     interpret=None, kv_lens=None, segment_ids=None,
-                    window=None):
+                    window=None, pair_mask=None):
     """softmax(q k^T * scale [+causal/length/segment mask]) v,
     blockwise in VMEM. ``kv_lens`` (B,) int32 masks keys at/after each
     example's valid length (variable-length batches, e.g. BERT
@@ -1089,16 +1191,21 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, q_offset=0,
     ``window - 1`` keys before it; tiles outside the band are not walked.
     k and v may have fewer heads than q (q (B, H, S, D), k/v (B, Hk, S, D),
     Hk divides H): query head h reads key/value head h // (H / Hk), and
-    dk, dv come back with Hk heads, summed over each group."""
+    dk, dv come back with Hk heads, summed over each group.
+    ``pair_mask`` (B, Sq, Skv) int8, DATA and not a static argument: query t
+    sees key s only where ``pair_mask[b, t, s]`` is nonzero (and the other
+    masks allow it); one mask for all the heads of a batch entry. Tiles in
+    which it leaves nothing are skipped, forward and backward."""
     return _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                      kv_lens, segment_ids, window)[0]
+                      kv_lens, segment_ids, window, pair_mask)[0]
 
 
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
-                   kv_lens=None, segment_ids=None, window=None):
+                   kv_lens=None, segment_ids=None, window=None,
+                   pair_mask=None):
     o, lse = _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret,
-                        kv_lens, segment_ids, window)
-    return o, (q, k, v, o, lse, kv_lens, segment_ids)
+                        kv_lens, segment_ids, window, pair_mask)
+    return o, (q, k, v, o, lse, kv_lens, segment_ids, pair_mask)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

@@ -13,8 +13,11 @@ purpose: the result is this chip's partial sum.
 
 Dropless at static shapes. The row buffer holds as many rows as there are
 tokens (four times the load a balanced router sends here at 8 of 256
-experts held) plus one tile of padding an expert; the tiles past the rows
-in use are skipped by the kernels. The worst case, ``tokens x min(top_k,
+experts held), or twice the balanced load where that is more (16 of 128
+held at 8 a token is one slot a token: a buffer of one row a token would
+send every step whose router leans towards the held experts down the dense
+branch; PERF.md section 6, PR 33), plus one tile of padding an expert; the
+tiles past the rows in use are skipped by the kernels. The worst case, ``tokens x min(top_k,
 E_held)`` rows, is seven times that buffer at 8 a token: by count about
 2 GB more of temporaries a layer at 8,192 tokens x 2,304 (the gathered rows,
 their hidden rows, the float32 rows of the combine), which does not fit
@@ -235,15 +238,25 @@ def grouped_matmul(x, w, tables, tile=TILE, use_kernel=False, interpret=None):
 
 # ---- the layer's arithmetic ----------------------------------------------------------
 
-def route(x, router_weight, score_bias, top_k, scaling, renormalize=True):
-    """Sigmoid router in float32: scores over all experts, the top ``top_k``
-    of score + bias, and the chosen scores as weights (normalised over the
-    chosen, times ``scaling``). Returns (expert ids (T, k), weights (T, k))."""
+def route(x, router_weight, score_bias, top_k, scaling, renormalize=True,
+          score="sigmoid"):
+    """The router in float32: scores over all experts (``score``: each
+    expert's ``sigmoid``, or a ``softmax`` over them), the top ``top_k`` of
+    score + bias (``score_bias`` None: of the score), and the chosen scores
+    as weights (normalised over the chosen, times ``scaling``). Returns
+    (expert ids (T, k), weights (T, k))."""
     f32 = jnp.float32
     logits = jnp.matmul(x.astype(f32), router_weight.astype(f32).T,
                         precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, ids = lax.top_k(scores + score_bias.astype(f32)[None], top_k)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"route: score {score!r} is neither sigmoid nor softmax")
+    biased = scores if score_bias is None \
+        else scores + score_bias.astype(f32)[None]
+    _, ids = lax.top_k(biased, top_k)
     chosen = jnp.take_along_axis(scores, ids, axis=1)
     if renormalize:
         chosen = chosen / jnp.sum(chosen, axis=1, keepdims=True)
@@ -257,18 +270,19 @@ def _gated(h, width):
 
 @jax.named_scope("mxtpu_moe")
 def experts_held(x, ids, weights, gate_up, down, lo, use_kernel=False,
-                 interpret=None, tile=TILE):
+                 interpret=None, tile=TILE, capacity_rows=None):
     """The held experts' part of the layer: sum over the chosen slots whose
     expert is in [lo, lo + E_held) of weight * E(x). x (T, D); gate_up
     (E_held, 2F, D), rows [0, F) the gate and [F, 2F) the up projection;
     down (E_held, D, F). Returns (y (T, D), counts (E_held,) int32,
     unplaced () int32: the held slots that the branch taken did not
-    compute, a check of the tables that reads 0)."""
+    compute, a check of the tables that reads 0). ``capacity_rows``: the
+    sorted branch's row buffer before padding (default: a row a token)."""
     t, d = x.shape
     n_held, two_f, _ = gate_up.shape
     f = two_f // 2
     k = ids.shape[1]
-    tables = dispatch_tables(ids, lo, n_held, t, tile)
+    tables = dispatch_tables(ids, lo, n_held, capacity_rows or t, tile)
     rows = tables["row_slot"].shape[0]
     flat_w = weights.reshape(-1)
 

@@ -57,7 +57,7 @@ _LOCK = threading.Lock()
 # other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "remat_kept": 0, "remat_kept_bytes": 0,
-           "flash_tiles": 0, "flash_tiles_live": 0}
+           "flash_tiles": 0, "flash_tiles_live": 0, "dsa_layers": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -156,13 +156,18 @@ def counters(device=True):
     static masks (causal, window) leave live, which are the ones that do
     matmul work; the ratio says how much of S^2 a model's attention layers
     skip, and one near 1 under a window says the window is masked inside
-    tiles, not skipped. Trace-time tallies, flat across steps.
+    tiles, not skipped. ``dsa_layers``: sparse attention layers (an
+    indexer's selection over the flash kernel) traced so far. Trace-time
+    tallies, flat across steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and
     never inside a step: ``moe_slots`` (slots sent to the experts held,
     summed over the layers), ``moe_dropped`` (slots no branch computed:
-    0), and ``moe_slots/<layer>`` (the list per held expert).
+    0), and ``moe_slots/<layer>`` (the list per held expert); a sparse
+    attention layer's ``running_pairs``: ``dsa_pairs_selected`` (pairs its
+    selections kept: the live entries of the masks the kernel was given) and
+    ``dsa_pairs_causal`` (key <= query pairs), summed over the layers.
     ``device=False`` leaves them out: the host counts alone, free of any
     device read, for a loop that polls every step."""
     out = dict(_COUNTS)

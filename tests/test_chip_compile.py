@@ -370,3 +370,60 @@ def test_selective_scan_fwd_bwd_compiles(one_chip):
                           ((channels, states), BF16), ((rows, length, states), BF16),
                           ((rows, length, states), BF16), ((channels,), BF16))
     _assert_kernels(text, "mxtpu_ssm_fwd", "mxtpu_ssm_bwd")
+
+
+@pytest.mark.parametrize("length", [8192, 2048], ids=["split_8192", "fused_2048"])
+def test_flash_pair_mask_compiles(one_chip, length):
+    """One row of Keye-VL-2.0's attention: 32 query heads read 4 key/value
+    heads, 128 wide, with an int8 pair mask as an operand (1 MB tiles at 512 x
+    2048) and its tile summary in SMEM; both backward arms."""
+    def step(q, k, v, mask):
+        return jax.grad(lambda q, k, v: _sum(flash_attention(
+            q, k, v, None, True, 0, False, None, None, None, mask)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(step, one_chip, ((1, 32, length, 128), BF16),
+                          ((1, 4, length, 128), BF16), ((1, 4, length, 128), BF16),
+                          ((1, length, length), jnp.int8))
+    if length > 4096:
+        _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_dq",
+                        "mxtpu_flash_bwd_dkv")
+    else:
+        _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_fused")
+
+
+def test_the_indexers_selection_compiles_at_8192(one_chip):
+    """The selection over one row's 8,192 x 8,192 float32 scores (XLA: 32
+    counting passes a block of query rows and one running count)."""
+    from mxnet_tpu.ops.pallas import dsa
+
+    text = _compiled_text(lambda s: dsa.topk_mask(s, 2048), one_chip,
+                          ((1, 8192, 8192), jnp.float32))
+    assert "s8[1,8192,8192]" in text
+
+
+def test_the_indexers_kernels_compile_at_8192(one_chip):
+    """One row of Keye-VL-2.0's indexer (16 heads of 64, one key head) at
+    8,192 tokens, 512 x 1024 float32 pair tiles resident over the heads; and
+    the head mean of its attention's probabilities (32 / 4 heads of 128),
+    query-row-blocked XLA."""
+    from mxnet_tpu.ops.pallas import dsa
+
+    length = 8192
+
+    def scores(q, k, w):
+        return jax.value_and_grad(lambda *a: _sum(jnp.where(
+            jnp.tril(jnp.ones((length, length), bool)),
+            dsa.index_scores(*a, use_kernel=True, interpret=False), 0.0)),
+            argnums=(0, 1, 2))(q, k, w)
+
+    text = _compiled_text(scores, one_chip, ((1, 16, length, 64), BF16),
+                          ((1, length, 64), BF16), ((1, length, 16), BF16))
+    _assert_kernels(text, "mxtpu_dsa_index_fwd", "mxtpu_dsa_index_bwd_dq",
+                    "mxtpu_dsa_index_bwd_dk")
+    text = _compiled_text(
+        lambda q, k, lse, mask: dsa.head_mean_probs(
+            q, k, lse, mask, sm_scale=128 ** -0.5),
+        one_chip, ((1, 32, length, 128), BF16), ((1, 4, length, 128), BF16),
+        ((1, 32, length), jnp.float32), ((1, length, length), jnp.int8))
+    assert "f32[1,8192,8192]" in text

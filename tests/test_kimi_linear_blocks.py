@@ -412,7 +412,8 @@ def test_kimi_linear_trains_through_record_backward_step():
     net.hybridize()
     ids = mx.nd.array(np.random.default_rng(9).integers(0, 64, (2, 32)), dtype="int32")
     trainer = gluon.Trainer(net.collect_params(), "adamw", {"learning_rate": 3e-3})
-    builds = mx.profiler.counters()["cachedop_builds"]
+    before = mx.profiler.counters()     # running sums of the process: compare increments
+    builds = before["cachedop_builds"]
     losses = []
     for _ in range(4):
         with autograd.record():
@@ -424,7 +425,8 @@ def test_kimi_linear_trains_through_record_backward_step():
     assert losses[-1] < losses[0] and all(np.isfinite(losses))
     seen = mx.profiler.counters()
     assert seen["cachedop_builds"] == builds + 1     # routing re-traces nothing
-    assert seen["moe_dropped"] == 0.0 and seen["looped"] == 0
+    assert seen["moe_dropped"] == before.get("moe_dropped", 0.0)
+    assert seen["looped"] == before["looped"]
 
 
 def test_remat_rows_changes_neither_the_gradients_nor_the_tally():
