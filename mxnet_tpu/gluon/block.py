@@ -663,7 +663,8 @@ class HybridBlock(Block):
                     now = _profiler.counters(device=False)
                     build.set(**{k: now[k] - tiles[k] for k in
                                  ("flash_tiles", "flash_tiles_live",
-                                  "dsa_layers")})
+                                  "dsa_layers", "dsa_topk_chunks",
+                                  "dsa_topk_chunks_live")})
                 _profiler.count("remat_kept", kept[0])
                 _profiler.count("remat_kept_bytes", kept[1])
                 self._cached_graph[key] = entry

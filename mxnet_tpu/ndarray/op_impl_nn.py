@@ -1194,12 +1194,20 @@ def dsa_topk_mask(scores, top_k=2048):
     """Each query's min(top_k, t + 1) best causal keys by ``scores`` (B, S,
     S), the lower index first among equals: an int8 (B, S, S) mask, and
     [pairs kept, causal pairs] float32 (2,) for the layer's running tally.
-    No gradient. Scope ``mxtpu_dsa_topk``."""
+    On the chip, at lengths its tiles divide, the Pallas kernel
+    ``mxtpu_dsa_topk`` (a block of query rows' keys in VMEM; a counting pass
+    stops at the block's last causal column and the passes stop once every
+    row's count is met, a block whose rows all have at most ``top_k`` causal
+    keys writes the causal mask, and the running count among equals runs
+    only where a row has more equals than room); everywhere else
+    query-row-blocked XLA. No gradient. Scope ``mxtpu_dsa_topk``."""
+    from ..ops import pallas as _pallas
     from ..ops.pallas import dsa as _dsa
 
     b, s, _ = scores.shape
+    use_kernel = _pallas.pallas_ok_for(scores) and scores.dtype == jnp.float32
     with jax.named_scope("mxtpu_dsa_topk"):
-        mask = _dsa.topk_mask(scores, int(top_k))
+        mask = _dsa.topk_mask(scores, int(top_k), use_kernel=use_kernel)
         tally = jnp.stack([jnp.sum(mask, dtype=jnp.int32).astype(jnp.float32),
                            jnp.float32(b * (s * (s + 1) // 2))])
     return mask, tally

@@ -24,8 +24,8 @@ makes 67 M entries large, so each works a block of query rows at a time
   softmax_kept(I)), whose gradient to I is softmax_kept(I) - p_bar, written
   out (a ``custom_vjp``: nothing but its three inputs is kept).
 
-Each is a jitted function (a model's layers trace one body). ``topk_mask``,
-``head_mean_probs`` and ``index_loss`` are XLA on every backend.
+Each is a jitted function, or a choice between two (a model's layers trace
+one body). ``head_mean_probs`` and ``index_loss`` are XLA on every backend.
 ``index_scores`` has a Pallas form for the chip (``use_kernel``):
 ``mxtpu_dsa_index_fwd``, one (block_q, block_k) tile of the pair matrix
 resident in VMEM while the heads, the grid's innermost axis, add their terms
@@ -33,8 +33,26 @@ to it, so that the heads x S^2 products never reach HBM (the XLA form writes
 and reads them: 16 x 268 MB a row of 8,192), and its backward is the pair
 ``mxtpu_dsa_index_bwd_dq`` (a head's dq and dw summed over the kv tiles in
 VMEM) / ``mxtpu_dsa_index_bwd_dk`` (dk summed over the heads and the q
-tiles), each rebuilding a tile's products. The main attention's kernel is the
-flash kernel itself.
+tiles), each rebuilding a tile's products. ``topk_mask`` has one too,
+``mxtpu_dsa_topk``, which does the work of the pairs that can be chosen and
+no other. XLA's form keeps a block of rows in VMEM as well; its time is 33
+vector-unit passes over every column of every row. The kernel takes 128
+query rows a grid step: their scores come in once, their keys (the floats'
+order as signed integers, the least integer above the diagonal) go to a VMEM
+scratch, and each of the 32 counting passes, a loop inside the kernel, walks
+512-column chunks only up to the block's last causal column: a compare, a
+select and an add into a (rows, 128) partial sum a vreg. A block whose rows
+all have at most ``top_k`` causal keys writes the causal mask and searches
+nothing. The search carries how many keys stand at or over the threshold:
+once that is the count wanted in every row of the block the lower bits
+cannot change the choice, so the search stops there (no longer 32 passes:
+21 to 26 on normal scores) and the mask is ``key >= threshold``; the running
+count among equals (128 columns at a time against a triangle of ones on the
+MXU) runs only in a block where, after all 32, some row has more equals than
+room. Columns past the diagonal are written as zeros and never read. It
+tallies what it visits (``dsa_topk_chunks`` / ``dsa_topk_chunks_live`` in
+``profiler.counters()``). The main attention's kernel is the flash kernel
+itself.
 """
 from __future__ import annotations
 
@@ -47,6 +65,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import profiler as _profiler
 from ._util import resolve_interpret, x32
 from .flash_attention import _dot_precision
 
@@ -305,15 +324,18 @@ def index_scores(q_i, k_i, w, block_q=_BLOCK_Q, use_kernel=False, interpret=None
 def _order_bits(x):
     """float32 -> uint32 in the floats' total order (-0 below +0, as
     ``lax.top_k`` has them)."""
+    return lax.bitcast_convert_type(_order_key(x), jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+
+
+def _order_key(x):
+    """float32 -> int32 in the floats' total order, signed."""
     bits = lax.bitcast_convert_type(x, jnp.int32)
-    bits = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
-    return lax.bitcast_convert_type(bits, jnp.uint32) ^ jnp.uint32(0x80000000)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "block_q"))
-def topk_mask(scores, top_k, block_q=_BLOCK_Q):
-    """(B, S, S) int8: 1 on each query's min(top_k, t + 1) largest causal
-    scores, the lower index first among equals; exactly that many a query."""
+def _topk_mask_xla(scores, top_k, block_q):
     b, s, _ = scores.shape
     bq, _ = _blocks(s, block_q)
 
@@ -341,7 +363,179 @@ def topk_mask(scores, top_k, block_q=_BLOCK_Q):
         return jnp.logical_or(above, jnp.logical_and(equal, admitted)) \
             .astype(jnp.int8)
 
-    return _by_row_blocks(block, s, block_q, lax.stop_gradient(scores))
+    return _by_row_blocks(block, s, block_q, scores)
+
+
+# The selection's kernel: a block of query rows' ordered keys in VMEM, read a
+# chunk of columns at a time up to the block's last causal column.
+_TOPK_ROWS, _TOPK_CHUNK = 128, 512
+_INT_MIN = -2 ** 31
+
+
+def _topk_tiles(s):
+    """(rows a block, columns a chunk) of the selection's kernel, or None
+    where ``s`` is no multiple of them (toy lengths: the XLA form)."""
+    bq, ck = min(_TOPK_ROWS, s), min(_TOPK_CHUNK, s)
+    return (bq, ck) if s % bq == 0 and s % ck == 0 and bq % 32 == 0 \
+        and ck % 128 == 0 else None
+
+
+def _topk_visits(s, top_k, bq, ck):
+    """(Row block, column chunk) visits of one (S, S) selection, from its
+    shapes: (those of 32 counting passes and the writing pass over the whole
+    rectangle, those the kernel makes: a block's chunks up to its last causal
+    column, 33 times where a row of the block chooses and once, for the
+    causal mask's write, where none does)."""
+    ends = np.arange(bq, s + 1, bq)                 # one past a block's last row
+    live = -(-ends // ck)
+    return ((s // bq) * (s // ck) * 33,
+            int(np.where(ends > top_k, 33 * live, live).sum()))
+
+
+def _topk_kernel(sc_ref, o_ref, key_ref, *, top_k, block_q, chunk):
+    bq, ck = block_q, chunk
+    lanes = ck // 128
+    first = pl.program_id(1) * bq
+    n_live = (first + bq + (ck - 1)) // ck          # chunks with a causal column
+    row = first + lax.broadcasted_iota(jnp.int32, (bq, 128), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (bq, 128), 1)
+
+    def columns(c, j):
+        return pl.ds(pl.multiple_of(c * ck + j * 128, 128), 128)
+
+    def causal(c, j):
+        return c * ck + j * 128 + lane <= row
+
+    def write(c, j, kept):
+        o_ref[0, 0, :, columns(c, j)] = jnp.where(kept, 1, 0).astype(jnp.int8)
+
+    def over_live(body, carry=None):
+        """``body(c, j, carry)`` over the live chunks' 128-column slices."""
+        def chunk_body(c, carry):
+            for j in range(lanes):
+                carry = body(c, j, carry)
+            return carry
+        return lax.fori_loop(0, n_live, chunk_body, carry)
+
+    @pl.when(first + bq <= top_k)
+    def _():            # every row keeps all its causal keys
+        over_live(lambda c, j, _: write(c, j, causal(c, j)))
+
+    @pl.when(first + bq > top_k)
+    def _():
+        def build(c, j, _):
+            # the least int32 sorts below every score's key, and no
+            # candidate below is it
+            key_ref[:, columns(c, j)] = jnp.where(
+                causal(c, j), _order_key(sc_ref[0, :, columns(c, j)]),
+                jnp.int32(_INT_MIN))
+        over_live(build)
+
+        def count(pred, level):
+            """(bq, 1): each row's keys with ``pred(key, level)``."""
+            wide = jnp.broadcast_to(level, (bq, 128))
+            part = over_live(
+                lambda c, j, acc: acc + jnp.where(
+                    pred(key_ref[:, columns(c, j)], wide), 1, 0),
+                jnp.zeros((bq, 128), jnp.int32))
+            return jnp.sum(part, axis=1, keepdims=True)
+
+        want = jnp.minimum(
+            first + lax.broadcasted_iota(jnp.int32, (bq, 1), 0) + 1, top_k)
+
+        def open_rows(found):
+            """Is there a row without exactly its count at or over ``thr``?"""
+            return jnp.max(jnp.where(found != want, 1, 0)) > 0
+
+        def bit(state):
+            # thr: the threshold's bits so far, in the unsigned order; a
+            # candidate's signed twin is what the signed keys are held to
+            i, thr, found = state
+            cand = thr | lax.shift_left(jnp.int32(1), 31 - i)
+            n = count(lambda k, c: k >= c, cand ^ jnp.int32(_INT_MIN))
+            enough = n >= want
+            return (i + 1, jnp.where(enough, cand, thr),
+                    jnp.where(enough, n, found))
+
+        # the want-th largest: its bits, from the top one down, and how many
+        # keys are no less (-1: no candidate had enough yet). A row whose
+        # threshold has exactly its count at or over it is settled, whatever
+        # the lower bits: the search stops when every row of the block is
+        # (after 21 to 26 of the 32 passes on normal scores at 8,192)
+        _, thr, found = lax.while_loop(
+            lambda state: jnp.logical_and(state[0] < 32, open_rows(state[2])),
+            bit, (jnp.int32(0), jnp.zeros((bq, 1), jnp.int32),
+                  jnp.full((bq, 1), -1, jnp.int32)))
+        thr = thr ^ jnp.int32(_INT_MIN)
+        wide = jnp.broadcast_to(thr, (bq, 128))
+        crowded = open_rows(found)
+
+        @pl.when(jnp.logical_not(crowded))
+        def _():        # as many at or over the threshold as wanted: those
+            over_live(lambda c, j, _: write(
+                c, j, key_ref[:, columns(c, j)] >= wide))
+
+        @pl.when(crowded)
+        def _():        # more equals than room: the lower index first
+            room = (want - count(lambda k, t: k > t, thr)).astype(jnp.float32)
+            upto = (lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+                    >= lax.broadcasted_iota(jnp.int32, (128, 128), 1))
+            upto = jnp.where(upto, 1.0, 0.0).astype(jnp.bfloat16)
+
+            def admit(c, j, before):
+                key = key_ref[:, columns(c, j)]
+                equal = jnp.logical_and(key == wide, causal(c, j))
+                # equals up to and with each column (0/1 sums to 128: exact)
+                run = _dot_nt(jnp.where(equal, 1.0, 0.0).astype(jnp.bfloat16),
+                              upto)
+                write(c, j, jnp.logical_or(
+                    key > wide, jnp.logical_and(equal, before + run <= room)))
+                return before + run[:, 127:128]
+            over_live(admit, jnp.zeros((bq, 1), jnp.float32))
+
+    def beyond(c, _):   # no causal column: written, never read
+        o_ref[0, 0, :, pl.ds(pl.multiple_of(c * ck, ck), ck)] = \
+            jnp.zeros((bq, ck), jnp.int8)
+    lax.fori_loop(n_live, o_ref.shape[3] // ck, beyond, None)
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "tiles", "interpret"))
+@x32
+def _topk_mask_pallas(scores, top_k, tiles, interpret):
+    b, s, _ = scores.shape
+    bq, ck = tiles
+    # out as (B, blocks, rows, S), a free reshape from (B, S, S): the flash
+    # wrapper's tile summary (a max over tiles of rows) then reduces each
+    # block's rows where they lie; from a (B, S, S) custom call XLA re-tiled
+    # the whole mask first (0.6 ms a summary at 8,192, three a layer-row)
+    return pl.pallas_call(
+        functools.partial(_topk_kernel, top_k=top_k, block_q=bq, chunk=ck),
+        grid=(b, s // bq),
+        in_specs=[pl.BlockSpec((1, bq, s), lambda b_, i: (b_, i, 0))],
+        out_specs=pl.BlockSpec((1, 1, bq, s), lambda b_, i: (b_, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s // bq, bq, s), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((bq, s), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=interpret, name="mxtpu_dsa_topk",
+    )(scores).reshape(b, s, s)
+
+
+def topk_mask(scores, top_k, block_q=_BLOCK_Q, use_kernel=False, interpret=None):
+    """(B, S, S) int8: 1 on each query's min(top_k, t + 1) largest causal
+    scores, the lower index first among equals; exactly that many a query.
+    ``use_kernel``: ``mxtpu_dsa_topk`` (lengths that its tiles divide), which
+    tallies ``dsa_topk_chunks`` / ``dsa_topk_chunks_live`` as it is traced."""
+    b, s, _ = scores.shape
+    scores = lax.stop_gradient(scores)
+    tiles = _topk_tiles(s) if use_kernel else None
+    if tiles is None:
+        return _topk_mask_xla(scores, top_k, block_q)
+    full, live = _topk_visits(s, top_k, *tiles)
+    _profiler.count("dsa_topk_chunks", b * full)
+    _profiler.count("dsa_topk_chunks_live", b * live)
+    return _topk_mask_pallas(scores, top_k, tiles, resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "block_q"))

@@ -52,12 +52,14 @@ _LOCK = threading.Lock()
 # from what that trace's rematerialised blocks keep, "remat_kept" and
 # "remat_kept_bytes"), `Optimizer.update_multi` "fused" and "looped" (and
 # "invokes", once, for its compiled program), the flash attention
-# wrappers, while traced, "flash_tiles" and "flash_tiles_live". Not locked:
+# wrappers, while traced, "flash_tiles" and "flash_tiles_live", the sparse
+# attention's selection kernel "dsa_topk_chunks" and "_live". Not locked:
 # a span reads the difference on its own thread, which is exact while no
 # other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "remat_kept": 0, "remat_kept_bytes": 0,
-           "flash_tiles": 0, "flash_tiles_live": 0, "dsa_layers": 0}
+           "flash_tiles": 0, "flash_tiles_live": 0, "dsa_layers": 0,
+           "dsa_topk_chunks": 0, "dsa_topk_chunks_live": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -157,8 +159,15 @@ def counters(device=True):
     matmul work; the ratio says how much of S^2 a model's attention layers
     skip, and one near 1 under a window says the window is masked inside
     tiles, not skipped. ``dsa_layers``: sparse attention layers (an
-    indexer's selection over the flash kernel) traced so far. Trace-time
-    tallies, flat across steps.
+    indexer's selection over the flash kernel) traced so far.
+    ``dsa_topk_chunks`` / ``dsa_topk_chunks_live``: of every call of the
+    selection's kernel ``mxtpu_dsa_topk`` traced so far, the (row block,
+    column chunk) visits that 32 counting passes and the writing pass over
+    the whole score matrix would make, and those the kernel makes (only up
+    to a block's last causal column, and one where no row of the block has
+    more causal keys than ``top_k``): 0 and 0 say the XLA form ran, a ratio
+    of 1 that the kernel skips nothing. Trace-time tallies, flat across
+    steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and

@@ -393,13 +393,20 @@ def test_flash_pair_mask_compiles(one_chip, length):
 
 
 def test_the_indexers_selection_compiles_at_8192(one_chip):
-    """The selection over one row's 8,192 x 8,192 float32 scores (XLA: 32
-    counting passes a block of query rows and one running count)."""
+    """The selection over one row's 8,192 x 8,192 float32 scores: the Pallas
+    kernel ``mxtpu_dsa_topk``, 128 query rows a grid step, their scores in
+    once (4 MB) and their ordered int32 keys in a VMEM scratch of the same
+    size; up to 32 counting passes inside the kernel, each over 512-column
+    chunks up to the block's last causal column; the int8 mask out once,
+    straight into (B, S, S). No loop of XLA's counting fusions is left."""
     from mxnet_tpu.ops.pallas import dsa
 
-    text = _compiled_text(lambda s: dsa.topk_mask(s, 2048), one_chip,
-                          ((1, 8192, 8192), jnp.float32))
+    text = _compiled_text(
+        lambda s: dsa.topk_mask(s, 2048, use_kernel=True, interpret=False),
+        one_chip, ((1, 8192, 8192), jnp.float32))
+    _assert_kernels(text, "mxtpu_dsa_topk")
     assert "s8[1,8192,8192]" in text
+    assert not re.search(r"\bwhile\(|reduce", text)
 
 
 def test_the_indexers_kernels_compile_at_8192(one_chip):

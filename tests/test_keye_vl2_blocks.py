@@ -211,12 +211,15 @@ def _dense_scores(q, k, w):
 
 
 def _top_k_mask(scores, top_k):
+    """``lax.top_k`` over each query's causal keys (the keys above the
+    diagonal at -inf, behind every causal key of the same value by their
+    index), as an int8 mask."""
+    length = scores.shape[-1]
+    causal = np.tril(np.ones((length, length), bool))
+    _, idx = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), min(top_k, length))
     out = np.zeros(scores.shape, np.int8)
-    for b in range(scores.shape[0]):
-        for t in range(scores.shape[1]):
-            _, idx = jax.lax.top_k(scores[b, t, :t + 1], min(top_k, t + 1))
-            out[b, t, np.asarray(idx)] = 1
-    return out
+    np.put_along_axis(out, np.asarray(idx), 1, axis=-1)
+    return out * causal
 
 
 @pytest.mark.parametrize("block_q", [16, 64], ids=["blocks", "one_block"])
@@ -257,19 +260,104 @@ def test_the_index_score_kernel_is_its_twin(monkeypatch):
         lambda *a: dsa.index_scores(*a, use_kernel=True, interpret=True))(q, k, w))
 
 
-@pytest.mark.parametrize("top_k", [16, 64, 200])
-def test_the_selection_is_lax_top_ks_with_ties_planted(top_k):
-    scores = np.asarray(_dense_scores(*_index_inputs(13))).copy()
-    scores[0, 40, 3:30] = 1.5                                  # 27 equal, at the top
-    scores[1, 50, :51] = 0.0                                   # a row of equals
-    scores[1, 50, 7] = -0.0                                    # below +0 in the total order
-    scores[0, 63, :64] = np.where(np.arange(64) % 2, 0.25, -0.25)
-    mask = np.asarray(dsa.topk_mask(jnp.asarray(scores), top_k, block_q=16))
-    assert mask.dtype == np.int8
-    want = np.minimum(top_k, np.arange(64) + 1)
+def _planted_scores(length=512):
+    """(2, length, length) float32 with what a selection can trip over."""
+    rng = np.random.default_rng(13)
+    scores = rng.standard_normal((2, length, length)).astype(np.float32)
+    above = np.triu(np.ones((length, length), bool), 1)
+    scores[0][above] = -np.inf                                 # as the indexer leaves it
+    scores[1][above] = 1e9                                     # nothing above the diagonal is read
+    scores[0, 300, 100:290] = 1.5       # 190 equals at the top, over three 128-column chunks
+    scores[0, 301, 120:140] = np.sort(scores[0, 301, :302])[-50]   # equals at 64's threshold, over two
+    scores[1, 400, :401] = 0.0                                 # a row of equals
+    scores[1, 400, 7] = -0.0                                   # below +0 in the total order
+    scores[0, 511, :] = np.where(np.arange(length) % 2, 0.25, -0.25)
+    scores[1, 260, :200] = -np.inf                             # -inf among the causal keys
+    return scores
+
+
+def _select(form, scores, top_k):
+    if form == "kernel":
+        return dsa.topk_mask(scores, top_k, use_kernel=True, interpret=True)
+    return dsa.topk_mask(scores, top_k, block_q=64)
+
+
+@pytest.fixture
+def small_selection_tiles(monkeypatch):
+    """Eight row blocks and four column chunks at 512 tokens."""
+    monkeypatch.setattr(dsa, "_TOPK_ROWS", 64)
+    monkeypatch.setattr(dsa, "_TOPK_CHUNK", 128)
+
+
+@pytest.mark.parametrize("top_k", [16, 64, 200, 600])
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_selection_is_lax_top_ks_with_ties_planted(small_selection_tiles, form, top_k):
+    """Both forms against ``lax.top_k``, bit for bit, two rows of 512 tokens:
+    at 64 one block of the kernel's lies under ``top_k`` (the causal mask,
+    no search), at 200 three do and one straddles it, at 600 all do; equals
+    at a threshold across column chunks, more of them than there is room; a
+    row of equals; -0 against +0; -inf among and above the causal keys."""
+    scores = jnp.asarray(_planted_scores())
+    mask = np.asarray(_select(form, scores, top_k))
+    assert mask.dtype == np.int8 and mask.shape == scores.shape
+    want = np.minimum(top_k, np.arange(512) + 1)
     assert (mask.sum(-1) == want).all()                        # exactly, never more
-    assert (mask == _top_k_mask(jnp.asarray(scores), top_k)).all()
+    assert (mask == _top_k_mask(scores, top_k)).all()
     assert not np.triu(mask, 1).any()
+
+
+def test_the_selection_kernel_is_taken_where_its_tiles_divide_the_length():
+    """``mxtpu_dsa_topk`` at 512 tokens; at 96, which no tile divides, and
+    without ``use_kernel`` the XLA form: no Pallas call in the jaxpr."""
+    def text(length, **how):
+        scores, = _normal(5, (1, length, length))
+        return str(jax.make_jaxpr(lambda s: dsa.topk_mask(s, 32, **how))(scores))
+
+    assert "mxtpu_dsa_topk" in text(512, use_kernel=True, interpret=True)
+    assert "pallas" not in text(96, use_kernel=True, interpret=True)
+    assert "pallas" not in text(512)
+
+
+def test_the_selection_kernel_tallies_the_chunks_it_visits(small_selection_tiles):
+    """``dsa_topk_chunks`` / ``dsa_topk_chunks_live`` from the shapes, when the
+    kernel is traced: 8 row blocks x 4 column chunks x 33 passes a row of 512
+    tokens, of which the first block (under ``top_k`` 64) visits one chunk
+    once and the others their 1, 2, 2, 3, 3, 4, 4 causal chunks 33 times; the
+    XLA form adds nothing; a block's build span carries the trace's two."""
+    def tally(fn):
+        before = mx.profiler.counters(device=False)
+        fn()
+        after = mx.profiler.counters(device=False)
+        return tuple(after[n] - before[n] for n in ("dsa_topk_chunks", "dsa_topk_chunks_live"))
+
+    scores, = _normal(6, (2, 512, 512))
+    counts = (2 * 8 * 4 * 33, 2 * (1 + 33 * 19))
+    assert tally(lambda: dsa.topk_mask(scores, 64, use_kernel=True, interpret=True)) == counts
+    assert tally(lambda: dsa.topk_mask(scores, 64)) == (0, 0)
+
+    class Select(gluon.HybridBlock):
+        def hybrid_forward(self, F, x):
+            return F.dsa_topk_mask(x, top_k=64)[0]
+
+    def build(block):
+        mx.profiler.set_state("run")
+        try:
+            block(mx.nd.array(np.asarray(scores))).wait_to_read()
+        finally:
+            mx.profiler.set_state("stop")
+        from mxnet_tpu.profiler import _EVENTS
+        return [e["args"] for e in _EVENTS if e["name"] == "mxtpu/cachedop/build"][-1]
+
+    block = Select()
+    block.hybridize()
+    span = build(block)                 # on the CPU: the XLA form
+    assert (span["dsa_topk_chunks"], span["dsa_topk_chunks_live"]) == (0, 0)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("MXNET_TPU_PALLAS_INTERPRET", "1")
+        block = Select()
+        block.hybridize()
+        span = build(block)
+    assert (span["dsa_topk_chunks"], span["dsa_topk_chunks_live"]) == counts
 
 
 def _attention_probs(seed, mask):
