@@ -261,7 +261,13 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args)
+        if _in_cached_call():
+            # inside a compiled program the block's kind is the name of its
+            # operations in the device trace (`_block_scope`)
+            with _block_scope(self):
+                out = self.forward(*args)
+        else:
+            out = self.forward(*args)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out
@@ -298,6 +304,21 @@ _TRACE_GUARD = threading.local()
 
 def _in_cached_call() -> bool:
     return getattr(_TRACE_GUARD, "depth", 0) > 0
+
+
+def _block_scope(block):
+    """The name a block's operations carry in a compiled program, and from
+    there in the device trace (each event's ``tf_op``): ``mx.<class>``, one
+    component of jax's name stack a block call, so nesting gives the path
+    (``mx.BERTModel/mx.TransformerEncoderCell/mx.Dense/dot_general``). The
+    class and not the instance: six equal layers are one row of a table.
+    Entered only under `_in_cached_call`; it is metadata of the program and
+    costs nothing at step time. Of a step's phases jax marks remat's rebuild
+    (``rematted_computation``); the backward of a recorded CachedOp is a
+    second program with the forward's paths (`_build_cached_op` names both
+    ``mxtpu_fwd_<class>``; ``transpose(`` shows only where a whole step is
+    one program), and `optimizer._fused_update` names the update."""
+    return jax.named_scope("mx." + type(block).__name__)
 
 
 class _trace_guard:
@@ -721,7 +742,9 @@ class HybridBlock(Block):
                     for p, arr in zip(params, p_arrays):
                         wrappers = {c: _wrap(arr, c) for c in p._data}
                         p._data = wrappers
-                    out = block.forward(*call_args)
+                    # the root's call does not pass `__call__`
+                    with _block_scope(block):
+                        out = block.forward(*call_args)
             finally:
                 _REMAT_GUARD.active = prev_remat
                 for p, d in saved_data:
@@ -742,6 +765,10 @@ class HybridBlock(Block):
             return tuple(x._data if isinstance(x, NDArray) else x
                          for x in flat) + tuple(aux_arrays)
 
+        # the program's name in the trace's ``XLA Modules`` line, and part of
+        # its key in the persistent compile cache
+        traced.__name__ = traced.__qualname__ = \
+            "mxtpu_fwd_" + type(self).__name__
         fn = jax.checkpoint(traced, policy=policy) if remat else traced
         jitted = jax.jit(fn)
         # learn the output structure abstractly — no device execution

@@ -1220,7 +1220,7 @@ def dsa_attention(query, key, value, pair_mask):
     and p_bar (B, S, S) float32, the head mean of the probabilities on the
     kept pairs (no gradient through it). On the chip the flash kernel with
     the mask as an operand; p_bar is query-row-blocked XLA on every backend;
-    scope ``mxtpu_dsa_attn``."""
+    scope ``mxtpu_dsa_attn``, p_bar's part of it ``mxtpu_dsa_pbar``."""
     from ..ops import pallas as _pallas
     from ..ops.pallas import dsa as _dsa
 
@@ -1236,8 +1236,9 @@ def dsa_attention(query, key, value, pair_mask):
         else:
             out, lse = _masked_attention_with_lse(query, key, value, pair_mask,
                                                   scale)
-        p_bar = _dsa.head_mean_probs(query, key, lse, pair_mask,
-                                     sm_scale=scale)
+        with jax.named_scope("mxtpu_dsa_pbar"):
+            p_bar = _dsa.head_mean_probs(query, key, lse, pair_mask,
+                                         sm_scale=scale)
     return out, p_bar
 
 

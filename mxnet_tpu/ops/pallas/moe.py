@@ -296,6 +296,7 @@ def experts_held(x, ids, weights, gate_up, down, lo, use_kernel=False,
         y = jnp.zeros((t, d), jnp.float32).at[token].add(ys * wrow[:, None])
         return y, jnp.sum(tables["row_valid"], dtype=jnp.int32)
 
+    @jax.named_scope("mxtpu_moe_dense")     # a trace tells the branch taken
     def every_expert(_):
         local = ids - lo
         prec = _precision(x.dtype)

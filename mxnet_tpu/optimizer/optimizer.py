@@ -16,7 +16,6 @@ with the multi-precision casts and the state writes in it.
 """
 from __future__ import annotations
 
-import functools
 import math
 import pickle
 
@@ -71,8 +70,7 @@ def _definer(cls, attr):
     return next(c for c in cls.__mro__ if attr in vars(c))
 
 
-@functools.partial(jax.jit, static_argnames="rules",
-                   donate_argnames=("states", "masters"))
+@jax.named_scope("mxtpu_update")
 def _fused_update(rules, weights, grads, states, masters, hyper, rescale_grad):
     """Every parameter's rule in one program: ``rules[i]`` is parameter
     i's (op name, constant hyperparameters), static; ``hyper`` maps the
@@ -83,7 +81,9 @@ def _fused_update(rules, weights, grads, states, masters, hyper, rescale_grad):
     not: each is cast to the dtype of the array the eager op would
     multiply it into, and every output to the array it replaces. The
     optimizer's own arrays (``states``, ``masters``) are donated; weights
-    and gradients are the caller's and are not."""
+    and gradients are the caller's and are not. The name scope
+    ``mxtpu_update`` marks the one phase of a step that jax's name stack
+    does not mark itself (``transpose(``, ``rematted_computation``)."""
     new_weights, new_states, new_masters = [], [], []
     for i, (name, consts) in enumerate(rules):
         op, weight, grad, master = _get_op(name), weights[i], grads[i], masters[i]
@@ -101,6 +101,13 @@ def _fused_update(rules, weights, grads, states, masters, hyper, rescale_grad):
         new_masters.append(None if master is None else new)
         new_weights.append(new.astype(weights[i].dtype))
     return new_weights, new_states, new_masters
+
+
+# the program's name: `jit_mxtpu_update` on a trace's ``XLA Modules`` line,
+# and part of its key in the persistent compile cache
+_fused_update.__name__ = _fused_update.__qualname__ = "mxtpu_update"
+_fused_update = jax.jit(_fused_update, static_argnames="rules",
+                        donate_argnames=("states", "masters"))
 
 
 class Optimizer:
