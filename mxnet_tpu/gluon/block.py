@@ -680,12 +680,12 @@ class HybridBlock(Block):
                     finally:
                         _REMAT_GUARD.kept = None
                     build.set(remat_kept=kept[0], remat_kept_bytes=kept[1])
-                    # the flash kernels' tile tallies of this trace
+                    # the kernels' tallies of this trace (`_BUILD_TALLIES`, at
+                    # this file's end: a line added here would move `traced`
+                    # below, whose line number compiled programs carry)
                     now = _profiler.counters(device=False)
-                    build.set(**{k: now[k] - tiles[k] for k in
-                                 ("flash_tiles", "flash_tiles_live",
-                                  "dsa_layers", "dsa_topk_chunks",
-                                  "dsa_topk_chunks_live")})
+                    build.set(**{k: now[k] - tiles[k]
+                                 for k in _BUILD_TALLIES})
                 _profiler.count("remat_kept", kept[0])
                 _profiler.count("remat_kept_bytes", kept[1])
                 self._cached_graph[key] = entry
@@ -938,3 +938,10 @@ class SymbolBlock(HybridBlock):
 
     def hybrid_forward(self, F, x, *args, **kwargs):
         raise NotImplementedError
+
+
+# what a build span reports of its trace's kernels (`_call_cached_op`):
+# differences of these `profiler.counters()` tallies over the trace
+_BUILD_TALLIES = ("flash_tiles", "flash_tiles_live", "dsa_layers",
+                  "dsa_topk_chunks", "dsa_topk_chunks_live",
+                  "conv1d_calls", "conv1d_kernel_calls")

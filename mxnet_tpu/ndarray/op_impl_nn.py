@@ -993,9 +993,9 @@ def swiglu(data):
             * data[..., f:].astype(jnp.float32)).astype(data.dtype)
 
 
-@register_op("causal_conv1d")
+# the twin of `causal_conv1d` (at the end of this file; see the note there)
 @_lean
-def causal_conv1d(data, weight, bias=None, activation=None):
+def _causal_conv1d_twin(data, weight, bias=None, activation=None):
     """Depthwise causal convolution over time: data (B, S, C), weight
     (C, K); y_t = sum_i weight[:, i] * x_{t-(K-1)+i} (the last tap is the
     current token), zeros before the row's start, + ``bias`` (C,) where
@@ -1266,3 +1266,44 @@ def dsa_index_loss(scores, pair_mask, p_bar):
 
     with jax.named_scope("mxtpu_dsa_index"):
         return _dsa.index_loss(scores, pair_mask, p_bar)
+
+
+# ----------------------------------------------------------------------
+# `causal_conv1d` stands at the END of this file, away from its twin above:
+# a Pallas call's serialized module carries the line numbers of the frames
+# that called it, this file's ops among them, so a line added above
+# `kda_chunked`, `moe_experts_held` or the `dsa_*` ops would re-key every
+# compiled program that holds their kernels (PERF.md section 6, PRs 33, 36).
+# ----------------------------------------------------------------------
+@register_op("causal_conv1d")
+def causal_conv1d(data, weight, bias=None, activation=None):
+    """Depthwise causal convolution over time: data (B, S, C), weight
+    (C, K); y_t = sum_i weight[:, i] * x_{t-(K-1)+i} (the last tap is the
+    current token), zeros before the row's start, + ``bias`` (C,) where
+    given. ``activation='silu'`` applies SiLU to the result. Float32
+    arithmetic, the taps summed in the order i = 0 .. K-1; returns data's
+    type. On the chip, for bfloat16 or float32 data whose channel count is
+    a multiple of 128 and whose length a token block divides
+    (``ops/pallas/conv1d.py`` ``tiles``), a Pallas kernel pair with a
+    hand-written backward (``mxtpu_conv1d_fwd`` / ``mxtpu_conv1d_bwd``: x
+    read once in its own layout, the K - 1 rows before a block carried in
+    VMEM; the backward keeps the inputs only and sums the taps' and the
+    bias's gradients in float32); everywhere else `_causal_conv1d_twin`, a
+    sum of K shifted slices of a padded float32 copy, differentiated by jax
+    under ``jax.checkpoint``. Both run under
+    ``jax.named_scope("mxtpu_conv1d")`` and tally, as they are traced,
+    ``conv1d_calls`` (and, the kernel taken, ``conv1d_kernel_calls``) in
+    ``profiler.counters()``."""
+    from .. import profiler as _profiler
+    from ..ops import pallas as _pallas
+    from ..ops.pallas import conv1d as _conv1d
+
+    use_kernel = (_pallas.pallas_ok_for(data) and _conv1d.tiles(
+        data.shape, weight.shape[1], data.dtype) is not None)
+    _profiler.count("conv1d_calls")
+    if use_kernel:
+        _profiler.count("conv1d_kernel_calls")
+    with jax.named_scope("mxtpu_conv1d"):
+        if use_kernel:
+            return _conv1d.causal_conv1d(data, weight, bias, activation)
+        return _causal_conv1d_twin(data, weight, bias, activation=activation)

@@ -28,6 +28,13 @@ we only drop to Pallas where XLA's own fusion genuinely loses:
   head mean of the attention's probabilities as pair tiles resident in
   VMEM over the heads; the selection and the indexer's loss in XLA
   (imported as a module: ``ops.pallas.dsa``).
+- ``conv1d`` — the depthwise causal short convolution in front of a
+  linear-attention or state-space mixer: x read once in the projections'
+  (B, S, C) layout, the K - 1 rows before a token block carried in VMEM, a
+  hand-written backward that keeps the inputs only (``causal_conv1d``;
+  imported as a module, ``ops.pallas.conv1d``: its twin is
+  ``F.causal_conv1d``'s own ``jax.numpy`` body, which also runs at shapes
+  the tiles do not divide).
 
 Dispatch contract: every kernel here has a pure-jnp twin used when the
 backend is not TPU (tests run on the CPU mesh) or when
@@ -47,6 +54,7 @@ from .softmax_xent import softmax_xent_fused  # noqa: E402
 from .kda import kda_chunked  # noqa: E402
 from .moe import experts_held, grouped_matmul  # noqa: E402
 from .ssm import selective_scan  # noqa: E402
+from . import conv1d  # noqa: E402,F401
 
 __all__ = [
     "pallas_enabled",
@@ -62,4 +70,5 @@ __all__ = [
     "selective_scan",
     "grouped_matmul",
     "experts_held",
+    "conv1d",
 ]

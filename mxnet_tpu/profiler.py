@@ -53,13 +53,15 @@ _LOCK = threading.Lock()
 # "remat_kept_bytes"), `Optimizer.update_multi` "fused" and "looped" (and
 # "invokes", once, for its compiled program), the flash attention
 # wrappers, while traced, "flash_tiles" and "flash_tiles_live", the sparse
-# attention's selection kernel "dsa_topk_chunks" and "_live". Not locked:
-# a span reads the difference on its own thread, which is exact while no
-# other thread dispatches (a training loop).
+# attention's selection kernel "dsa_topk_chunks" and "_live", the short
+# convolution `F.causal_conv1d` "conv1d_calls" and, its kernel pair taken,
+# "conv1d_kernel_calls". Not locked: a span reads the difference on its own
+# thread, which is exact while no other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "remat_kept": 0, "remat_kept_bytes": 0,
            "flash_tiles": 0, "flash_tiles_live": 0, "dsa_layers": 0,
-           "dsa_topk_chunks": 0, "dsa_topk_chunks_live": 0}
+           "dsa_topk_chunks": 0, "dsa_topk_chunks_live": 0,
+           "conv1d_calls": 0, "conv1d_kernel_calls": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -166,8 +168,12 @@ def counters(device=True):
     the whole score matrix would make, and those the kernel makes (only up
     to a block's last causal column, and one where no row of the block has
     more causal keys than ``top_k``): 0 and 0 say the XLA form ran, a ratio
-    of 1 that the kernel skips nothing. Trace-time tallies, flat across
-    steps.
+    of 1 that the kernel skips nothing. ``conv1d_calls`` /
+    ``conv1d_kernel_calls``: calls of the short convolution
+    ``F.causal_conv1d`` traced so far, and those of them that took the Pallas
+    kernel pair ``mxtpu_conv1d_fwd`` / ``_bwd`` (on the chip, at a shape its
+    tiles divide); equal on a chip at published widths, the second 0 where
+    the ``jax.numpy`` form ran. Trace-time tallies, flat across steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and

@@ -434,3 +434,26 @@ def test_the_indexers_kernels_compile_at_8192(one_chip):
         one_chip, ((1, 32, length, 128), BF16), ((1, 4, length, 128), BF16),
         ((1, 32, length), jnp.float32), ((1, length, length), jnp.int8))
     assert "f32[1,8192,8192]" in text
+
+
+@pytest.mark.parametrize("channels, bias, dtype", [
+    (4096, False, BF16), (5120, True, BF16), (4096, True, jnp.float32)],
+    ids=["kimi_4096", "phi_5120_bias", "float32_4096_bias"])
+def test_short_convolution_fwd_bwd_compiles(one_chip, channels, bias, dtype):
+    """One row of a Kimi KDA mixer's q, k or v (4,096 channels, no bias) and
+    of a Phi-4-mini-flash Mamba layer's u (5,120, bias), and a float32 row
+    (its blocks are twice the bytes in VMEM): 8,192 tokens, four taps, SiLU.
+    The pair reads its windows at sublane offsets K - 1 .. 1 off a tile's
+    start, which interpret mode does not check."""
+    from mxnet_tpu.ops.pallas import conv1d
+
+    def step(x, w, b):
+        def loss(x, w, b):
+            return _sum(conv1d.causal_conv1d(x, w, b if bias else None, "silu",
+                                             interpret=False))
+        return jax.value_and_grad(loss, (0, 1, 2) if bias else (0, 1))(x, w, b)
+
+    assert conv1d.tiles((1, 8192, channels), 4, dtype) == (1024, 256, 64, 512)
+    text = _compiled_text(step, one_chip, ((1, 8192, channels), dtype),
+                          ((channels, 4), dtype), ((channels,), dtype))
+    _assert_kernels(text, "mxtpu_conv1d_fwd", "mxtpu_conv1d_bwd")
