@@ -945,4 +945,5 @@ class SymbolBlock(HybridBlock):
 _BUILD_TALLIES = ("flash_tiles", "flash_tiles_live", "dsa_layers",
                   "dsa_topk_chunks", "dsa_topk_chunks_live",
                   "conv1d_calls", "conv1d_kernel_calls",
-                  "mla_rope_calls", "mtp_modules")
+                  "mla_rope_calls", "mtp_modules",
+                  "flash_calls", "flash_two_width_calls")

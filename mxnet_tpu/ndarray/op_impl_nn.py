@@ -473,11 +473,11 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
     skipped whole.
 
     The q.k width and the v width may differ (latent attention: 192 and
-    128). The default scale is 1/sqrt of the q.k width. The kernel has one
-    head width, so the Pallas path zero-pads q, k and v to the next
-    multiple of 128 of the wider one and cuts the output back to v's: the
-    pad adds nothing to q.k and its output columns are dropped. The jnp
-    fallback needs no pad.
+    128). The default scale is 1/sqrt of the q.k width. The Pallas path
+    zero-pads q and k to the next multiple of 128 of the wider width (the
+    pad adds nothing to q.k); the kernel takes v at its own width where
+    v's multiple of 128 is narrower (latent attention's v passes as it is),
+    else v is padded with them and the output cut back (`_pad_widths`).
 
     ``window=W`` (with ``causal``; static): a token sees itself and the
     W - 1 keys before it. The kernel walks only the tiles the band crosses.
@@ -526,9 +526,9 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
         q_off = key.shape[2] - query.shape[2] if causal else 0
         if d != dv:
             wide = -(-max(d, dv) // 128) * 128
-            query, key, value = (
-                jnp.pad(t, ((0, 0),) * 3 + ((0, wide - t.shape[-1]),))
-                for t in (query, key, value))
+            # q and k padded to ``wide``; v too unless its own multiple of 128
+            # is narrower (latent attention): `_pad_widths`, at the file's end
+            query, key, value = _pad_widths(query, key, value, wide)
             sm_scale = scale
         out = _pallas.flash_attention(query, key, value, sm_scale,
                                       bool(causal), q_off, None, valid_len,
@@ -1320,3 +1320,20 @@ def _kept(loss, mask):
     if mask is None:
         return loss
     return loss * lax.stop_gradient(mask).astype(loss.dtype).reshape(loss.shape)
+
+
+# ----------------------------------------------------------------------
+# ``flash_attention_op``'s pads at unequal widths, at this file's end for
+# the reason above.
+# ----------------------------------------------------------------------
+def _pad_widths(query, key, value, wide):
+    """q and k zero-padded to ``wide`` (the wider width's multiple of 128).
+    Where v's own multiple of 128 is narrower (latent attention: q.k 192
+    against v 128), v keeps it and the kernel runs p.v, o, do and dv at that
+    width; else (q.k 64 against v 128) v is padded to ``wide`` too."""
+    pad = lambda t, w: jnp.pad(t, ((0, 0),) * 3 + ((0, w - t.shape[-1]),))  # noqa: E731
+    lanes = -(-value.shape[-1] // 128) * 128
+    if lanes >= wide:
+        return tuple(pad(t, wide) for t in (query, key, value))
+    return (pad(query, wide), pad(key, wide),
+            value if lanes == value.shape[-1] else pad(value, lanes))

@@ -14,7 +14,10 @@ online-softmax (differentiable through lax.scan) rather than this
 forward-only kernel; this entry point serves external combiners and
 golden tests.
 
-Shapes: q (B, H, Sq, D), k/v (B, H, Skv, D). ``q_offset`` is the
+Shapes: q, k (B, H, Sq / Skv, D), v (B, H, Skv, Dv): q.k's width D and
+v's Dv are read apart, so latent attention (q.k 192 padded to 256 by the
+op, v 128) runs p.v, o, do and dv at 128 columns; Dv == D is the one-width
+call every other attention layer makes. ``q_offset`` is the
 global position of q row 0 relative to k row 0 (ring attention passes
 the rotating chunk offset; 0 for vanilla causal). The same
 Sq != Skv + offset geometry is what the decode engine's CHUNKED
@@ -100,7 +103,8 @@ kernels trace what they traced before it existed
 Trace-time tallies (`profiler.counters()`): every forward or backward
 call adds the tiles of its (q tiles x kv tiles) rectangle to
 ``flash_tiles`` and those its static masks (causal, window) leave live
-to ``flash_tiles_live``.
+to ``flash_tiles_live``; it counts once in ``flash_calls``, and in
+``flash_two_width_calls`` too where v is narrower than q.k.
 """
 from __future__ import annotations
 
@@ -763,7 +767,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, kv_lens=None,
                segment_ids=None, window=None, pair_mask=None):
     b, h, sq, d = q.shape
-    skv = k.shape[2]
+    skv, dv = k.shape[2], v.shape[-1]     # q.k's width d, v's own dv
     if segment_ids is not None and sq != skv:
         raise ValueError(
             f"segment_ids (packing) requires self-attention shapes, got "
@@ -780,7 +784,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
     # elementwise *sm_scale (dq is rescaled on its small output block)
     qf = (q * sm_scale).astype(q.dtype).reshape(b * h, sq, d)
     kf = k.reshape(b * hk, skv, d)
-    vf = v.reshape(b * hk, skv, d)
+    vf = v.reshape(b * hk, skv, dv)
     if sq_p != sq:
         qf = _pad0(qf, ((0, 0), (0, sq_p - sq), (0, 0)))
     if skv_p != skv:
@@ -794,6 +798,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
         else jnp.full((bh,), skv, jnp.int32)
     nq, nk = sq_p // block_q, skv_p // block_k
     _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk)
+    _tally_widths(d, dv)
     # a window's walk: the inner axis holds the band's tiles, not the row's
     narrow = (nq, nk) if window is not None else None
     kv_steps = _band_steps(window, q_offset, block_q, block_k, nq, nk)[0] \
@@ -809,7 +814,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, dv), kv_map, memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, kf, vf, kvlf]
@@ -825,24 +830,24 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
         grid=(bh, nq, kv_steps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
+            pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, sq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         name="mxtpu_flash_fwd",
         interpret=interpret,
     )(*operands)
-    o = o[:, :sq].reshape(b, h, sq, d)
+    o = o[:, :sq].reshape(b, h, sq, dv)
     lse = lse[:, :sq, 0].reshape(b, h, sq)
     return o, lse
 
@@ -852,7 +857,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, dlse=None, kv_lens=None,
                segment_ids=None, window=None, pair_mask=None):
     b, h, sq, d = q.shape
-    skv = k.shape[2]
+    skv, dv = k.shape[2], v.shape[-1]
     hk = k.shape[1]        # the forward checked the heads
     group = h // hk
     bq0, bk0 = _pick_blocks(sq, skv, window)
@@ -879,8 +884,8 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     # IS the original-k gradient, dq rescales by sm_scale at the write
     qf = (q * sm_scale).astype(q.dtype).reshape(bh, sq, d)
     kf = k.reshape(b * hk, skv, d)
-    vf = v.reshape(b * hk, skv, d)
-    dof = do.reshape(bh, sq, d)
+    vf = v.reshape(b * hk, skv, dv)
+    dof = do.reshape(bh, sq, dv)
     lsef = lse.reshape(bh, sq, 1)
     if sq_p != sq:
         pad = ((0, 0), (0, sq_p - sq), (0, 0))
@@ -896,6 +901,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
 
     nq, nk = sq_p // block_q, skv_p // block_k
     _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk)
+    _tally_widths(d, dv)
     common = dict(sm_scale=sm_scale, causal=causal, q_offset=q_offset,
                   kv_len=skv, block_q=block_q, block_k=block_k,
                   precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
@@ -909,11 +915,11 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     # dq accumulates in VMEM scratch
     if nk <= 2:
         return _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
-                                (b, h, sq, skv, d), nq, nk, common,
+                                (b, h, sq, skv, d, dv), nq, nk, common,
                                 interpret, k.dtype, v.dtype, q.dtype, group,
                                 mask_ops)
     return _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
-                            (b, h, sq, skv, d), nq, nk, common,
+                            (b, h, sq, skv, d, dv), nq, nk, common,
                             interpret, k.dtype, v.dtype, q.dtype, group,
                             mask_ops)
 
@@ -925,7 +931,7 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     blocks: ``bert_base.train_b64x512`` (S=512, nk=1) runs this kernel;
     ``kimi_linear_48b_a3b.train_8k``'s latent attention (S=8192 at the
     2048 cap, nk=4) runs ``_flash_bwd_split``'s dq/dkv pair."""
-    b, h, sq, skv, d = dims
+    b, h, sq, skv, d, dv = dims
     bh = b * h
     block_q, block_k = common["block_q"], common["block_k"]
     sq_p, skv_p = nq * block_q, nk * block_k
@@ -944,9 +950,9 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
         pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
+        pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, dv), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -958,7 +964,7 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     if mask_ops is not None:
         in_specs += _mask_specs(block_q, block_k, h // group, True, group, nq)
         operands += mask_ops
-    dq_part, dk, dv = pl.pallas_call(
+    dq_part, dk, dvg = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, **common),
         grid=(bh // group, nk, group * nq),
         in_specs=in_specs,
@@ -967,7 +973,7 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
+            pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
@@ -975,11 +981,11 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
             # kernel in f32, then casts once to the caller dtype
             jax.ShapeDtypeStruct((bh, nk, sq_p, d), jnp.float32),
             jax.ShapeDtypeStruct((bh // group, skv_p, d), k_dtype),
-            jax.ShapeDtypeStruct((bh // group, skv_p, d), v_dtype),
+            jax.ShapeDtypeStruct((bh // group, skv_p, dv), v_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=_BWD_COMPILER_PARAMS,
         name="mxtpu_flash_bwd_fused",
@@ -990,14 +996,14 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
         else dq_part[:, 0].astype(q_dtype)
     dq = dq[:, :sq].reshape(b, h, sq, d)
     dk = dk[:, :skv].reshape(b, h // group, skv, d)
-    dv = dv[:, :skv].reshape(b, h // group, skv, d)
-    return dq, dk, dv
+    dvg = dvg[:, :skv].reshape(b, h // group, skv, dv)
+    return dq, dk, dvg
 
 
 def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                      nq, nk, common, interpret, k_dtype, v_dtype, q_dtype,
                      group=1, mask_ops=None):
-    b, h, sq, skv, d = dims
+    b, h, sq, skv, d, dv = dims
     bh = b * h
     block_q, block_k = common["block_q"], common["block_k"]
     sq_p, skv_p = nq * block_q, nk * block_k
@@ -1014,8 +1020,8 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), kv_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
+        pl.BlockSpec((1, block_k, dv), kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
@@ -1047,9 +1053,9 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
         pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
+        pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, dv), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_q, 1), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1059,23 +1065,23 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     if mask_ops is not None:
         dkv_specs += _mask_specs(block_q, block_k, h // group, True, group,
                                  q_steps)
-    dk, dv = pl.pallas_call(
+    dk, dvg = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         grid=(bh // group, nk, group * q_steps),
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
+            pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh // group, skv_p, d), k_dtype),
-            jax.ShapeDtypeStruct((bh // group, skv_p, d), v_dtype),
+            jax.ShapeDtypeStruct((bh // group, skv_p, dv), v_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=_BWD_COMPILER_PARAMS,
         name="mxtpu_flash_bwd_dkv",
@@ -1084,8 +1090,8 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
 
     dq = dq[:, :sq].reshape(b, h, sq, d)
     dk = dk[:, :skv].reshape(b, h // group, skv, d)
-    dv = dv[:, :skv].reshape(b, h // group, skv, d)
-    return dq, dk, dv
+    dvg = dvg[:, :skv].reshape(b, h // group, skv, dv)
+    return dq, dk, dvg
 
 
 def _int_ct(x):
@@ -1184,9 +1190,11 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, q_offset=0,
     blockwise in VMEM. ``kv_lens`` (B,) int32 masks keys at/after each
     example's valid length (variable-length batches, e.g. BERT
     padding); ``segment_ids`` (B, S) int32 makes attention
-    block-diagonal over packed sequences (see module docstring). q, k
-    and v share one head width; ``mx.nd.flash_attention`` pads a wider
-    q.k (192 against v's 128) up to it and passes the true ``sm_scale``.
+    block-diagonal over packed sequences (see module docstring). v may
+    be narrower than q and k (q, k (..., D), v (..., Dv)): the output,
+    dv and the p.v products are Dv wide (``mx.nd.flash_attention`` pads
+    latent attention's q.k 192 to 256, leaves v at 128 and passes the
+    true ``sm_scale``).
     ``window`` (static, with ``causal``): a token sees itself and the
     ``window - 1`` keys before it; tiles outside the band are not walked.
     k and v may have fewer heads than q (q (B, H, S, D), k/v (B, Hk, S, D),
@@ -1361,8 +1369,8 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, kv_lens,
     and runs a plain max-subtracted softmax. Every row's computation
     is independent of the others — the property the join/leave
     solo-parity golden leans on. One head width ``d`` for q, k and v, as
-    the kernels here: unequal q.k and v widths (latent attention) are the
-    op's business (``flash_attention_op`` zero-pads them to one width)."""
+    the paged kernel: unequal q.k and v widths (latent attention) are the
+    training kernel's (``flash_attention`` takes v at its own width)."""
     b, h, sq, d = q.shape
     page_size = k_pages.shape[2]
     npages = page_table.shape[1]
@@ -1390,3 +1398,11 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, kv_lens,
     l = jnp.where(l == 0.0, np.float32(1.0), l)
     return (jnp.einsum("bhqk,bhkd->bhqd", p / l,
                        v.astype(jnp.float32))).astype(q.dtype)
+
+
+def _tally_widths(d, dv):
+    """Trace-time: ``flash_calls`` += 1 a forward or backward call,
+    ``flash_two_width_calls`` += 1 where v's width is narrower than q.k's."""
+    _profiler.count("flash_calls")
+    if dv < d:
+        _profiler.count("flash_two_width_calls")

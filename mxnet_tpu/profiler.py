@@ -56,7 +56,8 @@ _LOCK = threading.Lock()
 # attention's selection kernel "dsa_topk_chunks" and "_live", the short
 # convolution `F.causal_conv1d` "conv1d_calls" and, its kernel pair taken,
 # "conv1d_kernel_calls", the latent attention's rotation (`MLAMixer` with
-# `rope_theta`) "mla_rope_calls" and `MTPModule` "mtp_modules", once a call
+# `rope_theta`) "mla_rope_calls" and `MTPModule` "mtp_modules", the flash
+# kernel's wrappers "flash_calls" and "flash_two_width_calls", once a call
 # as traced. Not locked: a span reads the difference on its own
 # thread, which is exact while no other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
@@ -64,7 +65,8 @@ _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "flash_tiles": 0, "flash_tiles_live": 0, "dsa_layers": 0,
            "dsa_topk_chunks": 0, "dsa_topk_chunks_live": 0,
            "conv1d_calls": 0, "conv1d_kernel_calls": 0,
-           "mla_rope_calls": 0, "mtp_modules": 0}
+           "mla_rope_calls": 0, "mtp_modules": 0,
+           "flash_calls": 0, "flash_two_width_calls": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -176,7 +178,11 @@ def counters(device=True):
     ``F.causal_conv1d`` traced so far, and those of them that took the Pallas
     kernel pair ``mxtpu_conv1d_fwd`` / ``_bwd`` (on the chip, at a shape its
     tiles divide); equal on a chip at published widths, the second 0 where
-    the ``jax.numpy`` form ran. Trace-time tallies, flat across steps.
+    the ``jax.numpy`` form ran. ``flash_calls`` / ``flash_two_width_calls``:
+    flash attention kernel calls, forward and backward, traced so far, and
+    those of them whose v is narrower than q.k (latent attention's 128
+    against 256: p.v, o, do and dv run at v's width); 0 of the second where
+    every call has one width. Trace-time tallies, flat across steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and

@@ -89,7 +89,8 @@ _FLASH = {
                              (256, 256)),
     "packed_s2048_causal_256x256": ((16, 12, 2048, 64), True, False, True,
                                     (256, 256)),
-    # latent attention at 8k: q.k 192 and v 128 reach the kernel padded to 256
+    # one width of 256 at 8k (latent attention's call at two widths:
+    # test_flash_two_widths_compile)
     "mla_s8192_d256_causal": ((1, 32, 8192, 256), True, False, False, None),
 }
 
@@ -457,3 +458,23 @@ def test_short_convolution_fwd_bwd_compiles(one_chip, channels, bias, dtype):
     text = _compiled_text(step, one_chip, ((1, 8192, channels), dtype),
                           ((channels, 4), dtype), ((channels,), dtype))
     _assert_kernels(text, "mxtpu_conv1d_fwd", "mxtpu_conv1d_bwd")
+
+
+@pytest.mark.parametrize("length", [8192, 2048], ids=["split_8192", "fused_2048"])
+def test_flash_two_widths_compile(one_chip, length):
+    """One row of latent attention at two head widths: 32 heads, q.k 192
+    padded to 256, v at its own 128, so the v, o, do and dv blocks, the
+    forward's accumulator and the backward's dv sum are 128 wide; both
+    backward arms."""
+    def step(q, k, v):
+        return jax.grad(lambda q, k, v: _sum(flash_attention(
+            q, k, v, 192 ** -0.5, True, 0, False)), argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(step, one_chip, ((1, 32, length, 256), BF16),
+                          ((1, 32, length, 256), BF16), ((1, 32, length, 128), BF16))
+    if length > 4096:
+        _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_dq",
+                        "mxtpu_flash_bwd_dkv")
+    else:
+        _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_fused")
+    assert f"bf16[32,{length},128]" in text

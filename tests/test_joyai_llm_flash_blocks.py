@@ -357,7 +357,10 @@ def test_only_the_built_settings_are_accepted():
 # sha256 of the parent's (PR 36) jaxprs, at toy widths in bfloat16: Kimi's
 # MLAMixer (NoPE, one query matrix) through `functionalize`, on the CPU's
 # flash twin and in interpret mode (the Pallas call with its pad to 256);
-# Keye's F.rope with three position streams
+# Keye's F.rope with three position streams. The kernel form keeps its hash
+# since the kernel took v at its own width: at these toy widths (q.k 16 + 8,
+# v 16) both round to one 128-lane tile, so the op pads all three as before;
+# the two-width calls are `tests/test_flash_two_widths.py`'s
 _PARENT = {
     ("kimi_mla", "forward", "twin"): "de2c65afde7bfec9acfa523f0a56c9e5f06003eb736c49fe3fc58c6aa7756739",
     ("kimi_mla", "gradient", "twin"): "402e6dc727882882fd1b9d3fcf43b9e876c7b51301c93e7a7975863d5c1785e7",
