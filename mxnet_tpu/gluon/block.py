@@ -946,4 +946,5 @@ _BUILD_TALLIES = ("flash_tiles", "flash_tiles_live", "dsa_layers",
                   "dsa_topk_chunks", "dsa_topk_chunks_live",
                   "conv1d_calls", "conv1d_kernel_calls",
                   "mla_rope_calls", "mtp_modules",
-                  "flash_calls", "flash_two_width_calls")
+                  "flash_calls", "flash_two_width_calls",
+                  "bdiff_calls", "bdiff_tiles", "bdiff_tiles_live")

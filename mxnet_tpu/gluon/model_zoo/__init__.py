@@ -11,5 +11,7 @@ from . import keye_vl2 as keye_vl2_zoo
 from .keye_vl2 import KeyeVL2Model, keye_vl2
 from . import joyai_llm_flash as joyai_llm_flash_zoo
 from .joyai_llm_flash import JoyAILLMFlashModel, joyai_llm_flash
+from . import sdar as sdar_zoo
+from .sdar import SDARModel, sdar
 from . import wide_deep as wide_deep_zoo
 from .wide_deep import WideDeep, wide_deep

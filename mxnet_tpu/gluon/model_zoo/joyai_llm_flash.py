@@ -35,25 +35,13 @@ other, through the one head.
 """
 from __future__ import annotations
 
-from .kimi_linear import _LMHead as _PlainHead, chunked_token_loss
+from .lm_head import _LMHead, chunked_token_loss
 from ..block import HybridBlock
 from ..nn import Embedding
 from ..nn.decoder import GatedMLP, HeldExperts, MLAMixer, MTPModule, RMSNorm
 from ..nn.transformer import remat_per_layer
 
 __all__ = ["JoyAILLMFlashModel", "JoyAILLMFlashLayer", "joyai_llm_flash"]
-
-
-class _LMHead(_PlainHead):
-    """The untied head; with labels the summed cross-entropy of its logits,
-    and with a ``mask`` (1 a row counted, 0 left out) the masked sum."""
-
-    def hybrid_forward(self, F, x, labels=None, mask=None):
-        if mask is None:
-            return super().hybrid_forward(F, x, labels)
-        return F.softmax_cross_entropy(
-            self.proj(F.reshape(x, shape=(-1, self._units))),
-            F.reshape(labels, shape=(-1,)), F.reshape(mask, shape=(-1,)))
 
 
 class JoyAILLMFlashLayer(HybridBlock):

@@ -31,7 +31,7 @@ to the step's loss with weight 1; they share no gradient path.
 """
 from __future__ import annotations
 
-from .kimi_linear import _LMHead, chunked_token_loss
+from .lm_head import _LMHead, chunked_token_loss
 from ..block import HybridBlock
 from ..nn import Embedding
 from ..nn.decoder import HeldExperts, RMSNorm, SparseGQAttention

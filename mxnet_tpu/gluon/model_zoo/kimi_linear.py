@@ -27,48 +27,13 @@ the summed token cross-entropy (1,), the head and the loss taken over
 """
 from __future__ import annotations
 
+from .lm_head import _LMHead, chunked_token_loss
 from ..block import HybridBlock
 from ..nn import Embedding
-from ..nn.decoder import (GatedMLP, HeldExperts, KDAMixer, MLAMixer, RMSNorm,
-                          _linear)
+from ..nn.decoder import GatedMLP, HeldExperts, KDAMixer, MLAMixer, RMSNorm
 from ..nn.transformer import remat_per_layer
 
 __all__ = ["KimiLinearModel", "KimiLinearLayer", "kimi_linear"]
-
-
-class _LMHead(HybridBlock):
-    """The untied head; with labels, the summed cross-entropy of its logits."""
-
-    def __init__(self, vocab, units, dtype, weight_initializer, prefix=None,
-                 params=None):
-        super().__init__(prefix=prefix, params=params)
-        self._units = units
-        with self.name_scope():
-            self.proj = _linear(vocab, units, dtype, weight_initializer, "head_")
-
-    def hybrid_forward(self, F, x, labels=None):
-        if labels is None:
-            return self.proj(x)
-        # a 2-D product, as F.softmax_cross_entropy wants its producer
-        return F.softmax_cross_entropy(
-            self.proj(F.reshape(x, shape=(-1, self._units))),
-            F.reshape(labels, shape=(-1,)))
-
-
-def chunked_token_loss(F, head, x, labels, chunks, *more):
-    """The summed loss of ``head(x, labels, *more)`` (``more``: other per-token
-    arrays, a mask) over ``chunks`` stretches of the sequence one after the
-    other (with the head marked for remat one stretch's logits are live)."""
-    seq = x.shape[1]
-    if seq % chunks:
-        raise ValueError(f"loss_chunks {chunks} does not divide the length {seq}")
-    loss = None
-    for i in range(chunks):
-        lo, hi = i * seq // chunks, (i + 1) * seq // chunks
-        part = head(*(F.slice_axis(a, axis=1, begin=lo, end=hi)
-                      for a in (x, labels) + more))
-        loss = part if loss is None else loss + part
-    return loss
 
 
 class KimiLinearLayer(HybridBlock):

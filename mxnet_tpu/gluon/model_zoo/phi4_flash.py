@@ -36,7 +36,7 @@ from ..block import HybridBlock
 from ..nn import Embedding, LayerNorm
 from ..nn.decoder import DiffAttention, GatedMemoryUnit, GatedMLP, MambaMixer
 from ..nn.transformer import remat_per_layer
-from .kimi_linear import chunked_token_loss
+from .lm_head import chunked_token_loss
 
 __all__ = ["Phi4FlashModel", "Phi4FlashLayer", "phi4_flash", "layer_kind"]
 

@@ -11,6 +11,7 @@ from .transformer import (
 from .decoder import (
     RMSNorm, GatedMLP, CausalConv1D, KDAMixer, MLAMixer, HeldExperts,
     MambaMixer, DiffAttention, GatedMemoryUnit, SparseGQAttention, MTPModule,
+    GQAttention,
 )
 from .conv_layers import (
     Conv1D, Conv2D, Conv3D, Conv1DTranspose, Conv2DTranspose, Conv3DTranspose,

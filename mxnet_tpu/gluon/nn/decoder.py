@@ -10,7 +10,8 @@ cross to another layer's keys and values) with grouped key/value heads, and
 a Gated Memory Unit that reuses another layer's scan output; grouped-query
 attention with rotary positions over the keys a learned indexer selects
 (DeepSeek-V3.2-Exp's, as Keye-VL-2.0's language model has it); and, at this
-file's end, DeepSeek-V3's multi-token prediction module.
+file's end, DeepSeek-V3's multi-token prediction module and grouped-query
+attention over all its keys under a block-diffusion mask.
 
 No upstream-gluon analog. Layout (batch, seq, units); pre-norm residual
 wiring is the model's (gluon/model_zoo/). Every block is a HybridBlock over
@@ -29,7 +30,7 @@ from ... import initializer as _init
 
 __all__ = ["RMSNorm", "GatedMLP", "CausalConv1D", "KDAMixer", "MLAMixer",
            "HeldExperts", "MambaMixer", "DiffAttention", "GatedMemoryUnit",
-           "SparseGQAttention", "MTPModule"]
+           "SparseGQAttention", "MTPModule", "GQAttention"]
 
 
 def _linear(units, in_units, dtype, init, prefix):
@@ -620,3 +621,57 @@ class MTPModule(HybridBlock):
         embed, head_loss = self._borrowed
         x = self.eh_proj(F.concat(self.enorm(embed(next_ids)), self.hnorm(h), dim=-1))
         return head_loss(F, self.final_norm(self.layer(x, positions)), labels, mask)
+
+
+# ----------------------------------------------------------------------
+# Grouped-query attention over all its keys (the Qwen3-MoE family's layer)
+# under block diffusion's mask; at this file's end for the reason above.
+# ----------------------------------------------------------------------
+class GQAttention(HybridBlock):
+    """q = W_q x (``num_heads`` of ``head_dim``), k = W_k x, v = W_v x
+    (``num_kv_heads``; query head h reads key/value head h // group); q and k
+    RMS-normalised a head (one gain of ``head_dim`` each), then rotated
+    (``F.rope``: rotate-half over all the columns, ``rope_theta``, one
+    position stream, ``positions`` (B, S)); softmax(q k^T / sqrt(head_dim) +
+    M) v (flash); W_o. No bias.
+
+    M is the block-diffusion mask of blocks of ``block_diffusion`` = β
+    (BD3-LM, Arriola et al. 2025): x (B, 2L, D) holds a clean copy of each row
+    and its noised copy, the clean first, both at positions 0 .. L - 1; a
+    clean token sees the clean tokens of its own and earlier blocks, a noisy
+    token the clean tokens of earlier blocks and the noisy tokens of its own
+    block (``F.flash_attention(block_diffusion=β)``, under the name scope
+    ``mxtpu_bdiff``)."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 block_diffusion, rope_theta=10000.0, epsilon=1e-6,
+                 dtype="float32", weight_initializer=None, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_kv_heads} key/value heads do not divide "
+                             f"{num_heads} query heads")
+        self._h, self._hk, self._eps = num_heads, num_kv_heads, epsilon
+        self._theta, self._block = rope_theta, block_diffusion
+        init = weight_initializer
+        with self.name_scope():
+            self.q_proj = _linear(num_heads * head_dim, units, dtype, init, "q_")
+            self.k_proj = _linear(num_kv_heads * head_dim, units, dtype, init, "k_")
+            self.v_proj = _linear(num_kv_heads * head_dim, units, dtype, init, "v_")
+            self.o_proj = _linear(units, num_heads * head_dim, dtype, init, "o_")
+            self.q_norm_gamma = self.params.get("q_norm_gamma", shape=(head_dim,),
+                                                init="ones")
+            self.k_norm_gamma = self.params.get("k_norm_gamma", shape=(head_dim,),
+                                                init="ones")
+
+    def hybrid_forward(self, F, x, positions, q_norm_gamma, k_norm_gamma):
+        q = F.RMSNorm(_split_heads(F, self.q_proj(x), self._h), q_norm_gamma,
+                      eps=self._eps)
+        k = F.RMSNorm(_split_heads(F, self.k_proj(x), self._hk), k_norm_gamma,
+                      eps=self._eps)
+        v = _split_heads(F, self.v_proj(x), self._hk)
+        q = F.rope(q, positions, theta=self._theta)
+        k = F.rope(k, positions, theta=self._theta)
+        out = F.flash_attention(q, k, v, block_diffusion=self._block,
+                                name_scope="mxtpu_bdiff")
+        return self.o_proj(_merge_heads(F, out))

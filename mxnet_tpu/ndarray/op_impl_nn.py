@@ -459,7 +459,7 @@ def causal_mask_scores(scores):
 @register_op("flash_attention")
 def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
                        causal=False, sm_scale=None, window=None,
-                       name_scope=None, pair_mask=None):
+                       name_scope=None, pair_mask=None, block_diffusion=None):
     """softmax(Q K^T * scale) V over (B, H, S, D) inputs.
 
     Pallas flash kernel on TPU (O(S) memory); jnp fallback elsewhere.
@@ -495,7 +495,15 @@ def flash_attention_op(query, key, value, valid_len=None, segment_ids=None,
     ``causal`` allows; one mask for all heads. The kernel reads it a tile at
     a time and skips the tiles it leaves empty; it goes with neither
     ``valid_len``, ``segment_ids`` nor ``window``.
+    ``block_diffusion=β`` (static; `_block_diffusion_attention`, at this
+    file's end): q, k and v span a row's clean copy and its noised copy, the
+    clean first, under the block-diffusion mask of blocks of β; it goes with
+    no other mask argument.
     """
+    if block_diffusion is not None:
+        return _block_diffusion_attention(query, key, value, block_diffusion,
+                                          sm_scale, name_scope, causal, valid_len,
+                                          segment_ids, window, pair_mask)
     if name_scope is not None:
         with jax.named_scope(name_scope):
             return flash_attention_op(query, key, value, valid_len,
@@ -1337,3 +1345,44 @@ def _pad_widths(query, key, value, wide):
         return tuple(pad(t, wide) for t in (query, key, value))
     return (pad(query, wide), pad(key, wide),
             value if lanes == value.shape[-1] else pad(value, lanes))
+
+
+# ----------------------------------------------------------------------
+# ``flash_attention_op``'s block-diffusion mask, at this file's end for the
+# reason above.
+# ----------------------------------------------------------------------
+def _block_diffusion_attention(query, key, value, block, sm_scale, name_scope,
+                               *others):
+    """Attention over a clean copy and a noised copy of each row, (B, H, 2L,
+    D) with the clean L positions first, under the block-diffusion mask of
+    blocks of ``block`` (``ops/pallas/flash_attention.py``
+    ``flash_attention_block_diffusion``): the Pallas kernels on the chip,
+    everywhere else the same mask as one array and a plain softmax.
+    ``others`` (causal, valid_len, segment_ids, window, pair_mask) must be
+    unset."""
+    from ..ops import pallas as _pallas
+    from ..ops.pallas.flash_attention import block_diffusion_mask
+
+    if any(o not in (None, False) for o in others):
+        raise ValueError("flash_attention: block_diffusion goes with no other "
+                         "mask (causal, valid_len, segment_ids, window, "
+                         "pair_mask)")
+    if name_scope is not None:
+        with jax.named_scope(name_scope):
+            return _block_diffusion_attention(query, key, value, block, sm_scale,
+                                              None)
+    scale = sm_scale if sm_scale is not None else 1.0 / (query.shape[-1] ** 0.5)
+    if (_pallas.pallas_ok_for(query)
+            and query.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
+            and query.ndim == 4):
+        return _pallas.flash_attention_block_diffusion(query, key, value,
+                                                       int(block), scale)
+    group = query.shape[1] // key.shape[1]
+    if group > 1:
+        key, value = (jnp.repeat(t, group, axis=1) for t in (key, value))
+    s = jnp.einsum("bhqd,bhkd->bhqk", query.astype(jnp.float32),
+                   key.astype(jnp.float32)) * scale
+    mask = block_diffusion_mask(s.shape[-1], int(block))
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p,
+                      value.astype(jnp.float32)).astype(query.dtype)

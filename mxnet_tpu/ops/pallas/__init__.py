@@ -48,6 +48,7 @@ from ._util import interpret_mode, pallas_enabled, pallas_ok_for  # noqa: F401
 
 from .layer_norm import layer_norm_fused  # noqa: E402
 from .flash_attention import flash_attention, flash_attention_with_lse  # noqa: E402
+from .flash_attention import flash_attention_block_diffusion  # noqa: E402
 from .flash_attention import (paged_attention_reference,  # noqa: E402
                               paged_flash_attention)
 from .softmax_xent import softmax_xent_fused  # noqa: E402
@@ -63,6 +64,7 @@ __all__ = [
     "layer_norm_fused",
     "flash_attention",
     "flash_attention_with_lse",
+    "flash_attention_block_diffusion",
     "paged_flash_attention",
     "paged_attention_reference",
     "softmax_xent_fused",

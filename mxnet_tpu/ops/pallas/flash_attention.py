@@ -204,7 +204,7 @@ def _tile_mask(pm_ref, smask):
 
 
 def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-               kvl=None, smask=None, window=None):
+               kvl=None, smask=None, window=None, bdiff=None):
     """Validity mask for the (i, j) score block, or None when every
     position is statically visible (no kv padding, not causal, no
     per-example length, no segments) — the common dense shape skips the
@@ -232,11 +232,14 @@ def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
         if window is not None:   # itself and the window - 1 before it
             cm = jnp.logical_and(cm, row - col < window)
         mask = cm if mask is None else jnp.logical_and(mask, cm)
+    if bdiff is not None:    # (half, block): `_bdiff_mask`, at the file's end
+        bm = _bdiff_mask(i, j, block_q, block_k, *bdiff)
+        mask = bm if mask is None else jnp.logical_and(mask, bm)
     return mask
 
 
 def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                   segrng=None, window=None, live=None):
+                   segrng=None, window=None, live=None, bdiff=None):
     """Whether the (i, j) tile has ANY live score: causal skip, the
     window skip (the tile's last key is older than the first row's
     window), the per-example length skip (tiles starting at/after kvl
@@ -257,6 +260,10 @@ def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                                                    kmin <= qmax))
     if live is not None:
         vis = jnp.logical_and(vis, live != 0)
+    if bdiff is not None:
+        vis = jnp.logical_and(vis, _bdiff_live(
+            i * block_q, (i + 1) * block_q - 1, j * block_k,
+            (j + 1) * block_k - 1, *bdiff))
     return vis
 
 
@@ -287,7 +294,8 @@ def _band_steps(window, q_offset, block_q, block_k, nq, nk):
             max(1, int((last_q - first_q + 1).max())))
 
 
-def _tally_tiles(rows, causal, window, q_offset, block_q, block_k, nq, nk):
+def _tally_tiles(rows, causal, window, q_offset, block_q, block_k, nq, nk,
+                 bdiff=None):
     """Trace-time: ``flash_tiles`` += the tiles of this call's score
     rectangles, ``flash_tiles_live`` += those the static masks leave live."""
     i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
@@ -296,6 +304,10 @@ def _tally_tiles(rows, causal, window, q_offset, block_q, block_k, nq, nk):
         live &= j * block_k <= (i + 1) * block_q - 1 + q_offset
     if window is not None:
         live &= (j + 1) * block_k - 1 >= i * block_q + q_offset - (window - 1)
+    if bdiff is not None:
+        live &= _bdiff_live(i * block_q, (i + 1) * block_q - 1, j * block_k,
+                            (j + 1) * block_k - 1, *bdiff, xp=np)
+        _tally_bdiff(rows * nq * nk, rows * int(live.sum()))
     _profiler.count("flash_tiles", rows * nq * nk)
     _profiler.count("flash_tiles_live", rows * int(live.sum()))
 
@@ -303,7 +315,7 @@ def _tally_tiles(rows, causal, window, q_offset, block_q, block_k, nq, nk):
 def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
                 sm_scale, causal, q_offset, kv_len, block_q, block_k,
                 precision, dynamic_kv, dynamic_seg, n_heads,
-                window=None, narrow=None, dynamic_mask=False):
+                window=None, narrow=None, dynamic_mask=False, bdiff=None):
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (o_ref, lse_ref, acc_sc, m_sc, l_sc)) = _split_rest(
          rest, dynamic_seg, dynamic_mask)
@@ -325,7 +337,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
     # disjoint, or one in which the pair mask leaves nothing
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
-                             window, _tile_live(live_ref, i, j, n_heads))
+                             window, _tile_live(live_ref, i, j, n_heads),
+                             bdiff)
     if narrow:
         visible = jnp.logical_and(visible, j < narrow[1])
 
@@ -342,7 +355,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
         smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
                            if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window)
+                          kvl, smask, window, bdiff)
         if mask is not None:
             s = jnp.where(mask, s, np.float32(_NEG_INF))
 
@@ -377,7 +390,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    sm_scale, causal, q_offset, kv_len, block_q, block_k,
                    precision, dynamic_kv, dynamic_seg, n_heads,
                    window=None, narrow=None, group=1, steps=None,
-                   dynamic_mask=False):
+                   dynamic_mask=False, bdiff=None):
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (dq_ref, dq_sc)) = _split_rest(rest, dynamic_seg, dynamic_mask)
     i, jj = pl.program_id(1), pl.program_id(2)
@@ -392,7 +405,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
-                             window, _tile_live(live_ref, i, j, n_heads))
+                             window, _tile_live(live_ref, i, j, n_heads),
+                             bdiff)
     if narrow:
         visible = jnp.logical_and(visible, j < narrow[1])
 
@@ -411,7 +425,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
                            if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window)
+                          kvl, smask, window, bdiff)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
         dp = jax.lax.dot_general(
@@ -443,7 +457,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     sm_scale, causal, q_offset, kv_len, block_q, block_k,
                     precision, dynamic_kv, dynamic_seg, n_heads,
                     window=None, narrow=None, group=1, steps=None,
-                    dynamic_mask=False):
+                    dynamic_mask=False, bdiff=None):
     # grid: (B*Hk, nk, group * q steps) — q is the inner (sequential) axis
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (dk_ref, dv_ref, dk_sc, dv_sc)) = _split_rest(
@@ -463,7 +477,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
                              window,
-                             _tile_live(live_ref, i, j, n_heads // group))
+                             _tile_live(live_ref, i, j, n_heads // group),
+                             bdiff)
     if narrow:
         visible = jnp.logical_and(visible, i < narrow[0])
 
@@ -482,7 +497,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
                            if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window)
+                          kvl, smask, window, bdiff)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
 
@@ -508,7 +523,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       sm_scale, causal, q_offset, kv_len, block_q, block_k,
                       precision, dynamic_kv, dynamic_seg, n_heads,
                       window=None, narrow=None, group=1, steps=None,
-                      dynamic_mask=False):
+                      dynamic_mask=False, bdiff=None):
     """One-pass backward: dq, dk, dv from a SINGLE traversal of the
     (q block, k block) grid — the score matrix s and dp are computed
     once per pair instead of once in a dq kernel and again in a dkv
@@ -539,7 +554,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
                              window,
-                             _tile_live(live_ref, i, j, n_heads // group))
+                             _tile_live(live_ref, i, j, n_heads // group),
+                             bdiff)
 
     @pl.when(visible)
     def _():
@@ -556,7 +572,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
                            if dynamic_seg else None)
         mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window)
+                          kvl, smask, window, bdiff)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
 
@@ -765,7 +781,7 @@ def _mask_specs(block_q, block_k, rows, transposed_grid, group=1, steps=None):
 @x32
 def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, kv_lens=None,
-               segment_ids=None, window=None, pair_mask=None):
+               segment_ids=None, window=None, pair_mask=None, bdiff=None):
     b, h, sq, d = q.shape
     skv, dv = k.shape[2], v.shape[-1]     # q.k's width d, v's own dv
     if segment_ids is not None and sq != skv:
@@ -797,7 +813,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
     kvlf = _expand_kv_lens(kv_lens, b, h) if dynamic_kv \
         else jnp.full((bh,), skv, jnp.int32)
     nq, nk = sq_p // block_q, skv_p // block_k
-    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk)
+    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk, bdiff)
     _tally_widths(d, dv)
     # a window's walk: the inner axis holds the band's tiles, not the row's
     narrow = (nq, nk) if window is not None else None
@@ -808,7 +824,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
         q_offset=q_offset, kv_len=skv, block_q=block_q, block_k=block_k,
         precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
         dynamic_seg=dynamic_seg, n_heads=h, window=window, narrow=narrow,
-        dynamic_mask=pair_mask is not None)
+        dynamic_mask=pair_mask is not None, bdiff=bdiff)
     kv_map = _kv_map(group, window, narrow, q_offset, block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
@@ -855,7 +871,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
 @x32
 def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
                block_q=None, block_k=None, dlse=None, kv_lens=None,
-               segment_ids=None, window=None, pair_mask=None):
+               segment_ids=None, window=None, pair_mask=None, bdiff=None):
     b, h, sq, d = q.shape
     skv, dv = k.shape[2], v.shape[-1]
     hk = k.shape[1]        # the forward checked the heads
@@ -900,14 +916,14 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
         kf, vf = _pad0(kf, pad), _pad0(vf, pad)
 
     nq, nk = sq_p // block_q, skv_p // block_k
-    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk)
+    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk, bdiff)
     _tally_widths(d, dv)
     common = dict(sm_scale=sm_scale, causal=causal, q_offset=q_offset,
                   kv_len=skv, block_q=block_q, block_k=block_k,
                   precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
                   dynamic_seg=seg_ops is not None, n_heads=h,
                   window=window, group=group,
-                  dynamic_mask=mask_ops is not None)
+                  dynamic_mask=mask_ops is not None, bdiff=bdiff)
 
     # the fused pass writes nk f32 dq-partial copies to HBM; past nk=2
     # that memory/write cliff outweighs the recompute saving, so long
@@ -1115,7 +1131,7 @@ REMAT_KEEP = ("flash_out", "flash_lse")
 
 
 def _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret, kv_lens,
-               segment_ids, window=None, pair_mask=None):
+               segment_ids, window=None, pair_mask=None, bdiff=None):
     """The forward that the primals and the VJPs' forward rules share:
     (out, lse) under their `REMAT_KEEP` names."""
     if sm_scale is None:
@@ -1123,7 +1139,7 @@ def _fwd_named(q, k, v, sm_scale, causal, q_offset, interpret, kv_lens,
     o, lse = _flash_fwd(q, k, v, sm_scale, bool(causal), int(q_offset),
                         resolve_interpret(interpret), kv_lens=kv_lens,
                         segment_ids=segment_ids, window=window,
-                        pair_mask=pair_mask)
+                        pair_mask=pair_mask, bdiff=bdiff)
     named = []
     for name, x in zip(REMAT_KEEP, (o, lse)):
         _profiler.note_named(name, x)   # trace time: `remat_kept`'s tally
@@ -1406,3 +1422,121 @@ def _tally_widths(d, dv):
     _profiler.count("flash_calls")
     if dv < d:
         _profiler.count("flash_two_width_calls")
+
+
+# ----------------------------------------------------------------------
+# A STATIC BLOCK-DIFFUSION MASK (BD3-LM, Arriola et al., ICLR 2025;
+# ``flash_attention_block_diffusion``; ``bdiff`` = (L, block) in the
+# kernels): q, k and v span a row's clean copy and its noised copy, the clean
+# L positions first. Inside a live tile the mask is written from indices
+# (``_bdiff_mask``); a tile it leaves empty is skipped whole (``_bdiff_live``,
+# from the tile's corners), in every arm and with grouped heads; no (B, 2L,
+# 2L) operand is read. Absent, every other call traces the jaxpr it traced
+# before (``tests/test_sdar_blocks.py``). A call also adds to the tallies
+# ``bdiff_calls``, ``bdiff_tiles`` and ``bdiff_tiles_live``.
+# ----------------------------------------------------------------------
+def _blocks_of(x, block):
+    """``x // block`` for non-negative integers (a shift where ``block`` is a
+    power of two); numpy arrays, traced scalars and vectors alike."""
+    if block & (block - 1) == 0:
+        return x >> (int(block).bit_length() - 1)
+    return x // block
+
+
+def _bdiff_live(r0, r1, c0, c1, half, block, xp=jnp):
+    """Whether rows [r0, r1] x keys [c0, c1] (inclusive; positions of the 2 *
+    ``half`` of a clean-first row, past it tile padding) hold a pair the
+    block-diffusion mask leaves live: a clean row sees the clean keys of its
+    own and earlier blocks; a noisy row the clean keys of earlier blocks and
+    the noisy keys of its own block; a clean row no noisy key."""
+    n, blk = 2 * half, functools.partial(_blocks_of, block=block)
+    r1, c1 = xp.minimum(r1, n - 1), xp.minimum(c1, n - 1)
+    clean_r1, clean_c1 = xp.minimum(r1, half - 1), xp.minimum(c1, half - 1)
+    clean_keys = c0 <= clean_c1
+    clean_rows = (r0 <= clean_r1) & clean_keys & (blk(c0) <= blk(clean_r1))
+    noisy_rows = (r1 >= half) & (r0 <= r1)
+    a0, a1 = xp.maximum(r0, half) - half, r1 - half
+    b0, b1 = xp.maximum(c0, half) - half, c1 - half
+    noisy_clean = noisy_rows & clean_keys & (blk(c0) < blk(a1))
+    noisy_noisy = (noisy_rows & (c1 >= half) & (c0 <= c1)
+                   & (blk(b0) <= blk(a1)) & (blk(a0) <= blk(b1)))
+    return clean_rows | noisy_clean | noisy_noisy
+
+
+def _bdiff_mask(i, j, block_q, block_k, half, block):
+    """The (block_q, block_k) block-diffusion mask of tile (i, j), from a
+    column of row positions and a row of key positions: a key's code is its
+    block, + ``half`` if it is noisy; a row sees the codes up to its last
+    visible clean block and, if noisy, the one code of its own noisy block."""
+    row = i * block_q + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    col = j * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    rn = (row >= half).astype(jnp.int32)
+    cn = (col >= half).astype(jnp.int32)
+    rb = _blocks_of(row - half * rn, block)
+    code = _blocks_of(col - half * cn, block) + half * cn
+    last_clean = rb - rn
+    own = jnp.where(rn != 0, rb + half, -1)
+    return jnp.logical_or(code <= last_clean, code == own)
+
+
+def block_diffusion_mask(n, block):
+    """The (n, n) boolean mask the kernels apply, as one array (the op's
+    ``jax.numpy`` form): n positions, the clean copy's n / 2 first."""
+    return _bdiff_mask(0, 0, n, n, n // 2, int(block))
+
+
+def _tally_bdiff(tiles, live):
+    """Trace-time: ``bdiff_calls`` += 1 a forward or backward call,
+    ``bdiff_tiles`` / ``bdiff_tiles_live`` += its tiles and those the mask
+    leaves live."""
+    _profiler.count("bdiff_calls")
+    _profiler.count("bdiff_tiles", tiles)
+    _profiler.count("bdiff_tiles_live", live)
+
+
+def _bdiff_args(q, k, v, block):
+    if q.shape[2] != k.shape[2] or q.shape[2] % 2 or int(block) < 1:
+        raise ValueError(
+            f"block diffusion needs one even length for q and k (a clean and a "
+            f"noised copy) and a block of at least 1, got {q.shape[2]}, "
+            f"{k.shape[2]} and {block}")
+    _check_heads(q, k, v, None, False, None)
+    return q.shape[2] // 2, int(block)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention_block_diffusion(q, k, v, block, sm_scale=None,
+                                    interpret=None):
+    """softmax(q k^T * scale + M) v over a row's clean copy and its noised
+    copy, the clean copy first: q, k (B, H|Hk, 2L, D), v (B, Hk, 2L, Dv), L
+    positions each, both copies at positions 0 .. L - 1. ``block`` (static)
+    is the diffusion block length; with blk(t) = (t mod L) // block, M lets a
+    clean query t see the clean keys s with blk(s) <= blk(t); a noisy query
+    the clean keys with blk(s) < blk(t) and the noisy keys with blk(s) =
+    blk(t); a clean query no noisy key. From indices in every arm (forward,
+    fused backward, dq and dkv), with grouped key/value heads; tiles the mask
+    leaves empty are skipped whole. Tallies ``bdiff_calls`` / ``bdiff_tiles``
+    / ``bdiff_tiles_live``; the output and log-sum-exp carry the
+    `REMAT_KEEP` names."""
+    bdiff = _bdiff_args(q, k, v, block)
+    return _fwd_named(q, k, v, sm_scale, False, 0, interpret, None, None,
+                      bdiff=bdiff)[0]
+
+
+def _bdiff_vjp_fwd(q, k, v, block, sm_scale, interpret):
+    bdiff = _bdiff_args(q, k, v, block)
+    o, lse = _fwd_named(q, k, v, sm_scale, False, 0, interpret, None, None,
+                        bdiff=bdiff)
+    return o, (q, k, v, o, lse)
+
+
+def _bdiff_vjp_bwd(block, sm_scale, interpret, res, do):
+    q, k, v, o, lse = res
+    if sm_scale is None:
+        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    return _flash_bwd(q, k, v, o, lse, do, sm_scale, False, 0,
+                      resolve_interpret(interpret),
+                      bdiff=(q.shape[2] // 2, int(block)))
+
+
+flash_attention_block_diffusion.defvjp(_bdiff_vjp_fwd, _bdiff_vjp_bwd)

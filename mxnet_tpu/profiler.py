@@ -58,7 +58,8 @@ _LOCK = threading.Lock()
 # "conv1d_kernel_calls", the latent attention's rotation (`MLAMixer` with
 # `rope_theta`) "mla_rope_calls" and `MTPModule` "mtp_modules", the flash
 # kernel's wrappers "flash_calls" and "flash_two_width_calls", once a call
-# as traced. Not locked: a span reads the difference on its own
+# as traced, and its block-diffusion calls "bdiff_calls", "bdiff_tiles" and
+# "bdiff_tiles_live". Not locked: a span reads the difference on its own
 # thread, which is exact while no other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "remat_kept": 0, "remat_kept_bytes": 0,
@@ -66,7 +67,8 @@ _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "dsa_topk_chunks": 0, "dsa_topk_chunks_live": 0,
            "conv1d_calls": 0, "conv1d_kernel_calls": 0,
            "mla_rope_calls": 0, "mtp_modules": 0,
-           "flash_calls": 0, "flash_two_width_calls": 0}
+           "flash_calls": 0, "flash_two_width_calls": 0,
+           "bdiff_calls": 0, "bdiff_tiles": 0, "bdiff_tiles_live": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -182,7 +184,11 @@ def counters(device=True):
     flash attention kernel calls, forward and backward, traced so far, and
     those of them whose v is narrower than q.k (latent attention's 128
     against 256: p.v, o, do and dv run at v's width); 0 of the second where
-    every call has one width. Trace-time tallies, flat across steps.
+    every call has one width. ``bdiff_calls`` / ``bdiff_tiles`` /
+    ``bdiff_tiles_live``: flash kernel calls under a block-diffusion mask
+    (a clean and a noised copy of a row), forward and backward, traced so
+    far, their tiles and those the mask leaves live. Trace-time tallies,
+    flat across steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and

@@ -199,7 +199,7 @@ def _bert_head():
 
 
 def _kimi_head():
-    from mxnet_tpu.gluon.model_zoo.kimi_linear import _LMHead
+    from mxnet_tpu.gluon.model_zoo.lm_head import _LMHead
     return _LMHead(20480, 2304, "bfloat16", None)
 
 
@@ -478,3 +478,25 @@ def test_flash_two_widths_compile(one_chip, length):
     else:
         _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_fused")
     assert f"bf16[32,{length},128]" in text
+
+
+@pytest.mark.parametrize("length, block", [(16384, 4), (4096, 4), (16384, 48)],
+                         ids=["split_16384", "fused_4096", "split_16384_block48"])
+def test_flash_block_diffusion_compiles(one_chip, length, block):
+    """One row of block-diffusion attention: a clean and a noised copy of 8,192
+    (or 2,048) positions side by side, 32 query heads on 4 key/value heads of
+    128, the static mask written from iotas in every arm (a block of 48 is no
+    power of two: its block index is a division, not a shift)."""
+    from mxnet_tpu.ops.pallas import flash_attention_block_diffusion
+
+    def step(q, k, v):
+        return jax.grad(lambda q, k, v: _sum(flash_attention_block_diffusion(
+            q, k, v, block, None, False)), argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(step, one_chip, ((1, 32, length, 128), BF16),
+                          ((1, 4, length, 128), BF16), ((1, 4, length, 128), BF16))
+    if length > 4096:
+        _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_dq",
+                        "mxtpu_flash_bwd_dkv")
+    else:
+        _assert_kernels(text, "mxtpu_flash_fwd", "mxtpu_flash_bwd_fused")
