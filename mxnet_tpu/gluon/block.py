@@ -947,4 +947,5 @@ _BUILD_TALLIES = ("flash_tiles", "flash_tiles_live", "dsa_layers",
                   "conv1d_calls", "conv1d_kernel_calls",
                   "mla_rope_calls", "mtp_modules",
                   "flash_calls", "flash_two_width_calls",
-                  "bdiff_calls", "bdiff_tiles", "bdiff_tiles_live")
+                  "bdiff_calls", "bdiff_tiles", "bdiff_tiles_live",
+                  "flash_chunks", "flash_chunks_run")

@@ -100,11 +100,29 @@ neither a window, segments nor ``kv_lens``; with the operand absent the
 kernels trace what they traced before it existed
 (``tests/test_keye_vl2_blocks.py``).
 
+THREE KINDS OF TILE under the static masks (causal, window, the kv tile's
+padding, block diffusion), decided from the call's own masks and shapes
+(`_plan_tiles` at trace time, `_bodies` in the kernels): a dead tile is
+skipped; a tile they leave WHOLLY live runs its whole width with no static
+mask built (a pair mask, kv lengths and segments are data, and still
+apply); a PARTLY live one runs only its chunks of ``block_q`` columns (512
+of a 2048-wide kv tile) from the first that holds a live pair to the last,
+as one update at a static width (a body for each count of chunks, one of
+which runs), its mask built at that width: on the causal diagonal and in a
+noisy row's own diffusion blocks most chunks are empty. The products of the
+live pairs are the ones the whole tile computed. The DMA tile and the walk
+are the same. A call with no wholly live tile and no chunk to skip (no
+static mask; a window's tiles, one chunk wide) traces the one masked body
+it always traced; one with a plan calls its kernel through ``jax.jit``, so
+that a model's identical layer calls trace the several bodies once.
+
 Trace-time tallies (`profiler.counters()`): every forward or backward
 call adds the tiles of its (q tiles x kv tiles) rectangle to
 ``flash_tiles`` and those its static masks (causal, window) leave live
-to ``flash_tiles_live``; it counts once in ``flash_calls``, and in
-``flash_two_width_calls`` too where v is narrower than q.k.
+to ``flash_tiles_live``, the chunks of those live tiles to ``flash_chunks``
+and those it computes to ``flash_chunks_run``; it counts once in
+``flash_calls``, and in ``flash_two_width_calls`` too where v is narrower
+than q.k.
 """
 from __future__ import annotations
 
@@ -153,16 +171,17 @@ def _dot_precision(dtype):
             else lax.Precision.DEFAULT)
 
 
-def _segment_mask(qseg_ref, kseg_ref, block_k):
+def _segment_mask(qseg_ref, kseg_ref, block_k, cols=slice(None)):
     """(block_q, block_k) same-segment mask from the broadcast-layout id
     tiles: q ids (block_q, 128) repeated across lane groups, kv ids one
-    sublane row (1, block_k) broadcast down the sublanes."""
+    sublane row (1, block_k) broadcast down the sublanes; ``cols``: the
+    kv tile's ``block_k`` columns that the mask covers."""
     qs = qseg_ref[0]
     if block_k > _SEG_LANES:
         qs = pltpu.repeat(qs, block_k // _SEG_LANES, axis=1)
     elif block_k < _SEG_LANES:  # never hit: block_k is a 128-multiple
         qs = qs[:, :block_k]
-    return qs == kseg_ref[0][:1, :]
+    return qs == kseg_ref[0][:1, cols]
 
 
 def _seg_range(qrng_ref, krng_ref, i, j, n_heads):
@@ -195,45 +214,47 @@ def _tile_live(live_ref, i, j, rows):
     return live_ref[pl.program_id(0) // np.int32(rows), i, j]
 
 
-def _tile_mask(pm_ref, smask):
-    """The (block_q, block_k) boolean mask of a tile: the pair mask's where
+def _tile_mask(pm_ref, smask, cols=slice(None)):
+    """The boolean mask of a tile's columns ``cols``: the pair mask's where
     there is one (it never goes with segments), else ``smask``."""
     if pm_ref is None:
         return smask
-    return pm_ref[0].astype(jnp.int32) != 0
+    return pm_ref[0, :, cols].astype(jnp.int32) != 0
 
 
 def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-               kvl=None, smask=None, window=None, bdiff=None):
+               kvl=None, smask=None, window=None, bdiff=None, span=None):
     """Validity mask for the (i, j) score block, or None when every
     position is statically visible (no kv padding, not causal, no
     per-example length, no segments) — the common dense shape skips the
     iota/where entirely. ``kvl`` is the traced per-example valid kv
     length (SMEM scalar); it subsumes the static tail-pad mask since
     kvl <= kv_len. ``smask`` is the precomputed (block_q, block_k)
-    same-segment mask (packing)."""
+    same-segment mask (packing). ``span`` (col0, width): the mask of the
+    tile's ``width`` keys from key col0 alone (col0 None: the tile's
+    first)."""
+    col0, width = span or (None, block_k)
+
+    def cols():
+        return (j * block_k if col0 is None else col0) + \
+            lax.broadcasted_iota(jnp.int32, (block_q, width), 1)
     mask = smask
     if kvl is not None:
-        col = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        lm = col < kvl
+        lm = cols() < kvl
         mask = lm if mask is None else jnp.logical_and(mask, lm)
     elif kv_len % block_k != 0:  # padded tail block exists
-        col = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        lm = col < kv_len
+        lm = cols() < kv_len
         mask = lm if mask is None else jnp.logical_and(mask, lm)
     if causal:
-        col = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+        col = cols()
         row = i * block_q + q_offset + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+            jnp.int32, (block_q, width), 0)
         cm = col <= row
         if window is not None:   # itself and the window - 1 before it
             cm = jnp.logical_and(cm, row - col < window)
         mask = cm if mask is None else jnp.logical_and(mask, cm)
     if bdiff is not None:    # (half, block): `_bdiff_mask`, at the file's end
-        bm = _bdiff_mask(i, j, block_q, block_k, *bdiff)
+        bm = _bdiff_mask(i, j, block_q, block_k, *bdiff, span=span)
         mask = bm if mask is None else jnp.logical_and(mask, bm)
     return mask
 
@@ -294,28 +315,163 @@ def _band_steps(window, q_offset, block_q, block_k, nq, nk):
             max(1, int((last_q - first_q + 1).max())))
 
 
-def _tally_tiles(rows, causal, window, q_offset, block_q, block_k, nq, nk,
-                 bdiff=None):
-    """Trace-time: ``flash_tiles`` += the tiles of this call's score
-    rectangles, ``flash_tiles_live`` += those the static masks leave live."""
-    i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
-    live = np.ones((nq, nk), bool)
+def _chunk_cols(block_q, block_k):
+    """Columns of a chunk: a partly live kv tile runs the chunks of
+    ``block_q`` columns (the diagonal's and a diffusion block's step) that
+    hold its live pairs, where they cut the tile into lane-aligned parts,
+    else its whole width (None)."""
+    if block_k > block_q and block_k % block_q == 0 and block_q % 128 == 0:
+        return block_q
+    return None
+
+
+def _span_live(r0, r1, c0, c1, causal, q_offset, kv_len, window, bdiff,
+               xp=jnp):
+    """Whether rows [r0, r1] x keys [c0, c1] (inclusive) hold a pair that the
+    static masks leave live: causal, the window, the kv tile's padding and
+    block diffusion. jnp scalars in a kernel, numpy arrays at trace time."""
+    live = c0 < kv_len
     if causal:
-        live &= j * block_k <= (i + 1) * block_q - 1 + q_offset
+        live = xp.logical_and(live, c0 <= r1 + q_offset)
     if window is not None:
-        live &= (j + 1) * block_k - 1 >= i * block_q + q_offset - (window - 1)
+        live = xp.logical_and(live, c1 >= r0 + q_offset - (window - 1))
     if bdiff is not None:
-        live &= _bdiff_live(i * block_q, (i + 1) * block_q - 1, j * block_k,
-                            (j + 1) * block_k - 1, *bdiff, xp=np)
+        live = xp.logical_and(live, _bdiff_live(r0, r1, c0, c1, *bdiff, xp=xp))
+    return live
+
+
+def _span_whole(r0, r1, c0, c1, causal, q_offset, kv_len, window, bdiff,
+                xp=jnp):
+    """Whether the static masks leave EVERY pair of rows [r0, r1] x keys
+    [c0, c1] live (`_span_live`'s twin, from the same corners)."""
+    whole = c1 < kv_len
+    if causal:
+        whole = xp.logical_and(whole, c1 <= r0 + q_offset)
+    if window is not None:
+        whole = xp.logical_and(whole, r1 + q_offset - c0 < window)
+    if bdiff is not None:
+        whole = xp.logical_and(whole, _bdiff_whole(r0, r1, c0, c1, *bdiff,
+                                                   xp=xp))
+    return whole
+
+
+def _plan_tiles(rows, causal, window, q_offset, kv_len, block_q, block_k, nq,
+                nk, bdiff=None):
+    """Trace-time: the tallies of a call and its plan. ``flash_tiles`` += the
+    tiles of its score rectangles, ``flash_tiles_live`` += those the static
+    masks leave live; ``flash_chunks`` += the chunks of the live tiles,
+    ``flash_chunks_run`` += those the kernels compute: every chunk of a tile
+    the static masks leave wholly live, a partly live one's from its first
+    live chunk to its last.
+
+    The plan (a static kernel argument, `_bodies`): None where no live tile
+    is wholly live under a static mask and no chunk can be skipped (the
+    kernels trace the one masked body they always traced), else (whether
+    wholly live tiles exist, the chunk's columns or None). Its live chunks
+    are contiguous in every tile of the cells' calls."""
+    statics = (causal, q_offset, kv_len, window, bdiff)
+    chunk = _chunk_cols(block_q, block_k)
+    width = chunk or block_k
+    n = block_k // width
+    r0 = np.arange(nq)[:, None] * block_q
+    c0 = np.arange(nk * n)[None, :] * width
+    # the chunks a tile runs, its first live one to its last; a tile is live
+    # where any of its chunks is
+    lives = np.broadcast_to(_span_live(r0, r0 + block_q - 1, c0, c0 + width - 1,
+                                       *statics, xp=np),
+                            (nq, nk * n)).reshape(nq, nk, n)
+    at = np.arange(n)
+    ran = np.maximum(np.where(lives, at, -1).max(-1)
+                     - np.where(lives, at, n).min(-1) + 1, 0)
+    live = ran > 0
+    if bdiff is not None:
         _tally_bdiff(rows * nq * nk, rows * int(live.sum()))
     _profiler.count("flash_tiles", rows * nq * nk)
     _profiler.count("flash_tiles_live", rows * int(live.sum()))
+    c0 = np.arange(nk)[None, :] * block_k
+    whole = live & _span_whole(r0, r0 + block_q - 1, c0, c0 + block_k - 1,
+                               *statics, xp=np)
+    _profiler.count("flash_chunks", rows * n * int(live.sum()))
+    _profiler.count("flash_chunks_run",
+                    rows * int(np.where(whole, n, ran)[live].sum()))
+    masked = causal or window is not None or bdiff is not None \
+        or kv_len % block_k != 0
+    wholes = bool(masked and whole.any())
+    skips = bool((ran[live & ~whole] < n).any())
+    if not (wholes or skips):
+        return None
+    return wholes, chunk if skips else None
+
+
+def _bodies(visible, i, j, plan, block_q, block_k, statics):
+    """The bodies a kernel traces for tile (i, j), as (condition, span)
+    pairs, of which one runs for a visible tile. A span ``(col0, width,
+    cols, static)`` is the tile's key rows ``cols``, keys col0 .. col0 +
+    width - 1 (col0 None: from the tile's first), under every mask
+    (``static``) or under what is data alone. Without a plan, one body: the
+    tile under every mask. With one (`_plan_tiles`), a tile that the static
+    masks leave wholly live runs its whole width under the data's masks
+    alone, and a partly live one its chunks from the first live one to the
+    last, at a static width (a body for each count of chunks; where no
+    chunk can be skipped, the whole width). Under causal masks alone the
+    live chunks start the tile, so the span's columns are static (and the
+    data's masks, which go with no window or block diffusion, slice
+    statically); else the span starts at a dynamic chunk. ``statics``:
+    `_span_live`'s masks."""
+    full = (None, block_k, slice(None), True)
+    if plan is None:
+        return [(visible, full)]
+    wholes, chunk = plan
+    r0, c0 = i * block_q, j * block_k
+    r1 = r0 + block_q - 1
+    out, partial = [], visible
+    if wholes:
+        whole = _span_whole(r0, r1, c0, c0 + block_k - 1, *statics)
+        out.append((jnp.logical_and(visible, whole),
+                    (None, block_k, slice(None), False)))
+        partial = jnp.logical_and(visible, jnp.logical_not(whole))
+    if chunk is None:
+        return out + [(partial, full)]
+    n = block_k // chunk
+    prefix = statics[3] is None and statics[4] is None   # no window, bdiff
+    first, last = (0 if prefix else np.int32(n)), np.int32(-1)
+    for c in range(n):
+        live = _span_live(r0, r1, c0 + c * chunk, c0 + (c + 1) * chunk - 1,
+                          *statics)
+        if not prefix:
+            first = jnp.where(live, jnp.minimum(first, c), first)
+        last = jnp.where(live, c, last)
+    start = None if prefix else pl.multiple_of(first * chunk, chunk)
+    for w in range(1, n + 1):
+        span = (None, w * chunk, slice(0, w * chunk), True) if prefix else \
+            (c0 + start, w * chunk, pl.ds(start, w * chunk), True)
+        out.append((jnp.logical_and(partial, last - first == w - 1), span))
+    return out
+
+
+def _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset, kv_len,
+            block_q, block_k, window, bdiff):
+    """``mask(col0, width, cols, static)``: the mask of a span of tile
+    (i, j) (`_bodies`), or None: the data's masks (kv lengths, segments, the
+    pair mask) on the tile's columns ``cols`` and, where ``static``, the
+    static ones."""
+    def mask(col0, width, cols, static):
+        smask = _tile_mask(pm_ref, None if qseg_ref is None else
+                           _segment_mask(qseg_ref, kseg_ref, width, cols),
+                           cols)
+        if not static:
+            return _pair_mask(i, j, False, 0, block_k, block_q, block_k, kvl,
+                              smask)
+        return _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
+                          kvl, smask, window, bdiff, (col0, width))
+    return mask
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
                 sm_scale, causal, q_offset, kv_len, block_q, block_k,
                 precision, dynamic_kv, dynamic_seg, n_heads,
-                window=None, narrow=None, dynamic_mask=False, bdiff=None):
+                window=None, narrow=None, dynamic_mask=False, bdiff=None,
+                plan=None):
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (o_ref, lse_ref, acc_sc, m_sc, l_sc)) = _split_rest(
          rest, dynamic_seg, dynamic_mask)
@@ -341,21 +497,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
                              bdiff)
     if narrow:
         visible = jnp.logical_and(visible, j < narrow[1])
+    mask_of = _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset,
+                      kv_len, block_q, block_k, window, bdiff)
 
-    @pl.when(visible)
-    def _():
-        # q arrives pre-scaled by sm_scale (host side) so no per-pair
-        # (block_q, block_k) elementwise scale runs on the VPU
+    def run(col0, width, cols, static):
+        # one online-softmax update over the span's keys. q arrives
+        # pre-scaled by sm_scale (host side) so no per-pair (block_q,
+        # block_k) elementwise scale runs on the VPU
         q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
+        k = k_ref[0, cols].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
-        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
-                           if dynamic_seg else None)
-        mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window, bdiff)
+        mask = mask_of(col0, width, cols, static)
         if mask is not None:
             s = jnp.where(mask, s, np.float32(_NEG_INF))
 
@@ -369,11 +524,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
         alpha = jnp.where(seen, alpha, np.float32(0.0))
         p = jnp.where(seen, jnp.exp(s - m_cur), np.float32(0.0))
         l_sc[:] = l_sc[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)
+        v = v_ref[0, cols].astype(jnp.float32)
         acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         m_sc[:] = m_cur
+
+    for cond, span in _bodies(visible, i, j, plan, block_q, block_k,
+                              (causal, q_offset, kv_len, window, bdiff)):
+        pl.when(cond)(functools.partial(run, *span))
 
     @pl.when(jj == nk - 1)
     def _():
@@ -390,7 +549,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    sm_scale, causal, q_offset, kv_len, block_q, block_k,
                    precision, dynamic_kv, dynamic_seg, n_heads,
                    window=None, narrow=None, group=1, steps=None,
-                   dynamic_mask=False, bdiff=None):
+                   dynamic_mask=False, bdiff=None, plan=None):
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (dq_ref, dq_sc)) = _split_rest(rest, dynamic_seg, dynamic_mask)
     i, jj = pl.program_id(1), pl.program_id(2)
@@ -409,12 +568,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              bdiff)
     if narrow:
         visible = jnp.logical_and(visible, j < narrow[1])
+    mask_of = _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset,
+                      kv_len, block_q, block_k, window, bdiff)
 
-    @pl.when(visible)
-    def _():
+    def run(col0, width, cols, static):
         q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[0, cols].astype(jnp.float32)
+        v = v_ref[0, cols].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]
         delta = delta_ref[0]
@@ -422,10 +582,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
-        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
-                           if dynamic_seg else None)
-        mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window, bdiff)
+        mask = mask_of(col0, width, cols, static)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
         dp = jax.lax.dot_general(
@@ -435,6 +592,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_sc[:] = dq_sc[:] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
+
+    for cond, span in _bodies(visible, i, j, plan, block_q, block_k,
+                              (causal, q_offset, kv_len, window, bdiff)):
+        pl.when(cond)(functools.partial(run, *span))
 
     @pl.when(jj == nk - 1)
     def _():
@@ -457,7 +618,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     sm_scale, causal, q_offset, kv_len, block_q, block_k,
                     precision, dynamic_kv, dynamic_seg, n_heads,
                     window=None, narrow=None, group=1, steps=None,
-                    dynamic_mask=False, bdiff=None):
+                    dynamic_mask=False, bdiff=None, plan=None):
     # grid: (B*Hk, nk, group * q steps) — q is the inner (sequential) axis
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (dk_ref, dv_ref, dk_sc, dv_sc)) = _split_rest(
@@ -481,12 +642,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              bdiff)
     if narrow:
         visible = jnp.logical_and(visible, i < narrow[0])
+    mask_of = _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset,
+                      kv_len, block_q, block_k, window, bdiff)
 
-    @pl.when(visible)
-    def _():
+    def run(col0, width, cols, static):
+        # adds into the span's rows of dk and dv
         q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[0, cols].astype(jnp.float32)
+        v = v_ref[0, cols].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]
         delta = delta_ref[0]
@@ -494,23 +657,24 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
-        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
-                           if dynamic_seg else None)
-        mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window, bdiff)
+        mask = mask_of(col0, width, cols, static)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
 
-        dv_sc[:] = dv_sc[:] + jax.lax.dot_general(
+        dv_sc[cols] = dv_sc[cols] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         ds = p * (dp - delta)
-        dk_sc[:] = dk_sc[:] + jax.lax.dot_general(
+        dk_sc[cols] = dk_sc[cols] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
+
+    for cond, span in _bodies(visible, i, j, plan, block_q, block_k,
+                              (causal, q_offset, kv_len, window, bdiff)):
+        pl.when(cond)(functools.partial(run, *span))
 
     @pl.when(t == nq - 1)
     def _():
@@ -523,7 +687,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       sm_scale, causal, q_offset, kv_len, block_q, block_k,
                       precision, dynamic_kv, dynamic_seg, n_heads,
                       window=None, narrow=None, group=1, steps=None,
-                      dynamic_mask=False, bdiff=None):
+                      dynamic_mask=False, bdiff=None, plan=None):
     """One-pass backward: dq, dk, dv from a SINGLE traversal of the
     (q block, k block) grid — the score matrix s and dp are computed
     once per pair instead of once in a dq kernel and again in a dkv
@@ -556,12 +720,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              window,
                              _tile_live(live_ref, i, j, n_heads // group),
                              bdiff)
+    mask_of = _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset,
+                      kv_len, block_q, block_k, window, bdiff)
 
-    @pl.when(visible)
-    def _():
+    def run(col0, width, cols, static):
         q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[0, cols].astype(jnp.float32)
+        v = v_ref[0, cols].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]
         delta = delta_ref[0]
@@ -569,27 +734,28 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
-        smask = _tile_mask(pm_ref, _segment_mask(qseg_ref, kseg_ref, block_k)
-                           if dynamic_seg else None)
-        mask = _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
-                          kvl, smask, window, bdiff)
+        mask = mask_of(col0, width, cols, static)
         p = jnp.exp(s - lse) if mask is None \
             else jnp.where(mask, jnp.exp(s - lse), np.float32(0.0))
 
-        dv_sc[:] = dv_sc[:] + jax.lax.dot_general(
+        dv_sc[cols] = dv_sc[cols] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         ds = p * (dp - delta)
-        dk_sc[:] = dk_sc[:] + jax.lax.dot_general(
+        dk_sc[cols] = dk_sc[cols] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         dq_ref[0, 0] = (jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=precision) * np.float32(sm_scale)).astype(dq_ref.dtype)
+
+    for cond, span in _bodies(visible, i, j, plan, block_q, block_k,
+                              (causal, q_offset, kv_len, window, bdiff)):
+        pl.when(cond)(functools.partial(run, *span))
 
     @pl.when(jnp.logical_not(visible))
     def _():
@@ -813,18 +979,39 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
     kvlf = _expand_kv_lens(kv_lens, b, h) if dynamic_kv \
         else jnp.full((bh,), skv, jnp.int32)
     nq, nk = sq_p // block_q, skv_p // block_k
-    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk, bdiff)
+    plan = _plan_tiles(bh, causal, window, q_offset, skv, block_q, block_k,
+                       nq, nk, bdiff)
     _tally_widths(d, dv)
+    seg_ops = None if segment_ids is None else list(
+        _prep_segments(segment_ids, b, sq, skv, sq_p, skv_p, block_q, block_k))
+    mask_ops = None if pair_mask is None else _prep_pair_mask(
+        pair_mask, sq_p, skv_p, block_q, block_k)
+    common = dict(sm_scale=sm_scale, causal=causal, q_offset=q_offset,
+                  kv_len=skv, block_q=block_q, block_k=block_k,
+                  precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
+                  dynamic_seg=dynamic_seg, n_heads=h, window=window,
+                  dynamic_mask=pair_mask is not None, bdiff=bdiff, plan=plan)
+    call = _flash_fwd_call if plan is None else _flash_fwd_once
+    return call(qf, kf, vf, kvlf, seg_ops, mask_ops, (b, h, sq, dv),
+                tuple(sorted(common.items())), interpret, q.dtype, group)
+
+
+def _flash_fwd_call(qf, kf, vf, kvlf, seg_ops, mask_ops, dims, common,
+                    interpret, q_dtype, group):
+    """The forward's kernel call on its prepared operands; ``common``: the
+    kernel's static arguments, as sorted pairs."""
+    b, h, sq, dv = dims
+    common = dict(common)
+    block_q, block_k = common["block_q"], common["block_k"]
+    window, q_offset = common["window"], common["q_offset"]
+    d = qf.shape[-1]
+    bh, sq_p = b * h, qf.shape[1]
+    nq, nk = sq_p // block_q, kf.shape[1] // block_k
     # a window's walk: the inner axis holds the band's tiles, not the row's
     narrow = (nq, nk) if window is not None else None
     kv_steps = _band_steps(window, q_offset, block_q, block_k, nq, nk)[0] \
         if narrow else nk
-    kern = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        q_offset=q_offset, kv_len=skv, block_q=block_q, block_k=block_k,
-        precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
-        dynamic_seg=dynamic_seg, n_heads=h, window=window, narrow=narrow,
-        dynamic_mask=pair_mask is not None, bdiff=bdiff)
+    kern = functools.partial(_fwd_kernel, narrow=narrow, **common)
     kv_map = _kv_map(group, window, narrow, q_offset, block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
@@ -834,13 +1021,12 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, kf, vf, kvlf]
-    if dynamic_seg:
+    if seg_ops is not None:
         in_specs += _seg_specs(block_q, block_k, h, transposed_grid=False)
-        operands += list(_prep_segments(segment_ids, b, sq, skv,
-                                        sq_p, skv_p, block_q, block_k))
-    if pair_mask is not None:
+        operands += seg_ops
+    if mask_ops is not None:
         in_specs += _mask_specs(block_q, block_k, h, transposed_grid=False)
-        operands += _prep_pair_mask(pair_mask, sq_p, skv_p, block_q, block_k)
+        operands += mask_ops
     o, lse = pl.pallas_call(
         kern,
         grid=(bh, nq, kv_steps),
@@ -852,7 +1038,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq_p, dv), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq_p, dv), q_dtype),
             jax.ShapeDtypeStruct((bh, sq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
@@ -916,28 +1102,26 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
         kf, vf = _pad0(kf, pad), _pad0(vf, pad)
 
     nq, nk = sq_p // block_q, skv_p // block_k
-    _tally_tiles(bh, causal, window, q_offset, block_q, block_k, nq, nk, bdiff)
+    plan = _plan_tiles(bh, causal, window, q_offset, skv, block_q, block_k,
+                       nq, nk, bdiff)
     _tally_widths(d, dv)
     common = dict(sm_scale=sm_scale, causal=causal, q_offset=q_offset,
                   kv_len=skv, block_q=block_q, block_k=block_k,
                   precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
                   dynamic_seg=seg_ops is not None, n_heads=h,
                   window=window, group=group,
-                  dynamic_mask=mask_ops is not None, bdiff=bdiff)
+                  dynamic_mask=mask_ops is not None, bdiff=bdiff, plan=plan)
 
     # the fused pass writes nk f32 dq-partial copies to HBM; past nk=2
     # that memory/write cliff outweighs the recompute saving, so long
     # multi-k-block rows (S > 2*block_k cap) take the split path whose
     # dq accumulates in VMEM scratch
-    if nk <= 2:
-        return _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
-                                (b, h, sq, skv, d, dv), nq, nk, common,
-                                interpret, k.dtype, v.dtype, q.dtype, group,
-                                mask_ops)
-    return _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
-                            (b, h, sq, skv, d, dv), nq, nk, common,
-                            interpret, k.dtype, v.dtype, q.dtype, group,
-                            mask_ops)
+    call = (_flash_bwd_fused, _flash_bwd_split) if plan is None \
+        else (_flash_bwd_fused_once, _flash_bwd_split_once)
+    return call[nk > 2](qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
+                        (b, h, sq, skv, d, dv), nq, nk,
+                        tuple(sorted(common.items())), interpret, k.dtype,
+                        v.dtype, q.dtype, group, mask_ops)
 
 
 def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
@@ -949,9 +1133,9 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     2048 cap, nk=4) runs ``_flash_bwd_split``'s dq/dkv pair."""
     b, h, sq, skv, d, dv = dims
     bh = b * h
+    common = dict(common, steps=nq)
     block_q, block_k = common["block_q"], common["block_k"]
     sq_p, skv_p = nq * block_q, nk * block_k
-    common = dict(common, steps=nq)
     # the q side: with grouped heads the inner axis runs the group's query
     # heads one after the other, nq tiles each
     q_map = _q_map(group, nq, None, 0, block_q, block_k)
@@ -1021,9 +1205,10 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                      group=1, mask_ops=None):
     b, h, sq, skv, d, dv = dims
     bh = b * h
+    common = dict(common)
     block_q, block_k = common["block_q"], common["block_k"]
     sq_p, skv_p = nq * block_q, nk * block_k
-    window, q_offset = common.get("window"), common["q_offset"]
+    window, q_offset = common["window"], common["q_offset"]
     # a window's walk: both inner axes hold a band's tiles, not a row's
     narrow = (nq, nk) if window is not None else None
     kv_steps, q_steps = _band_steps(window, q_offset, block_q, block_k,
@@ -1108,6 +1293,18 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     dk = dk[:, :skv].reshape(b, h // group, skv, d)
     dvg = dvg[:, :skv].reshape(b, h // group, skv, dv)
     return dq, dk, dvg
+
+
+# A call with a plan traces several bodies a kernel (`_bodies`), a cost paid
+# again for each identical call of a model's layers, and in every process
+# whether the compile cache holds the program or not. Through jax.jit, keyed
+# by the static arguments and the operands' shapes, a program traces and
+# lowers them once for all its identical calls.
+_STATIC = ("dims", "common", "interpret", "q_dtype", "group")
+_flash_fwd_once = jax.jit(_flash_fwd_call, static_argnames=_STATIC)
+_flash_bwd_fused_once, _flash_bwd_split_once = (
+    jax.jit(f, static_argnames=_STATIC + ("nq", "nk", "k_dtype", "v_dtype"))
+    for f in (_flash_bwd_fused, _flash_bwd_split))
 
 
 def _int_ct(x):
@@ -1463,13 +1660,33 @@ def _bdiff_live(r0, r1, c0, c1, half, block, xp=jnp):
     return clean_rows | noisy_clean | noisy_noisy
 
 
-def _bdiff_mask(i, j, block_q, block_k, half, block):
-    """The (block_q, block_k) block-diffusion mask of tile (i, j), from a
-    column of row positions and a row of key positions: a key's code is its
-    block, + ``half`` if it is noisy; a row sees the codes up to its last
-    visible clean block and, if noisy, the one code of its own noisy block."""
+def _bdiff_whole(r0, r1, c0, c1, half, block, xp=jnp):
+    """Whether the block-diffusion mask leaves EVERY pair of rows [r0, r1] x
+    keys [c0, c1] live (`_bdiff_live`'s twin, from the same corners; rows past
+    the row's end are tile padding, keys past it are `_span_whole`'s): clean
+    rows against clean keys of their own or earlier blocks, noisy rows
+    against clean keys of earlier blocks, or noisy rows against the noisy
+    keys of the one block that holds them all."""
+    blk = functools.partial(_blocks_of, block=block)
+    r1 = xp.minimum(r1, 2 * half - 1)
+    clean = (r1 < half) & (c1 < half) & (blk(c1) <= blk(r0))
+    noisy = r0 >= half
+    noisy_clean = noisy & (c1 < half) & (blk(c1) < blk(r0 - half))
+    own = (noisy & (c0 >= half) & (blk(c0 - half) == blk(r1 - half))
+           & (blk(c1 - half) == blk(r0 - half)))
+    return clean | noisy_clean | own
+
+
+def _bdiff_mask(i, j, block_q, block_k, half, block, span=None):
+    """The (block_q, block_k) block-diffusion mask of tile (i, j) (of its
+    ``span``, as `_pair_mask`'s), from a column of row positions and a row of
+    key positions: a key's code is its block, + ``half`` if it is noisy; a
+    row sees the codes up to its last visible clean block and, if noisy, the
+    one code of its own noisy block."""
+    col0, width = span or (None, block_k)
     row = i * block_q + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-    col = j * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    col = (j * block_k if col0 is None else col0) + \
+        lax.broadcasted_iota(jnp.int32, (1, width), 1)
     rn = (row >= half).astype(jnp.int32)
     cn = (col >= half).astype(jnp.int32)
     rb = _blocks_of(row - half * rn, block)

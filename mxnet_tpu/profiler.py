@@ -59,7 +59,8 @@ _LOCK = threading.Lock()
 # `rope_theta`) "mla_rope_calls" and `MTPModule` "mtp_modules", the flash
 # kernel's wrappers "flash_calls" and "flash_two_width_calls", once a call
 # as traced, and its block-diffusion calls "bdiff_calls", "bdiff_tiles" and
-# "bdiff_tiles_live". Not locked: a span reads the difference on its own
+# "bdiff_tiles_live", and its live tiles' chunks "flash_chunks" and
+# "flash_chunks_run". Not locked: a span reads the difference on its own
 # thread, which is exact while no other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "remat_kept": 0, "remat_kept_bytes": 0,
@@ -68,7 +69,8 @@ _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "conv1d_calls": 0, "conv1d_kernel_calls": 0,
            "mla_rope_calls": 0, "mtp_modules": 0,
            "flash_calls": 0, "flash_two_width_calls": 0,
-           "bdiff_calls": 0, "bdiff_tiles": 0, "bdiff_tiles_live": 0}
+           "bdiff_calls": 0, "bdiff_tiles": 0, "bdiff_tiles_live": 0,
+           "flash_chunks": 0, "flash_chunks_run": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -187,8 +189,12 @@ def counters(device=True):
     every call has one width. ``bdiff_calls`` / ``bdiff_tiles`` /
     ``bdiff_tiles_live``: flash kernel calls under a block-diffusion mask
     (a clean and a noised copy of a row), forward and backward, traced so
-    far, their tiles and those the mask leaves live. Trace-time tallies,
-    flat across steps.
+    far, their tiles and those the mask leaves live. ``flash_chunks`` /
+    ``flash_chunks_run``: the chunks (``block_q`` columns of a kv tile) of
+    every live tile of those flash calls, and those the kernels compute:
+    every chunk of a tile the static masks leave wholly live, a partly live
+    one's from its first live chunk to its last (a ratio of 1: no chunk
+    skipped). Trace-time tallies, flat across steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and

@@ -167,13 +167,15 @@ def test_the_live_tiles_at_the_cells_size_are_the_issues_count():
 
 # the other cells' op calls: tests/test_flash_two_widths.py's pins, and the
 # latent attention call of Kimi's and JoyAI's cells (q.k 192, v 128, 32 heads,
-# one row: the two cells make the same call), taken on the parent
+# one row: the two cells make the same call), taken on the parent and
+# re-pinned when the kernel began to run wholly live tiles without building
+# their static mask and partly live ones a chunk at a time
 _MLA = ((1, 32, 8192, 192), (1, 32, 8192, 192), (1, 32, 8192, 128), True, None, False, False)
 _PARENT = {**two_widths._PARENT,
-           ("kimi", "forward"): "f0724ebb246929a71164ada1e7cb481101500842131699b487fcc0e3739721fa",
-           ("kimi", "gradient"): "6752afd05b79fd50a9f5ad465d50dde02223c697bbc2d5ec09fd63a2ad6c5280",
-           ("joyai", "forward"): "f0724ebb246929a71164ada1e7cb481101500842131699b487fcc0e3739721fa",
-           ("joyai", "gradient"): "6752afd05b79fd50a9f5ad465d50dde02223c697bbc2d5ec09fd63a2ad6c5280"}
+           ("kimi", "forward"): "d210b33d69cefcec6bfaaac32d597b28d6c30969f584166f01f39e9e8053ca4d",
+           ("kimi", "gradient"): "122131ad380654ebfa7dd76872f665177e2b4de20f7172097d3b10a8a730a59d",
+           ("joyai", "forward"): "d210b33d69cefcec6bfaaac32d597b28d6c30969f584166f01f39e9e8053ca4d",
+           ("joyai", "gradient"): "122131ad380654ebfa7dd76872f665177e2b4de20f7172097d3b10a8a730a59d"}
 
 
 @pytest.mark.parametrize("call,what", sorted(_PARENT))
