@@ -948,4 +948,4 @@ _BUILD_TALLIES = ("flash_tiles", "flash_tiles_live", "dsa_layers",
                   "mla_rope_calls", "mtp_modules",
                   "flash_calls", "flash_two_width_calls",
                   "bdiff_calls", "bdiff_tiles", "bdiff_tiles_live",
-                  "flash_chunks", "flash_chunks_run")
+                  "flash_chunks", "flash_chunks_run", "flash_grid_steps")

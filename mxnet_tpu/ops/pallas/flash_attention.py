@@ -66,14 +66,10 @@ attention; the KV-cache decode path has no packed analog here).
 A causal WINDOW (``window=W``, static): token t sees keys j with
 0 <= t - j < W, itself and the W - 1 before it (sliding-window layers).
 The kv tile is then at most as wide as the window, and tiles outside
-the band cost nothing: ``_block_visible`` skips them, and the forward
-and the split backward do not even walk them. Their grids' inner axis
-is narrowed to the most tiles a band crosses (``_band_steps``): step jj
-of q tile i visits kv tile ``_kv_tile(i, jj)``, the band's first tile
-+ jj (``mxtpu_flash_fwd``, ``mxtpu_flash_bwd_dq``); step ii of kv tile
-j visits q tile ``_q_tile(j, ii)`` (``mxtpu_flash_bwd_dkv``). The fused
-backward (at most two kv tiles: rows no longer than two windows) walks
-its whole (kv, q) grid and skips by ``_block_visible`` alone.
+the band cost nothing: the forward and the split backward walk the band's
+tiles alone (THE WALK, below), and the fused backward (at most two kv
+tiles: rows no longer than two windows) walks its whole (kv, q) grid and
+skips by ``_block_visible``.
 
 GROUPED key/value heads: k and v may have fewer heads than q (Hk
 divides H); query head h reads key/value head h // (H / Hk) through
@@ -101,31 +97,52 @@ kernels trace what they traced before it existed
 (``tests/test_keye_vl2_blocks.py``).
 
 THREE KINDS OF TILE under the static masks (causal, window, the kv tile's
-padding, block diffusion), decided from the call's own masks and shapes
-(`_plan_tiles` at trace time, `_bodies` in the kernels): a dead tile is
-skipped; a tile they leave WHOLLY live runs its whole width with no static
-mask built (a pair mask, kv lengths and segments are data, and still
-apply); a PARTLY live one runs only its chunks of ``block_q`` columns (512
-of a 2048-wide kv tile) from the first that holds a live pair to the last,
-as one update at a static width (a body for each count of chunks, one of
-which runs), its mask built at that width: on the causal diagonal and in a
-noisy row's own diffusion blocks most chunks are empty. The products of the
-live pairs are the ones the whole tile computed. The DMA tile and the walk
-are the same. A call with no wholly live tile and no chunk to skip (no
-static mask; a window's tiles, one chunk wide) traces the one masked body
-it always traced; one with a plan calls its kernel through ``jax.jit``, so
-that a model's identical layer calls trace the several bodies once.
+padding, block diffusion), decided from the call's own masks and shapes at
+trace time (`_tile_lives`): a DEAD tile is not walked (below); a tile they
+leave WHOLLY live runs its whole width with no static mask built (a pair
+mask, kv lengths and segments are data, and still apply); a PARTLY live one
+runs only its chunks of ``block_q`` columns (512 of a 2048-wide kv tile)
+from the first that holds a live pair to the last, as one update at a
+static width (a body for each count of chunks, one of which runs), its mask
+built at that width: on the causal diagonal and in a noisy row's own
+diffusion blocks most chunks are empty (`_plan_tiles`, `_bodies`). The
+products of the live pairs are the ones the whole tile computed. A call
+with no wholly live tile and no chunk to skip (no static mask; a window's
+tiles, one chunk wide) traces the one masked body it always traced; one
+with a plan calls its kernel through ``jax.jit``, so that a model's
+identical layer calls trace the several bodies once.
+
+THE WALK: where the static masks leave a tile dead, the forward, dq and
+dkv kernels walk a table of the live steps (`_plan_tiles`' walk,
+`_walk_table` at the file's end) instead of the whole (q tiles x kv tiles)
+rectangle, so that a dead tile costs neither a grid step nor the fetch of
+its blocks. The table is three int32 vectors, scalar-prefetched: step t's
+outer and inner tile, which the index maps read where the rectangle's grid
+gave its two inner program ids, and flags marking the first and last step
+of an outer tile and a live one. Its order is the rectangle's, dead steps
+left out (q tiles, each's live kv tiles ascending; for dkv kv tiles,
+each's group of query heads one after the other, each's live q tiles
+ascending), so every sum runs in the rectangle's order. An outer tile with
+no live step keeps one step, which only initialises and writes (o = 0 and
+lse = -inf, or dk = dv = 0), and fetches the inner blocks the step before
+fetched. On a walked step the static masks' visibility is the table's;
+the data's (kv lengths, segments, the pair mask's tile summary) is tested
+in the kernel as before. The table is the same for every row: static
+masks depend on neither the head nor the batch entry. A call whose static
+masks leave every tile live, and the fused backward, walk the rectangle.
 
 Trace-time tallies (`profiler.counters()`): every forward or backward
 call adds the tiles of its (q tiles x kv tiles) rectangle to
 ``flash_tiles`` and those its static masks (causal, window) leave live
 to ``flash_tiles_live``, the chunks of those live tiles to ``flash_chunks``
-and those it computes to ``flash_chunks_run``; it counts once in
-``flash_calls``, and in ``flash_two_width_calls`` too where v is narrower
-than q.k.
+and those it computes to ``flash_chunks_run``, and the (q tile, kv tile)
+steps that one of its kernels launches (the table's or the rectangle's)
+to ``flash_grid_steps``; it counts once in ``flash_calls``, and in
+``flash_two_width_calls`` too where v is narrower than q.k.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -260,19 +277,26 @@ def _pair_mask(i, j, causal, q_offset, kv_len, block_q, block_k,
 
 
 def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
-                   segrng=None, window=None, live=None, bdiff=None):
+                   segrng=None, window=None, live=None, bdiff=None,
+                   step=None):
     """Whether the (i, j) tile has ANY live score: causal skip, the
     window skip (the tile's last key is older than the first row's
     window), the per-example length skip (tiles starting at/after kvl
     are dead — the variable-length fast path's whole-tile saving), and
     the packed segment-range skip (disjoint id ranges cannot share a
     segment, so cross-sequence tiles cost no MXU work), and the pair mask's
-    tile summary (``live``: 0 where the mask leaves no pair of the tile)."""
-    q_last = (i + 1) * block_q - 1 + q_offset
-    vis = jnp.logical_or(not causal, j * block_k <= q_last)
-    if window is not None:
-        vis = jnp.logical_and(
-            vis, (j + 1) * block_k - 1 >= i * block_q + q_offset - (window - 1))
+    tile summary (``live``: 0 where the mask leaves no pair of the tile).
+    ``step``: a walked step's live flag (THE WALK), which stands for
+    the static masks' tests (causal, window, block diffusion); None on the
+    rectangle's grid, which tests them here."""
+    if step is None:
+        q_last = (i + 1) * block_q - 1 + q_offset
+        vis = jnp.logical_or(not causal, j * block_k <= q_last)
+        if window is not None:
+            vis = jnp.logical_and(vis, (j + 1) * block_k - 1
+                                  >= i * block_q + q_offset - (window - 1))
+    else:
+        vis = step
     if kvl is not None:
         vis = jnp.logical_and(vis, j * block_k < kvl)
     if segrng is not None:
@@ -281,38 +305,11 @@ def _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                                                    kmin <= qmax))
     if live is not None:
         vis = jnp.logical_and(vis, live != 0)
-    if bdiff is not None:
+    if bdiff is not None and step is None:
         vis = jnp.logical_and(vis, _bdiff_live(
             i * block_q, (i + 1) * block_q - 1, j * block_k,
             (j + 1) * block_k - 1, *bdiff))
     return vis
-
-
-def _kv_tile(i, jj, window, q_offset, block_q, block_k):
-    """The kv tile that step ``jj`` of q tile ``i``'s narrowed walk visits:
-    the first tile of the rows' band, + jj."""
-    first = jnp.maximum(i * block_q + (q_offset - (window - 1)), 0) // block_k
-    return first + jj
-
-
-def _q_tile(j, ii, q_offset, block_q, block_k):
-    """The q tile that step ``ii`` of kv tile ``j``'s narrowed walk visits:
-    the first tile whose rows see the tile's keys (causal), + ii."""
-    return jnp.maximum(j * block_k - q_offset, 0) // block_q + ii
-
-
-def _band_steps(window, q_offset, block_q, block_k, nq, nk):
-    """(kv tiles a q tile's band crosses at most, q tiles a kv tile's):
-    the lengths of the narrowed inner axes, counted at trace time."""
-    i, j = np.arange(nq), np.arange(nk)
-    first_kv = np.maximum(i * block_q + q_offset - (window - 1), 0) // block_k
-    last_kv = np.minimum((i * block_q + block_q - 1 + q_offset) // block_k,
-                         nk - 1)
-    first_q = np.maximum(j * block_k - q_offset, 0) // block_q
-    last_q = np.minimum(
-        (j * block_k + block_k - 1 + window - 1 - q_offset) // block_q, nq - 1)
-    return (max(1, int((last_kv - first_kv + 1).max())),
-            max(1, int((last_q - first_q + 1).max())))
 
 
 def _chunk_cols(block_q, block_k):
@@ -356,10 +353,11 @@ def _span_whole(r0, r1, c0, c1, causal, q_offset, kv_len, window, bdiff,
 
 
 def _plan_tiles(rows, causal, window, q_offset, kv_len, block_q, block_k, nq,
-                nk, bdiff=None):
-    """Trace-time: the tallies of a call and its plan. ``flash_tiles`` += the
-    tiles of its score rectangles, ``flash_tiles_live`` += those the static
-    masks leave live; ``flash_chunks`` += the chunks of the live tiles,
+                nk, bdiff=None, group=1):
+    """Trace-time: the tallies of a call, its plan and its walk, from what the
+    static masks leave live (`_tile_lives`). ``flash_tiles`` += the tiles of
+    its score rectangles, ``flash_tiles_live`` += those the static masks leave
+    live; ``flash_chunks`` += the chunks of the live tiles,
     ``flash_chunks_run`` += those the kernels compute: every chunk of a tile
     the static masks leave wholly live, a partly live one's from its first
     live chunk to its last.
@@ -368,18 +366,19 @@ def _plan_tiles(rows, causal, window, q_offset, kv_len, block_q, block_k, nq,
     is wholly live under a static mask and no chunk can be skipped (the
     kernels trace the one masked body they always traced), else (whether
     wholly live tiles exist, the chunk's columns or None). Its live chunks
-    are contiguous in every tile of the cells' calls."""
+    are contiguous in every tile of the cells' calls.
+
+    The walk (THE WALK, `_walk_table`): None where the static masks leave
+    every tile live (the kernels walk the rectangle), else the tables of the
+    forward's and dq's walk over q tiles and of dkv's over kv tiles, with the
+    ``group`` query heads of a key/value head each."""
     statics = (causal, q_offset, kv_len, window, bdiff)
     chunk = _chunk_cols(block_q, block_k)
-    width = chunk or block_k
-    n = block_k // width
+    lives = _tile_lives(statics, block_q, block_k, nq, nk)
+    n = lives.shape[-1]
     r0 = np.arange(nq)[:, None] * block_q
-    c0 = np.arange(nk * n)[None, :] * width
     # the chunks a tile runs, its first live one to its last; a tile is live
     # where any of its chunks is
-    lives = np.broadcast_to(_span_live(r0, r0 + block_q - 1, c0, c0 + width - 1,
-                                       *statics, xp=np),
-                            (nq, nk * n)).reshape(nq, nk, n)
     at = np.arange(n)
     ran = np.maximum(np.where(lives, at, -1).max(-1)
                      - np.where(lives, at, n).min(-1) + 1, 0)
@@ -394,30 +393,32 @@ def _plan_tiles(rows, causal, window, q_offset, kv_len, block_q, block_k, nq,
     _profiler.count("flash_chunks", rows * n * int(live.sum()))
     _profiler.count("flash_chunks_run",
                     rows * int(np.where(whole, n, ran)[live].sum()))
+    walk = None if live.all() else (_walk_table(live),
+                                    _walk_table(live.T, group))
     masked = causal or window is not None or bdiff is not None \
         or kv_len % block_k != 0
     wholes = bool(masked and whole.any())
     skips = bool((ran[live & ~whole] < n).any())
     if not (wholes or skips):
-        return None
-    return wholes, chunk if skips else None
+        return None, walk
+    return (wholes, chunk if skips else None), walk
 
 
 def _bodies(visible, i, j, plan, block_q, block_k, statics):
-    """The bodies a kernel traces for tile (i, j), as (condition, span)
-    pairs, of which one runs for a visible tile. A span ``(col0, width,
-    cols, static)`` is the tile's key rows ``cols``, keys col0 .. col0 +
-    width - 1 (col0 None: from the tile's first), under every mask
-    (``static``) or under what is data alone. Without a plan, one body: the
-    tile under every mask. With one (`_plan_tiles`), a tile that the static
-    masks leave wholly live runs its whole width under the data's masks
-    alone, and a partly live one its chunks from the first live one to the
-    last, at a static width (a body for each count of chunks; where no
-    chunk can be skipped, the whole width). Under causal masks alone the
-    live chunks start the tile, so the span's columns are static (and the
-    data's masks, which go with no window or block diffusion, slice
-    statically); else the span starts at a dynamic chunk. ``statics``:
-    `_span_live`'s masks."""
+    """The bodies a kernel traces for tile (i, j), as (condition, span) pairs,
+    of which one runs for a visible tile (a walked one, `_plan_tiles`' walk,
+    or on the rectangle's grid one that `_block_visible` passes). A span ``(col0,
+    width, cols, static)`` is the tile's key rows ``cols``, keys col0 .. col0 +
+    width - 1 (col0 None: from the tile's first), under every mask (``static``)
+    or under what is data alone. Without a plan, one body: the tile under every
+    mask. With one (`_plan_tiles`), a tile that the static masks leave wholly
+    live runs its whole width under the data's masks alone, and a partly live
+    one its chunks from the first live one to the last, at a static width (a
+    body for each count of chunks; where no chunk can be skipped, the whole
+    width). Under causal masks alone the live chunks start the tile, so the
+    span's columns are static (and the data's masks, which go with no window or
+    block diffusion, slice statically); else the span starts at a dynamic
+    chunk. ``statics``: `_span_live`'s masks."""
     full = (None, block_k, slice(None), True)
     if plan is None:
         return [(visible, full)]
@@ -470,19 +471,15 @@ def _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset, kv_len,
 def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
                 sm_scale, causal, q_offset, kv_len, block_q, block_k,
                 precision, dynamic_kv, dynamic_seg, n_heads,
-                window=None, narrow=None, dynamic_mask=False, bdiff=None,
-                plan=None):
+                window=None, dynamic_mask=False, bdiff=None, plan=None,
+                walk=None):
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (o_ref, lse_ref, acc_sc, m_sc, l_sc)) = _split_rest(
          rest, dynamic_seg, dynamic_mask)
-    i, jj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    i, j, first, last, step = _grid_step(walk)
     kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
-    # ``narrow`` (a window's walk): step jj visits the band's jj-th tile
-    j = _kv_tile(i, jj, window, q_offset, block_q, block_k) \
-        if narrow else jj
 
-    @pl.when(jj == 0)
+    @pl.when(first())
     def _():
         acc_sc[:] = jnp.zeros_like(acc_sc)
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
@@ -494,9 +491,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
                              window, _tile_live(live_ref, i, j, n_heads),
-                             bdiff)
-    if narrow:
-        visible = jnp.logical_and(visible, j < narrow[1])
+                             bdiff, step)
     mask_of = _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset,
                       kv_len, block_q, block_k, window, bdiff)
 
@@ -534,7 +529,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvl_ref, *rest,
                               (causal, q_offset, kv_len, window, bdiff)):
         pl.when(cond)(functools.partial(run, *span))
 
-    @pl.when(jj == nk - 1)
+    @pl.when(last())
     def _():
         l = l_sc[:]
         l_safe = jnp.where(l == np.float32(0.0), np.float32(1.0), l)
@@ -548,26 +543,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    kvl_ref, *rest,
                    sm_scale, causal, q_offset, kv_len, block_q, block_k,
                    precision, dynamic_kv, dynamic_seg, n_heads,
-                   window=None, narrow=None, group=1, steps=None,
-                   dynamic_mask=False, bdiff=None, plan=None):
+                   window=None, group=1, steps=None,
+                   dynamic_mask=False, bdiff=None, plan=None, walk=None):
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (dq_ref, dq_sc)) = _split_rest(rest, dynamic_seg, dynamic_mask)
-    i, jj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    i, j, first, last, step = _grid_step(walk)
     kvl = kvl_ref[pl.program_id(0)] if dynamic_kv else None
-    j = _kv_tile(i, jj, window, q_offset, block_q, block_k) \
-        if narrow else jj
 
-    @pl.when(jj == 0)
+    @pl.when(first())
     def _():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
     visible = _block_visible(i, j, causal, q_offset, block_q, block_k, kvl,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
                              window, _tile_live(live_ref, i, j, n_heads),
-                             bdiff)
-    if narrow:
-        visible = jnp.logical_and(visible, j < narrow[1])
+                             bdiff, step)
     mask_of = _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset,
                       kv_len, block_q, block_k, window, bdiff)
 
@@ -597,40 +587,37 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                               (causal, q_offset, kv_len, window, bdiff)):
         pl.when(cond)(functools.partial(run, *span))
 
-    @pl.when(jj == nk - 1)
+    @pl.when(last())
     def _():
         # dq is wrt the ORIGINAL q: rescale once on the small (bq, d)
         # block (q was pre-scaled; ds here is wrt unscaled scores)
         dq_ref[0] = (dq_sc[:] * np.float32(sm_scale)).astype(dq_ref.dtype)
 
 
-def _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, steps):
+def _inner_q_tile(t, group, steps):
     """The q tile of inner step ``t`` of the (kv rows, kv tiles, q steps)
     grids: with grouped heads the axis runs the group's query heads one
-    after the other, ``steps`` tiles each; narrowed, a head's step ii
-    visits the ii-th q tile that sees the kv tile."""
-    ii = t % steps if group > 1 else t
-    return _q_tile(j, ii, q_offset, block_q, block_k) if narrow else ii
+    after the other, ``steps`` tiles each."""
+    return t % steps if group > 1 else t
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     kvl_ref, *rest,
                     sm_scale, causal, q_offset, kv_len, block_q, block_k,
                     precision, dynamic_kv, dynamic_seg, n_heads,
-                    window=None, narrow=None, group=1, steps=None,
-                    dynamic_mask=False, bdiff=None, plan=None):
+                    window=None, group=1, steps=None,
+                    dynamic_mask=False, bdiff=None, plan=None, walk=None):
     # grid: (B*Hk, nk, group * q steps) — q is the inner (sequential) axis
     ((qseg_ref, kseg_ref, qrng_ref, krng_ref), (pm_ref, live_ref),
      (dk_ref, dv_ref, dk_sc, dv_sc)) = _split_rest(
          rest, dynamic_seg, dynamic_mask)
-    j, t = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    j, t, first, last, step = _grid_step(walk)
     # any query head of the row's batch entry: the lengths are per example
     kvl = kvl_ref[pl.program_id(0) * group if group > 1
                   else pl.program_id(0)] if dynamic_kv else None
-    i = _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, steps)
+    i = _inner_q_tile(t, group, steps)
 
-    @pl.when(t == 0)
+    @pl.when(first())
     def _():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
@@ -639,9 +626,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              _seg_range(qrng_ref, krng_ref, i, j, n_heads),
                              window,
                              _tile_live(live_ref, i, j, n_heads // group),
-                             bdiff)
-    if narrow:
-        visible = jnp.logical_and(visible, i < narrow[0])
+                             bdiff, step)
     mask_of = _masker(i, j, kvl, qseg_ref, kseg_ref, pm_ref, causal, q_offset,
                       kv_len, block_q, block_k, window, bdiff)
 
@@ -676,7 +661,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                               (causal, q_offset, kv_len, window, bdiff)):
         pl.when(cond)(functools.partial(run, *span))
 
-    @pl.when(t == nq - 1)
+    @pl.when(last())
     def _():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
@@ -686,7 +671,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       kvl_ref, *rest,
                       sm_scale, causal, q_offset, kv_len, block_q, block_k,
                       precision, dynamic_kv, dynamic_seg, n_heads,
-                      window=None, narrow=None, group=1, steps=None,
+                      window=None, group=1, steps=None,
                       dynamic_mask=False, bdiff=None, plan=None):
     """One-pass backward: dq, dk, dv from a SINGLE traversal of the
     (q block, k block) grid — the score matrix s and dp are computed
@@ -708,7 +693,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # any query head of the row's batch entry: the lengths are per example
     kvl = kvl_ref[pl.program_id(0) * group if group > 1
                   else pl.program_id(0)] if dynamic_kv else None
-    i = _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, steps)
+    i = _inner_q_tile(t, group, steps)
 
     @pl.when(t == 0)
     def _():
@@ -788,9 +773,12 @@ def _pad0(x, pad):
 # resident, the one-pass backward needs no dq partial-sum, and the
 # (q, do, lse, delta) reloads amortize across the whole row. VMEM: the
 # f32 score block is bq*bk*4 = 4 MB at 512x2048 (d<=128 keeps operand
-# blocks ~1 MB), inside the ~16 MB budget. The caps are what every
-# ledger run has used (S=512 and S=8192); whether 512x2048 is right at
-# S=2048 packed is not measured (ROADMAP.md Queue 2 workload 3).
+# blocks ~1 MB), inside the ~16 MB budget. Under a static mask a wide kv
+# tile costs no more than its live part: a dead tile is not walked
+# (THE WALK) and a partly live one runs its live chunks alone
+# (`_plan_tiles`). The caps are what every ledger run has used (S=512
+# and S=8192); whether 512x2048 is right at S=2048 packed is not measured
+# (ROADMAP.md Queue 2 workload 3).
 _BLOCK_Q_CAP = 512
 _BLOCK_K_CAP = 2048
 
@@ -829,33 +817,24 @@ def _check_heads(q, k, v, window, causal, segment_ids, pair_mask=None,
     return h // hk
 
 
-def _q_map(group, steps, narrow, q_offset, block_q, block_k):
+def _q_map(group, steps):
     """The q-side index map of the (kv rows, kv tiles, q steps) grids."""
-    if group == 1 and not narrow:
+    if group == 1:
         return lambda b_, j, i: (b_, i, 0)
     g32, s32 = np.int32(group), np.int32(steps)
 
     def qmap(b_, j, t):
-        i = _inner_q_tile(j, t, q_offset, block_q, block_k, narrow, group, s32)
-        if narrow:
-            i = jnp.minimum(i, np.int32(narrow[0] - 1))
-        return (b_ * g32 + t // s32 if group > 1 else b_, i, 0)
+        i = _inner_q_tile(t, group, s32)
+        return (b_ * g32 + t // s32, i, 0)
     return qmap
 
 
-def _kv_map(group, window, narrow, q_offset, block_q, block_k):
+def _kv_map(group):
     """The kv-side index map of the (q rows, q tiles, kv steps) grids."""
-    if group == 1 and not narrow:
+    if group == 1:
         return lambda b_, i, j: (b_, j, 0)
     g32 = np.int32(group)
-
-    def kmap(b_, i, jj):
-        j = jj
-        if narrow:
-            j = jnp.minimum(_kv_tile(i, jj, window, q_offset, block_q, block_k),
-                            np.int32(narrow[1] - 1))
-        return (b_ // g32 if group > 1 else b_, j, 0)
-    return kmap
+    return lambda b_, i, j: (b_ // g32, j, 0)
 
 
 def _expand_kv_lens(kv_lens, b, h):
@@ -979,9 +958,9 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
     kvlf = _expand_kv_lens(kv_lens, b, h) if dynamic_kv \
         else jnp.full((bh,), skv, jnp.int32)
     nq, nk = sq_p // block_q, skv_p // block_k
-    plan = _plan_tiles(bh, causal, window, q_offset, skv, block_q, block_k,
-                       nq, nk, bdiff)
-    _tally_widths(d, dv)
+    plan, walk = _plan_tiles(bh, causal, window, q_offset, skv, block_q,
+                             block_k, nq, nk, bdiff)
+    _tally_call(d, dv, bh, walk, nq, nk)
     seg_ops = None if segment_ids is None else list(
         _prep_segments(segment_ids, b, sq, skv, sq_p, skv_p, block_q, block_k))
     mask_ops = None if pair_mask is None else _prep_pair_mask(
@@ -993,26 +972,22 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, interpret,
                   dynamic_mask=pair_mask is not None, bdiff=bdiff, plan=plan)
     call = _flash_fwd_call if plan is None else _flash_fwd_once
     return call(qf, kf, vf, kvlf, seg_ops, mask_ops, (b, h, sq, dv),
-                tuple(sorted(common.items())), interpret, q.dtype, group)
+                tuple(sorted(common.items())), interpret, q.dtype, group,
+                walk and walk[0])
 
 
 def _flash_fwd_call(qf, kf, vf, kvlf, seg_ops, mask_ops, dims, common,
-                    interpret, q_dtype, group):
+                    interpret, q_dtype, group, walk=None):
     """The forward's kernel call on its prepared operands; ``common``: the
-    kernel's static arguments, as sorted pairs."""
+    kernel's static arguments, as sorted pairs; ``walk``: the table of its
+    live steps (`_plan_tiles`), or None to walk the rectangle."""
     b, h, sq, dv = dims
     common = dict(common)
     block_q, block_k = common["block_q"], common["block_k"]
-    window, q_offset = common["window"], common["q_offset"]
     d = qf.shape[-1]
     bh, sq_p = b * h, qf.shape[1]
     nq, nk = sq_p // block_q, kf.shape[1] // block_k
-    # a window's walk: the inner axis holds the band's tiles, not the row's
-    narrow = (nq, nk) if window is not None else None
-    kv_steps = _band_steps(window, q_offset, block_q, block_k, nq, nk)[0] \
-        if narrow else nk
-    kern = functools.partial(_fwd_kernel, narrow=narrow, **common)
-    kv_map = _kv_map(group, window, narrow, q_offset, block_q, block_k)
+    kv_map = _kv_map(group)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                      memory_space=pltpu.VMEM),
@@ -1027,9 +1002,8 @@ def _flash_fwd_call(qf, kf, vf, kvlf, seg_ops, mask_ops, dims, common,
     if mask_ops is not None:
         in_specs += _mask_specs(block_q, block_k, h, transposed_grid=False)
         operands += mask_ops
-    o, lse = pl.pallas_call(
-        kern,
-        grid=(bh, nq, kv_steps),
+    o, lse = _tiled_call(
+        functools.partial(_fwd_kernel, **common), (bh, nq, nk), walk,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0),
@@ -1102,9 +1076,10 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
         kf, vf = _pad0(kf, pad), _pad0(vf, pad)
 
     nq, nk = sq_p // block_q, skv_p // block_k
-    plan = _plan_tiles(bh, causal, window, q_offset, skv, block_q, block_k,
-                       nq, nk, bdiff)
-    _tally_widths(d, dv)
+    plan, walk = _plan_tiles(bh, causal, window, q_offset, skv, block_q,
+                             block_k, nq, nk, bdiff, group)
+    # the fused arm (nk <= 2, below) walks the rectangle: its docstring
+    _tally_call(d, dv, bh, walk if nk > 2 else None, nq, nk)
     common = dict(sm_scale=sm_scale, causal=causal, q_offset=q_offset,
                   kv_len=skv, block_q=block_q, block_k=block_k,
                   precision=_dot_precision(q.dtype), dynamic_kv=dynamic_kv,
@@ -1116,12 +1091,13 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, q_offset, interpret,
     # that memory/write cliff outweighs the recompute saving, so long
     # multi-k-block rows (S > 2*block_k cap) take the split path whose
     # dq accumulates in VMEM scratch
-    call = (_flash_bwd_fused, _flash_bwd_split) if plan is None \
-        else (_flash_bwd_fused_once, _flash_bwd_split_once)
-    return call[nk > 2](qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
-                        (b, h, sq, skv, d, dv), nq, nk,
-                        tuple(sorted(common.items())), interpret, k.dtype,
-                        v.dtype, q.dtype, group, mask_ops)
+    args = (qf, kf, vf, dof, lsef, delta, kvlf, seg_ops,
+            (b, h, sq, skv, d, dv), nq, nk, tuple(sorted(common.items())),
+            interpret, k.dtype, v.dtype, q.dtype, group, mask_ops)
+    if nk > 2:
+        split = _flash_bwd_split if plan is None else _flash_bwd_split_once
+        return split(*args, walk=walk)
+    return (_flash_bwd_fused if plan is None else _flash_bwd_fused_once)(*args)
 
 
 def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
@@ -1130,7 +1106,12 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     """Single-pass dq/dk/dv, taken where the kv grid has at most two
     blocks: ``bert_base.train_b64x512`` (S=512, nk=1) runs this kernel;
     ``kimi_linear_48b_a3b.train_8k``'s latent attention (S=8192 at the
-    2048 cap, nk=4) runs ``_flash_bwd_split``'s dq/dkv pair."""
+    2048 cap, nk=4) runs ``_flash_bwd_split``'s dq/dkv pair.
+
+    It walks the whole (kv tiles x q steps) rectangle under every mask and
+    takes no table of live steps (THE WALK), because each of its steps owns a
+    dq partial block that it must write, zeros where the tile is dead; no
+    cell runs it with a static mask."""
     b, h, sq, skv, d, dv = dims
     bh = b * h
     common = dict(common, steps=nq)
@@ -1138,7 +1119,7 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     sq_p, skv_p = nq * block_q, nk * block_k
     # the q side: with grouped heads the inner axis runs the group's query
     # heads one after the other, nq tiles each
-    q_map = _q_map(group, nq, None, 0, block_q, block_k)
+    q_map = _q_map(group, nq)
     if group == 1:
         dq_map = lambda b_, j, i: (b_, j, i, 0)  # noqa: E731
     else:
@@ -1202,20 +1183,19 @@ def _flash_bwd_fused(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
 
 def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
                      nq, nk, common, interpret, k_dtype, v_dtype, q_dtype,
-                     group=1, mask_ops=None):
+                     group=1, mask_ops=None, walk=None):
+    """The dq kernel and the dkv kernel; ``walk``: the tables of their live
+    steps (`_plan_tiles`: q tile-major and kv tile-major), or None to walk
+    the rectangles."""
     b, h, sq, skv, d, dv = dims
     bh = b * h
     common = dict(common)
     block_q, block_k = common["block_q"], common["block_k"]
     sq_p, skv_p = nq * block_q, nk * block_k
-    window, q_offset = common["window"], common["q_offset"]
-    # a window's walk: both inner axes hold a band's tiles, not a row's
-    narrow = (nq, nk) if window is not None else None
-    kv_steps, q_steps = _band_steps(window, q_offset, block_q, block_k,
-                                    nq, nk) if narrow else (nk, nq)
-    common = dict(common, narrow=narrow, steps=q_steps)
-    kv_map = _kv_map(group, window, narrow, q_offset, block_q, block_k)
-    q_map = _q_map(group, q_steps, narrow, q_offset, block_q, block_k)
+    common = dict(common, steps=nq)
+    kv_map = _kv_map(group)
+    q_map = _q_map(group, nq)
+    walk_dq, walk_dkv = walk or (None, None)
 
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
@@ -1237,9 +1217,8 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
     if mask_ops is not None:
         dq_specs += _mask_specs(block_q, block_k, h, transposed_grid=False)
         operands += mask_ops
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        grid=(bh, nq, kv_steps),
+    dq = _tiled_call(
+        functools.partial(_bwd_dq_kernel, **common), (bh, nq, nk), walk_dq,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0),
                                memory_space=pltpu.VMEM),
@@ -1265,10 +1244,10 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
         dkv_specs += _seg_specs(block_q, block_k, h, transposed_grid=True)
     if mask_ops is not None:
         dkv_specs += _mask_specs(block_q, block_k, h // group, True, group,
-                                 q_steps)
-    dk, dvg = pl.pallas_call(
+                                 nq)
+    dk, dvg = _tiled_call(
         functools.partial(_bwd_dkv_kernel, **common),
-        grid=(bh // group, nk, group * q_steps),
+        (bh // group, nk, group * nq), walk_dkv,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0),
@@ -1301,10 +1280,11 @@ def _flash_bwd_split(qf, kf, vf, dof, lsef, delta, kvlf, seg_ops, dims,
 # by the static arguments and the operands' shapes, a program traces and
 # lowers them once for all its identical calls.
 _STATIC = ("dims", "common", "interpret", "q_dtype", "group")
-_flash_fwd_once = jax.jit(_flash_fwd_call, static_argnames=_STATIC)
-_flash_bwd_fused_once, _flash_bwd_split_once = (
-    jax.jit(f, static_argnames=_STATIC + ("nq", "nk", "k_dtype", "v_dtype"))
-    for f in (_flash_bwd_fused, _flash_bwd_split))
+_BWD_STATIC = _STATIC + ("nq", "nk", "k_dtype", "v_dtype")
+_flash_fwd_once = jax.jit(_flash_fwd_call, static_argnames=_STATIC + ("walk",))
+_flash_bwd_fused_once = jax.jit(_flash_bwd_fused, static_argnames=_BWD_STATIC)
+_flash_bwd_split_once = jax.jit(_flash_bwd_split,
+                                static_argnames=_BWD_STATIC + ("walk",))
 
 
 def _int_ct(x):
@@ -1613,10 +1593,16 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, kv_lens,
                        v.astype(jnp.float32))).astype(q.dtype)
 
 
-def _tally_widths(d, dv):
+def _tally_call(d, dv, rows, walk, nq, nk):
     """Trace-time: ``flash_calls`` += 1 a forward or backward call,
-    ``flash_two_width_calls`` += 1 where v's width is narrower than q.k's."""
+    ``flash_two_width_calls`` += 1 where v's width is narrower than q.k's,
+    ``flash_grid_steps`` += the (q tile, kv tile) steps that one of its
+    kernels (the forward's or dq's, or the fused one) launches, in
+    ``flash_tiles``' unit: ``rows`` x the length of its ``walk`` table, or x
+    nq x nk on the rectangle."""
     _profiler.count("flash_calls")
+    _profiler.count("flash_grid_steps",
+                    rows * (len(walk[0][0]) if walk else nq * nk))
     if dv < d:
         _profiler.count("flash_two_width_calls")
 
@@ -1626,10 +1612,11 @@ def _tally_widths(d, dv):
 # ``flash_attention_block_diffusion``; ``bdiff`` = (L, block) in the
 # kernels): q, k and v span a row's clean copy and its noised copy, the clean
 # L positions first. Inside a live tile the mask is written from indices
-# (``_bdiff_mask``); a tile it leaves empty is skipped whole (``_bdiff_live``,
-# from the tile's corners), in every arm and with grouped heads; no (B, 2L,
-# 2L) operand is read. Absent, every other call traces the jaxpr it traced
-# before (``tests/test_sdar_blocks.py``). A call also adds to the tallies
+# (``_bdiff_mask``); a tile it leaves empty (``_bdiff_live``, from the tile's
+# corners) is not walked (THE WALK), or in the fused arm is skipped in
+# the kernel, with grouped heads too; no (B, 2L, 2L) operand is read.
+# Absent, every other call traces the jaxpr it traced before
+# (``tests/test_sdar_blocks.py``). A call also adds to the tallies
 # ``bdiff_calls``, ``bdiff_tiles`` and ``bdiff_tiles_live``.
 # ----------------------------------------------------------------------
 def _blocks_of(x, block):
@@ -1757,3 +1744,106 @@ def _bdiff_vjp_bwd(block, sm_scale, interpret, res, do):
 
 
 flash_attention_block_diffusion.defvjp(_bdiff_vjp_fwd, _bdiff_vjp_bwd)
+
+
+# ----------------------------------------------------------------------
+# THE WALK (the module docstring's): a call whose static masks leave a tile
+# dead walks a table of its live steps, scalar-prefetched, in place of the
+# (outer tiles x inner steps) rectangle. The tables are numpy at trace time,
+# built from the static masks and shapes alone, and reach the jitted call
+# wrappers as static arguments (tuples), so identical calls trace once.
+# ----------------------------------------------------------------------
+_FIRST, _LAST, _LIVE = 1, 2, 4     # a step's flags
+
+
+def _tile_lives(statics, block_q, block_k, nq, nk):
+    """Trace-time: (nq, nk, chunks a tile) bool, whether the static masks
+    (`_span_live`'s ``statics``) leave a pair of each chunk of each tile
+    live; a tile is live where any of its chunks is (`_chunk_cols`: one chunk
+    a tile where tiles do not split)."""
+    width = _chunk_cols(block_q, block_k) or block_k
+    n = block_k // width
+    r0 = np.arange(nq)[:, None] * block_q
+    c0 = np.arange(nk * n)[None, :] * width
+    return np.broadcast_to(_span_live(r0, r0 + block_q - 1, c0, c0 + width - 1,
+                                      *statics, xp=np),
+                           (nq, nk * n)).reshape(nq, nk, n)
+
+
+def _walk_table(live, group=1):
+    """The steps of a walk over ``live`` (outer tiles x inner tiles, bool), as
+    (outer, inner, flags) tuples of ints: each outer tile's live inner tiles
+    ascending, ``group`` times over (the g-th time as g x the inner tiles +
+    the tile, as the rectangle's inner axis counts), the first and last step
+    of an outer tile flagged, and every step that computes flagged live. An
+    outer tile with no live inner tile keeps one step, not live, on the inner
+    tile the step before fetched (an anchor: it initialises and writes)."""
+    n = live.shape[1]
+    outer, inner, flags = [], [], []
+    for a, row in enumerate(live):
+        steps = [g * n + int(b) for g in range(group)
+                 for b in np.flatnonzero(row)]
+        on = _LIVE if steps else 0
+        steps = steps or [inner[-1] if inner else 0]
+        for s, b in enumerate(steps):
+            outer.append(a)
+            inner.append(b)
+            flags.append(on | (_FIRST if s == 0 else 0)
+                         | (_LAST if s == len(steps) - 1 else 0))
+    return tuple(outer), tuple(inner), tuple(flags)
+
+
+def _grid_step(walk):
+    """A kernel's position: (outer tile, inner step, first(), last(), live),
+    from the rectangle's program ids (``walk`` None: ``live`` None, the static
+    masks tested in the kernel) or from the table's refs at this step (the
+    step's live flag). ``first`` / ``last`` are called where the step's
+    scratch is set and its output written, so that a kernel on the rectangle
+    traces the operations it traced before the walk, in their order."""
+    if walk is None:
+        a, b = pl.program_id(1), pl.program_id(2)
+        n = pl.num_programs(2)
+        return a, b, lambda: b == 0, lambda: b == n - 1, None
+    outer, inner, flags = walk
+    t = pl.program_id(1)
+    f = flags[t]
+    return (outer[t], inner[t], lambda: (f & _FIRST) != 0,
+            lambda: (f & _LAST) != 0, (f & _LIVE) != 0)
+
+
+def _on_table(spec):
+    """A BlockSpec of the rectangle's (rows, outer, inner) grid, on a walk's
+    (rows, step) grid: its index map reads the step's tiles from the table."""
+    if spec.index_map is None:           # a whole array (SMEM)
+        return spec
+    at = spec.index_map
+    return dataclasses.replace(
+        spec, index_map=lambda r, t, outer, inner, flags: at(r, outer[t],
+                                                             inner[t]))
+
+
+def _walked(kernel, outer_ref, inner_ref, flags_ref, *refs):
+    return kernel(*refs, walk=(outer_ref, inner_ref, flags_ref))
+
+
+def _tiled_call(kernel, grid, walk, *, in_specs, out_specs, scratch_shapes,
+                **kw):
+    """``pl.pallas_call`` of a flash kernel on the rectangle ``grid`` (rows,
+    outer tiles, inner steps), or, given a ``walk`` table, on (rows, its
+    steps): the table's three vectors go first, as scalar-prefetch operands,
+    and the kernel reads them as ``walk``."""
+    if walk is None:
+        return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_specs,
+                              scratch_shapes=scratch_shapes, **kw)
+    outs = [_on_table(s) for s in out_specs] if isinstance(out_specs, list) \
+        else _on_table(out_specs)
+    call = pl.pallas_call(
+        functools.partial(_walked, kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(grid[0], len(walk[0])),
+            in_specs=[_on_table(s) for s in in_specs], out_specs=outs,
+            scratch_shapes=scratch_shapes),
+        **kw)
+    tables = [jnp.asarray(np.asarray(x, np.int32)) for x in walk]
+    return lambda *operands: call(*tables, *operands)

@@ -60,7 +60,8 @@ _LOCK = threading.Lock()
 # kernel's wrappers "flash_calls" and "flash_two_width_calls", once a call
 # as traced, and its block-diffusion calls "bdiff_calls", "bdiff_tiles" and
 # "bdiff_tiles_live", and its live tiles' chunks "flash_chunks" and
-# "flash_chunks_run". Not locked: a span reads the difference on its own
+# "flash_chunks_run", and the grid steps its kernels walk
+# "flash_grid_steps". Not locked: a span reads the difference on its own
 # thread, which is exact while no other thread dispatches (a training loop).
 _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "remat_kept": 0, "remat_kept_bytes": 0,
@@ -70,7 +71,7 @@ _COUNTS = {"invokes": 0, "cachedop_builds": 0, "fused": 0, "looped": 0,
            "mla_rope_calls": 0, "mtp_modules": 0,
            "flash_calls": 0, "flash_two_width_calls": 0,
            "bdiff_calls": 0, "bdiff_tiles": 0, "bdiff_tiles_live": 0,
-           "flash_chunks": 0, "flash_chunks_run": 0}
+           "flash_chunks": 0, "flash_chunks_run": 0, "flash_grid_steps": 0}
 # bound once: `active()` is the one test `invoke` pays per op when off
 _session_live = jax.profiler.TraceAnnotation.is_enabled
 
@@ -194,7 +195,12 @@ def counters(device=True):
     every live tile of those flash calls, and those the kernels compute:
     every chunk of a tile the static masks leave wholly live, a partly live
     one's from its first live chunk to its last (a ratio of 1: no chunk
-    skipped). Trace-time tallies, flat across steps.
+    skipped). ``flash_grid_steps``: the (q tile, kv tile) grid steps that
+    one kernel of each of those calls launches, in ``flash_tiles``' unit: a
+    call whose static masks leave a tile dead walks a table of its live
+    steps, any other the whole rectangle, so
+    ``flash_grid_steps / flash_tiles`` is the share of the rectangles
+    walked (1: nothing left out). Trace-time tallies, flat across steps.
 
     Blocks that count on the device (`register_device_counters`: an expert
     layer's ``running_slots``) are read here, when the operator polls and

@@ -11,7 +11,7 @@ and without a ``q_offset``, block diffusion at blocks of 1, 4 and 48 (a block
 that straddles chunks), grouped key/value heads, causal with a pair mask whose
 rows see no key in some chunks, against a dense softmax over the same mask and
 against the same call forced to whole tiles (``_plan_tiles`` giving no plan:
-the one masked body the kernel traced before chunks). The tallies
+the one masked body the kernel traced before chunks, on the same walk). The tallies
 ``flash_chunks`` / ``flash_chunks_run`` at the cells' shapes.
 
 Tolerances: float32 inputs, so the kernels' dots run at ``HIGHEST``; what is
@@ -103,14 +103,17 @@ def test_chunks_are_the_dense_softmax_and_the_whole_tile_call(monkeypatch, case,
         return jax.jit(lambda q, k, v: (call(q, k, v), jax.grad(
             lambda *a: (call(*a) * w).sum(), (0, 1, 2))(q, k, v)))(q, k, v)
 
-    plan = flash_mod._plan_tiles
+    plan_tiles = flash_mod._plan_tiles
     plans = []
     monkeypatch.setattr(flash_mod, "_plan_tiles",
-                        lambda *a, **kw: plans.append(plan(*a, **kw)) or plans[-1])
+                        lambda *a, **kw: plans.append(plan_tiles(*a, **kw)) or plans[-1])
     chunked = both(call)
     # the forwards and the backward all split their tiles into 128-wide chunks
-    assert len(plans) == 3 and all(p is not None and p[1] == TILES[0] for p in plans)
-    monkeypatch.setattr(flash_mod, "_plan_tiles", lambda *a, **kw: (plan(*a, **kw), None)[1])
+    assert len(plans) == 3 and all(p is not None and p[1] == TILES[0]
+                                   for p, _ in plans)
+    # the same walk, no plan
+    monkeypatch.setattr(flash_mod, "_plan_tiles",
+                        lambda *a, **kw: (None, plan_tiles(*a, **kw)[1]))
     _close(chunked, both(call), 1e-6)
     _close(chunked, both(lambda q, k, v: _dense(q, k, v, mask, scale)), 1e-5)
 
@@ -140,8 +143,9 @@ def test_a_call_with_nothing_to_skip_keeps_its_one_body():
     tile, none of them wholly live): no plan, every chunk counted run."""
     for causal, window, length in ((False, None, 512), (True, 512, 8192)):
         bq, bk = flash_mod._pick_blocks(length, length, window)
-        assert flash_mod._plan_tiles(1, causal, window, 0, length, bq, bk,
-                                     -(-length // bq), -(-length // bk)) is None
+        plan, _ = flash_mod._plan_tiles(1, causal, window, 0, length, bq, bk,
+                                        -(-length // bq), -(-length // bk))
+        assert plan is None
     assert _chunk_share(False, 512) == 100.0
 
 

@@ -178,19 +178,22 @@ _OP_CALLS = {
 }
 
 # sha256 of the jaxprs before the kernel took v's width, in interpret mode,
-# bfloat16. BERT's and Phi's window calls are those jaxprs still; Phi's full
-# layer and Keye's masked call were re-pinned when the kernel began to run a
-# tile that its static masks leave wholly live without building them and a
-# partly live one a 512-column chunk at a time (tests/test_flash_chunks.py)
+# bfloat16. BERT's calls are those jaxprs still; Phi's full layer and Keye's
+# masked call were re-pinned when the kernel began to run a tile that its
+# static masks leave wholly live without building them and a partly live one
+# a 512-column chunk at a time (tests/test_flash_chunks.py), and those two and
+# Phi's window call when the kernels began to walk a table of the tiles the
+# static masks leave live (tests/test_flash_walk.py), their outputs and
+# gradients the parent's bit for bit
 _PARENT = {
     ("bert", "forward"): "76e523f4abe738791a22a359b75b96d9a561734397f09bb6be385d854ab20859",
     ("bert", "gradient"): "add6e79f79175e15edebffbeecfa87cef010e04a4761d74d300965898fb0003f",
-    ("keye", "forward"): "886153884a9e9c3f9c530dbe429a9d0554f2ffc5eb37aa2880b15a68af1b6a7d",
-    ("keye", "gradient"): "5829fa4e15c30d004febce6599e8a7c611801b028f84b826d29a9eefd5e128ef",
-    ("phi_full", "forward"): "d9a8fc58ba18ca3d99437977933f56b44394bff940de2b6a12719c505c55c0ec",
-    ("phi_full", "gradient"): "4a04fd8ad821909e969c46b05bc719205de42ed4b16923e64b9b7e202b003e16",
-    ("phi_window", "forward"): "281557083ffe10ddfefab275ccf58e05a3b854a05d8a343a2a31ea3d532f598f",
-    ("phi_window", "gradient"): "a8b388be6d483e0ed09ad58dbb162fc4d57452a6ca233ca681b8ee07f111811f",
+    ("keye", "forward"): "4d3b8871ba1df1866f546974da1d7583005ad05f10b39830b55573f36af12ff4",
+    ("keye", "gradient"): "dc79f56c1ffe395885db0b18b6be2c5afb0325cabd99d5fd31139c897a81eecd",
+    ("phi_full", "forward"): "e236cf47b354e550cddee3df4dd00c20b243320d21c88569519cd3e897ac7657",
+    ("phi_full", "gradient"): "ef8f4cf871e1f8eb71e8f7e6efcfa07c15f24d6c7e5e843c6ee5187599955735",
+    ("phi_window", "forward"): "a27898c958b7876f92296de700634cbd01d29b731d6fa5b8d841f77ee74d996f",
+    ("phi_window", "gradient"): "61bb231a94d9f0ce7257cda65029d4e1cdea9e7b6abae704e87e91e5242c8d08",
 }
 
 

@@ -169,13 +169,15 @@ def test_the_live_tiles_at_the_cells_size_are_the_issues_count():
 # latent attention call of Kimi's and JoyAI's cells (q.k 192, v 128, 32 heads,
 # one row: the two cells make the same call), taken on the parent and
 # re-pinned when the kernel began to run wholly live tiles without building
-# their static mask and partly live ones a chunk at a time
+# their static mask and partly live ones a chunk at a time, and again when
+# its kernels began to walk a table of the live tiles (outputs and gradients
+# the parent's bit for bit)
 _MLA = ((1, 32, 8192, 192), (1, 32, 8192, 192), (1, 32, 8192, 128), True, None, False, False)
 _PARENT = {**two_widths._PARENT,
-           ("kimi", "forward"): "d210b33d69cefcec6bfaaac32d597b28d6c30969f584166f01f39e9e8053ca4d",
-           ("kimi", "gradient"): "122131ad380654ebfa7dd76872f665177e2b4de20f7172097d3b10a8a730a59d",
-           ("joyai", "forward"): "d210b33d69cefcec6bfaaac32d597b28d6c30969f584166f01f39e9e8053ca4d",
-           ("joyai", "gradient"): "122131ad380654ebfa7dd76872f665177e2b4de20f7172097d3b10a8a730a59d"}
+           ("kimi", "forward"): "8782dcfdc703758d7d3258344775b4e278a4edcd6dff3a7fe10e2fdc64868d71",
+           ("kimi", "gradient"): "6dbeea3cf7e85be9857c7cf957ae6fa5679e48d9c65110661bfdab67630df008",
+           ("joyai", "forward"): "8782dcfdc703758d7d3258344775b4e278a4edcd6dff3a7fe10e2fdc64868d71",
+           ("joyai", "gradient"): "6dbeea3cf7e85be9857c7cf957ae6fa5679e48d9c65110661bfdab67630df008"}
 
 
 @pytest.mark.parametrize("call,what", sorted(_PARENT))
